@@ -55,9 +55,8 @@ ALLOWED_SYNC_FUNCS = {
     # comb-table build commits tables to device memory before the
     # fsync'd on-disk cache write (backend.py "tbl.block_until_ready()")
     ("crypto/backend.py", "TpuBackend._build_tables"),
-    # warm-up paths exist to absorb the compile+first-dispatch wait
+    # the warm-up thread exists to absorb the compile+first-dispatch wait
     ("crypto/backend.py", "TpuBackend._warm_verify_if_cold.warm"),
-    ("crypto/warmcompile.py", "_warm_one"),
 }
 
 _HOST_CASTS = {"float", "int", "bool", "complex"}

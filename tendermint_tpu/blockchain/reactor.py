@@ -250,6 +250,21 @@ class BlockchainReactor(Reactor):
             items.append((bid, b.height, blocks[i + 1].last_commit))
         return window, parts_list, items
 
+    def _window_ready(self, blocks) -> bool:
+        """Verify `blocks` (a window plus its successor) now, or wait?
+        A window goes when it is full, or when no fuller one can come:
+        the best peer's height ends inside it — the tip of the chain.
+        With no peer at all the tip is unknown, so it waits (a starved
+        boot evicts every peer for request timeouts at once, and they
+        redial).  Draining whatever happens to have arrived would
+        dispatch a new (lanes, templates) bucket for every odd size, and
+        each bucket is a fresh XLA compile — tens of seconds on the chip
+        — while only the full-window shape is warmed at boot."""
+        if len(blocks) > self.batch_size:
+            return True
+        best = self.pool.max_peer_height()
+        return 0 < best < blocks[0].height + self.batch_size
+
     def _sync_step(self) -> bool:
         """Drain one verified window: batch-verify K contiguous blocks'
         commits in one device call, then save + apply each — with the
@@ -260,6 +275,8 @@ class BlockchainReactor(Reactor):
         if len(peek) < 2:
             return False
         blocks = peek[:self.batch_size + 1]
+        if not self._window_ready(blocks):
+            return False
         chain_id = self.state.chain_id
         vals_hash = self.state.validators.hash()
         verified = None
@@ -339,7 +356,8 @@ class BlockchainReactor(Reactor):
         # speculative verify-ahead: the next contiguous window, against a
         # SNAPSHOT of the current set (apply below mutates the live one)
         nxt = peek[len(window):len(window) + self.batch_size + 1]
-        if len(nxt) >= 2 and not self._stopped.is_set():
+        if len(nxt) >= 2 and self._window_ready(nxt) and \
+                not self._stopped.is_set():
             self._lookahead = _Lookahead(
                 self.state.validators.copy(), chain_id, nxt)
         commit_by_height = {h: c for _bid, h, c in items}

@@ -89,7 +89,7 @@ def _warm_crypto(cfg) -> int:
     already warm (node boot also warms, but in a background thread —
     `node/node.py _maybe_precompile` — so a cold first boot verifies its
     first commits on the fallback backend; seeding at init moves the
-    one-time compile wait to the operator's init step, VERDICT r4 #3).
+    one-time compile wait to the operator's init step).
     Harmless no-op on the python/native backends."""
     import time
     from tendermint_tpu.crypto import backend as cb

@@ -253,10 +253,7 @@ def save_config_file(cfg: Config, path: str) -> None:
 def load_config_file(path: str, cfg: Config | None = None) -> Config:
     """Overlay a TOML config file onto defaults.  Unknown keys fail loudly
     (a typo silently reverting to a default is how testnets lose nights)."""
-    try:
-        import tomllib               # 3.11+ stdlib
-    except ModuleNotFoundError:      # 3.10: same API under the old name
-        import tomli as tomllib
+    import tomllib
     cfg = cfg or Config()
     with open(path, "rb") as f:
         data = tomllib.load(f)

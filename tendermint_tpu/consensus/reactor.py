@@ -41,7 +41,7 @@ GOSSIP_SLEEP = 0.1           # IDLE-ONLY safety net; gossip is event-driven
                              # condition variable wakes the routines the
                              # moment core or peer state changes, so the
                              # sleep only bounds staleness after a missed
-                             # signal; VERDICT r3: 20ms polling across
+                             # signal: 20ms polling across
                              # N peers x 3 threads starved the GIL)
 MAJ23_SLEEP = 0.5            # reference peerQueryMaj23SleepDuration (2s)
 

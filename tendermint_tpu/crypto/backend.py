@@ -24,7 +24,10 @@ import numpy as np
 
 from tendermint_tpu.crypto import pure_ed25519 as _ref
 from tendermint_tpu.utils import metrics, tracing
+from tendermint_tpu.utils.log import get_logger
 from tendermint_tpu.utils.metrics import REGISTRY
+
+log = get_logger("crypto")
 
 MIN_BUCKET = 16
 
@@ -36,7 +39,7 @@ MIN_BUCKET = 16
 # signature on an entry that was already warm is shape DRIFT — the
 # _bucket() padding leaked a shape and the node just paid a silent
 # 100s-class recompile.  The monitoring listener in
-# _enable_compile_cache() counts the REAL backend compiles; the pair of
+# enable_compile_cache() counts the REAL backend compiles; the pair of
 # views separates "dispatched cold" from "actually compiled".
 _jit_shapes: dict[str, set] = {}
 _jit_lock = threading.Lock()
@@ -64,6 +67,30 @@ def _note_dispatch(entry: str, *arrays) -> bool:
     return True
 
 
+_cold_in_flight = 0      # cold dispatches + table builds running now
+
+
+@contextmanager
+def _cold_section():
+    """Mark work that compiles (a first call of a shape, a table build):
+    minutes on a cold cache, and not a hung device.  The supervised
+    ladder reads `cold_dispatch_in_flight()` before it calls a slow
+    device call a fault."""
+    global _cold_in_flight
+    with _jit_lock:
+        _cold_in_flight += 1
+    try:
+        yield
+    finally:
+        with _jit_lock:
+            _cold_in_flight -= 1
+
+
+def cold_dispatch_in_flight() -> bool:
+    with _jit_lock:
+        return _cold_in_flight > 0
+
+
 @contextmanager
 def _firstcall(entry: str, cold: bool):
     """Time a cold dispatch under an `xla.firstcall` span (category
@@ -73,7 +100,7 @@ def _firstcall(entry: str, cold: bool):
         yield
         return
     t0 = time.perf_counter()
-    with tracing.span("xla.firstcall", entry=entry):
+    with _cold_section(), tracing.span("xla.firstcall", entry=entry):
         yield
     REGISTRY.xla_first_call_seconds.observe(time.perf_counter() - t0)
 
@@ -156,8 +183,10 @@ class PythonBackend:
 class TpuBackend:
     """JAX batch kernel (`tendermint_tpu.ops.ed25519`) with shape bucketing.
 
-    Also runs on the CPU XLA backend — "tpu" names the code path, not the
-    physical device; jax picks whatever platform is configured.
+    Runs on whatever platform jax was given (the test suite pins the CPU
+    XLA backend), so "tpu" names the code path; the platform and device
+    kind it really got are logged once at construction and kept in
+    `platform` / `device_kind`.
     """
     name = "tpu"
 
@@ -177,7 +206,7 @@ class TpuBackend:
         import jax
         import jax.numpy as jnp
         from tendermint_tpu.ops import ed25519 as dev
-        _enable_compile_cache()
+        enable_compile_cache()
         self._jnp = jnp
         self._dev = dev
         # fixed-base comb table, uploaded once and passed as an ARGUMENT
@@ -197,15 +226,22 @@ class TpuBackend:
         self._mesh = None
         self._sharded_fns: dict[bytes, object] = {}
         self._base_tbl_mesh = None
-        n_dev = len(jax.devices())
+        devs = jax.devices()
+        n_dev = len(devs)
+        self.platform = devs[0].platform
+        self.device_kind = devs[0].device_kind
         if n_dev > 1:
             from tendermint_tpu.parallel import sharding
             from jax.sharding import NamedSharding, PartitionSpec
             self._mesh = sharding.make_mesh(n_dev)
             self._base_tbl_mesh = jax.device_put(
                 self._base_tbl, NamedSharding(self._mesh, PartitionSpec()))
-        metrics.set_build_info(jax_backend=jax.default_backend(),
+        metrics.set_build_info(jax_backend=self.platform,
+                               device_kind=self.device_kind,
                                local_devices=n_dev)
+        log.info("tpu crypto backend up", platform=self.platform,
+                 device_kind=self.device_kind, devices=n_dev,
+                 cache_dir=compile_cache_dir())
 
     def tables_cached(self, set_key: bytes) -> bool:
         """True when the comb tables for `set_key` are already resident —
@@ -266,7 +302,8 @@ class TpuBackend:
                     break                    # we build
             pending.wait()                   # someone else is building
         try:
-            ent = self._build_tables(set_key, val_pubs)
+            with _cold_section():    # build compile + run: minutes cold
+                ent = self._build_tables(set_key, val_pubs)
         finally:
             with self._tables_lock:
                 self._builds.pop(set_key).set()
@@ -284,14 +321,13 @@ class TpuBackend:
         pure functions of the member pubkeys and set_key digests those,
         so content-addressing by set_key can never serve STALE tables.
         TRUST: the cache dir must be exactly as trusted as the jax
-        persistent compile cache next to it — anyone who can write
-        either can subvert verification (poisoned executables in the
-        compile cache are strictly worse), so both live under the same
-        operator-owned ~/.cache root by default."""
-        d = os.environ.get(
-            "TM_TABLE_CACHE_DIR",
-            os.path.join(os.path.expanduser("~"), ".cache",
-                         "tendermint_tpu", "tables"))
+        persistent compile cache — anyone who can write either can
+        subvert verification (poisoned executables in the compile cache
+        are strictly worse), so by default the tables live INSIDE the
+        compile cache's directory (`compile_cache_dir()`): one
+        operator-owned directory to protect, place and carry."""
+        d = os.environ.get("TM_TABLE_CACHE_DIR",
+                           os.path.join(compile_cache_dir(), "tables"))
         if not d:
             return None
         return os.path.join(
@@ -400,11 +436,10 @@ class TpuBackend:
                              kind: str, shape: tuple):
         """Overlap the verify executable's XLA compile with the comb-table
         build on a COLD set: the compile needs only shapes, so a dummy
-        call with zero tables runs on a thread while `_set_tables` pays
-        the (similarly long) build compile — the two overlap almost
-        fully, halving cold first-call latency (VERDICT r4 #3).  Returns
-        the thread (caller joins after tables are ready), or None when
-        the set is already cached."""
+        call with zero tables runs on a thread of THIS process (one
+        process owns the chip) while `_set_tables` pays the build's
+        compile and run.  Returns the thread (caller joins after tables
+        are ready), or None when the set is already cached."""
         if self._mesh is not None:
             return None     # mesh path compiles per-shape sharded fns
         with self._tables_lock:
@@ -415,73 +450,27 @@ class TpuBackend:
         from tendermint_tpu.ops.curve import COMB_DIGITS, COMB_WINDOWS
 
         def warm():
-            try:
-                # phase 1 (best effort): compile in a SUBPROCESS — two
-                # compiles in one process serialize inside XLA, but a
-                # separate process runs truly concurrent with the main
-                # thread's table-build compile and seeds the shared
-                # persistent cache
-                import json as _json
-                import subprocess
-                import sys as _sys
-                cache_dir = os.environ.get(
-                    "TM_JAX_CACHE_DIR",
-                    os.path.join(os.path.expanduser("~"), ".cache",
-                                 "tendermint_tpu", "jax"))
-                spec = _json.dumps({"kind": kind, "vb": vb,
-                                    "shape": list(shape),
-                                    "cache_dir": cache_dir})
-                try:
-                    proc = subprocess.run(
-                        [_sys.executable, "-m",
-                         "tendermint_tpu.crypto.warmcompile", spec],
-                        capture_output=True, timeout=600)
-                    # the warmer reports its compile time as a JSON line
-                    # (the compile happened in ANOTHER process, so the
-                    # in-process monitoring listener never saw it)
-                    for line in reversed(
-                            (proc.stdout or b"").decode(
-                                errors="replace").splitlines()):
-                        line = line.strip()
-                        if not line.startswith("{"):
-                            continue
-                        info = _json.loads(line)
-                        secs = float(info.get("compile_seconds") or 0.0)
-                        if secs > 0:
-                            REGISTRY.xla_compiles.inc()
-                            REGISTRY.xla_compile_seconds.observe(secs)
-                            tracing.RECORDER.record(
-                                "xla.compile", time.time() - secs, secs,
-                                {"entry": "warmcompile", "kind": kind})
-                        break
-                except Exception:
-                    pass
-                # phase 2: dummy call through THIS process's jit cache —
-                # a cache hit from phase 1 loads in seconds; on any
-                # subprocess failure this is the full (fallback) compile
-                ztbl = jnp.zeros((COMB_WINDOWS, COMB_DIGITS, vb, 3, 32),
-                                 jnp.uint8)
-                zok = jnp.zeros((vb,), bool)
-                if kind == "templated":
-                    b, tb, mlen = shape
-                    out = self._dev.verify_grouped_templated_jit(
-                        ztbl, zok, jnp.zeros((vb, 32), jnp.uint8),
-                        jnp.zeros((b,), jnp.int32),
-                        jnp.zeros((b,), jnp.int32),
-                        jnp.zeros((tb, mlen), jnp.uint8),
-                        jnp.zeros((b, 64), jnp.uint8), self._base_tbl)
-                else:
-                    b, mlen = shape
-                    # pubkeys here are PER-LANE (challenge-hash input),
-                    # so the warm shape is the lane bucket, not vb
-                    out = self._dev.verify_grouped_jit(
-                        ztbl, zok, jnp.zeros((b,), jnp.int32),
-                        jnp.zeros((b, 32), jnp.uint8),
-                        jnp.zeros((b, mlen), jnp.uint8),
-                        jnp.zeros((b, 64), jnp.uint8), self._base_tbl)
-                out.block_until_ready()
-            except Exception:
-                pass                   # warm-up is best-effort only
+            ztbl = jnp.zeros((COMB_WINDOWS, COMB_DIGITS, vb, 3, 32),
+                             jnp.uint8)
+            zok = jnp.zeros((vb,), bool)
+            if kind == "templated":
+                b, tb, mlen = shape
+                out = self._dev.verify_grouped_templated_jit(
+                    ztbl, zok, jnp.zeros((vb, 32), jnp.uint8),
+                    jnp.zeros((b,), jnp.int32),
+                    jnp.zeros((b,), jnp.int32),
+                    jnp.zeros((tb, mlen), jnp.uint8),
+                    jnp.zeros((b, 64), jnp.uint8), self._base_tbl)
+            else:
+                b, mlen = shape
+                # pubkeys here are PER-LANE (challenge-hash input),
+                # so the warm shape is the lane bucket, not vb
+                out = self._dev.verify_grouped_jit(
+                    ztbl, zok, jnp.zeros((b,), jnp.int32),
+                    jnp.zeros((b, 32), jnp.uint8),
+                    jnp.zeros((b, mlen), jnp.uint8),
+                    jnp.zeros((b, 64), jnp.uint8), self._base_tbl)
+            out.block_until_ready()
 
         t = threading.Thread(target=warm, daemon=True)
         t.start()
@@ -795,47 +784,68 @@ class TpuBackend:
         return out[:n]
 
 
+# The persistent caches (XLA executables here, comb tables under
+# tables/) live where JAX_COMPILATION_CACHE_DIR says.  jax reads that
+# variable itself, so when it is set this module sets no directory in
+# code; when it is not, everything that compiles — the node, bench.py,
+# chip_smoke.py, the test suite — shares ONE fixed path inside the
+# checkout.  The path is part of jax's cache key, so a cache that moves
+# never hits; a sealed machine that carries this one directory from run
+# to run starts warm.
+_CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".tm_cache")
+
+
+def compile_cache_dir() -> str:
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or _CHECKOUT_CACHE_DIR
+
+
 _cache_enabled = False
+_compile_tls = threading.local()
 
 
-def _enable_compile_cache() -> None:
+def enable_compile_cache() -> None:
     """Persistent XLA compilation cache: the ed25519/merkle graphs take
-    30-120s to compile cold, which would otherwise be paid again on every
+    minutes to compile cold, which would otherwise be paid again on every
     node restart (the restart path JITs during WAL replay)."""
     global _cache_enabled
     if _cache_enabled:
         return
     _cache_enabled = True
     import jax
-    cache_dir = os.environ.get(
-        "TM_JAX_CACHE_DIR",
-        os.path.join(os.path.expanduser("~"), ".cache", "tendermint_tpu",
-                     "jax"))
-    try:
-        os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    except Exception:
-        pass  # cache is an optimization; never block startup on it
-    try:
-        # count REAL backend compiles (the monitoring event fires only
-        # when XLA actually compiles — persistent-cache loads and jit
-        # cache hits stay silent), and drop a retroactive span into the
-        # flight recorder so the doctor attributes the interval to
-        # `compile` rather than device-idle
-        from jax import monitoring as _monitoring
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        os.makedirs(_CHECKOUT_CACHE_DIR, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", _CHECKOUT_CACHE_DIR)
+    from jax import monitoring
 
-        def _on_compile(event: str, duration: float, **kw) -> None:
-            if "backend_compile" not in event:
-                return
+    # jax wraps BOTH outcomes of a compile request in the
+    # backend_compile_duration event: a real XLA compile and a load from
+    # the persistent cache.  The cache_hits event fires first, on the
+    # same thread, so a thread-local flag tells them apart: real
+    # compiles count in xla_compiles, loads in xla_persistent_cache_hits.
+    # Either way a retroactive span lands in the flight recorder so the
+    # doctor attributes the interval to `compile`, not device-idle.
+    def _on_event(event: str, **kw) -> None:
+        if event == "/jax/compilation_cache/cache_hits":
+            _compile_tls.hit = True
+
+    def _on_duration(event: str, duration: float, **kw) -> None:
+        if "backend_compile" not in event:
+            return
+        hit = getattr(_compile_tls, "hit", False)
+        _compile_tls.hit = False
+        if hit:
+            REGISTRY.xla_persistent_cache_hits.inc()
+        else:
             REGISTRY.xla_compiles.inc()
             REGISTRY.xla_compile_seconds.observe(duration)
-            tracing.RECORDER.record("xla.compile", time.time() - duration,
-                                    duration, {"event": event})
+        tracing.RECORDER.record(
+            "xla.compile", tracing.now_epoch() - duration, duration,
+            {"fn": kw.get("fun_name"), "cached": hit})
 
-        _monitoring.register_event_duration_secs_listener(_on_compile)
-    except Exception:
-        pass  # observability must never block startup either
+    monitoring.register_event_listener(_on_event)
+    monitoring.register_event_duration_secs_listener(_on_duration)
 
 
 def _native_backend():
@@ -899,16 +909,20 @@ def get_backend() -> Backend:
                 raise ValueError(
                     f"unknown TM_CRYPTO_BACKEND={name!r}; "
                     f"known: {sorted(_BACKENDS)}")
-            try:
-                _current = _BACKENDS[name]()
-            except ImportError as e:
-                import warnings
-                warnings.warn(
-                    f"crypto backend {name!r} unavailable ({e}); "
-                    f"falling back to the slow python backend")
-                _current = PythonBackend()
+            _current = _BACKENDS[name]()
             metrics.set_build_info(crypto_backend=_current.name)
     return _current
+
+
+def active_backend_name() -> str:
+    """Name of the backend that would answer a call right now: under the
+    supervised ladder that is its active rung ("tpu" until a breaker
+    demotes it), otherwise the installed backend's own name.  Callers
+    that choose between a device path and a host path ask this, so a
+    supervised node on a healthy device still takes the device path."""
+    be = get_backend()
+    active = getattr(be, "active_rung_name", None)
+    return (active() or "") if active is not None else be.name
 
 
 def verify_batch(pubkeys, msgs, sigs) -> np.ndarray:

@@ -1,10 +1,9 @@
 """SupervisedBackend: runtime fault tolerance for the crypto ladder.
 
-`crypto/backend.py` picks ONE implementation at construction and only
-falls back to `PythonBackend` on ImportError — a mid-flight device
-failure (XLA error, OOM, runtime hang) previously surfaced as an
-exception in consensus or fast-sync, or worse, could be mistaken for a
-bad signature.  Hardware verification pipelines treat accelerator
+`crypto/backend.py` picks ONE implementation at construction and never
+swaps it — a mid-flight device failure (XLA error, OOM, runtime hang)
+surfaces there as an exception in consensus or fast-sync, or worse,
+could be mistaken for a bad signature.  Hardware verification pipelines treat accelerator
 failure as a first-class recoverable event with a slower verified path
 behind it (cf. arXiv:2104.06968, arXiv:2112.02229); this wrapper gives
 the framework that property:
@@ -213,6 +212,24 @@ class SupervisedBackend:
             rung.consecutive_faults = 0
 
     # -- invocation -----------------------------------------------------
+    def _await(self, fut, what: str):
+        """Wait for a device call under `call_timeout_s`.  The first call
+        of a shape compiles (and a cold set builds its tables) for
+        minutes on an empty cache; that is not a hung device, so the
+        deadline is not enforced while the backend reports a cold
+        dispatch in flight — the breaker must not demote the device
+        during boot."""
+        from tendermint_tpu.crypto import backend as cb
+        while True:
+            try:
+                return fut.result(timeout=self.call_timeout_s)
+            except FutureTimeout:
+                if cb.cold_dispatch_in_flight():
+                    continue
+                fut.cancel()
+                raise DeviceFault(f"{what} exceeded the "
+                                  f"{self.call_timeout_s}s call timeout")
+
     def _invoke(self, rung: _Rung, method: str, args: tuple):
         """One attempt on one rung: chaos injection, timeout enforcement,
         latency accounting.  Any exception or timeout from a device rung
@@ -241,14 +258,8 @@ class SupervisedBackend:
             else:
                 try:
                     if self.call_timeout_s > 0:
-                        fut = self._pool.submit(run)
-                        try:
-                            out = fut.result(timeout=self.call_timeout_s)
-                        except FutureTimeout:
-                            fut.cancel()
-                            raise DeviceFault(
-                                f"{rung.name}.{method} exceeded the "
-                                f"{self.call_timeout_s}s call timeout")
+                        out = self._await(self._pool.submit(run),
+                                          f"{rung.name}.{method}")
                     else:
                         out = run()
                 except DeviceFault:
@@ -402,14 +413,8 @@ class SupervisedBackend:
         REGISTRY.crypto_rung_calls.labels(rung.name).inc()
         try:
             if self.call_timeout_s > 0 and rung.is_device:
-                fut = self._pool.submit(run)
-                try:
-                    return fut.result(timeout=self.call_timeout_s)
-                except FutureTimeout:
-                    fut.cancel()
-                    raise DeviceFault(
-                        f"{rung.name}.dispatch exceeded the "
-                        f"{self.call_timeout_s}s call timeout")
+                return self._await(self._pool.submit(run),
+                                   f"{rung.name}.dispatch")
             return run()
         except DeviceFault:
             raise

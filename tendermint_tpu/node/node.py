@@ -170,16 +170,12 @@ class Node:
                          name="crypto-precompile").start()
 
     def _maybe_build_p2p(self) -> None:
-        """Wire the p2p stack when available; solo nodes skip it
-        (reference runs alone with fast_sync off, node/node.go:117-125)."""
+        """Wire the p2p stack when a listen address is configured; a
+        node configured solo (empty p2p.laddr) skips it (reference runs
+        alone with fast_sync off, node/node.go:117-125)."""
         if not self.config.p2p.laddr:
             return
-        try:
-            from tendermint_tpu.node.p2p_setup import build_p2p
-        except ImportError:
-            log.warn("p2p.laddr is set but the p2p stack is unavailable; "
-                     "running solo with no networking")
-            return
+        from tendermint_tpu.node.p2p_setup import build_p2p
         self.switch = build_p2p(self)
 
     # -- lifecycle ------------------------------------------------------

@@ -262,8 +262,8 @@ def build_affine_comb(Q) -> tuple:
     def window_step(row, _):
         packed, ok = _affine_pack(row)
         # x1024 = shift one window up; fori keeps ONE doubling body in
-        # the graph (10 inline copies of the 12-mul dbl were a large
-        # slice of the build's 130s+ XLA compile, VERDICT r4 #3)
+        # the graph (10 inline copies of the 12-mul dbl are ten times
+        # its share of the build's XLA compile)
         nxt = lax.fori_loop(0, COMB_WBITS, lambda _, p: pt_dbl(p), row)
         return nxt, (packed, ok)
 
@@ -340,7 +340,7 @@ def _base_table() -> np.ndarray:
     """np.uint8[22, 4096, 3, 32]: window w, digit j -> affine precomp of
     j * 2^(12w) * B as (y+x, y-x, 2d*x*y) canonical byte rows.
 
-    12-bit windows (VERDICT r3 lever): 22 mixed adds per [s]B instead of
+    12-bit windows: 22 mixed adds per [s]B instead of
     the 8-bit comb's 32 — the ~8.6 MB table stays device-resident.  Built
     once host-side from the golden bigint reference (~90k bigint adds,
     well under a second) and lru-cached for the process.
@@ -397,7 +397,7 @@ def scalar_mul_base(s: jnp.ndarray, tbl: jnp.ndarray | None = None) -> tuple:
 
     Pass the table (`_base_table()` uploaded once) as `tbl` from jitted
     entry points: baked in as a graph literal the 8.6 MB constant adds
-    ~5s of XLA compile per executable (measured v5e, VERDICT r4 #3)."""
+    seconds of XLA compile to every executable that carries it."""
     if tbl is None:
         tbl = jnp.asarray(_base_table())       # [22, 4096, 3, 32]
     digits = jnp.moveaxis(digits12(s), -1, 0)  # [22, ...]

@@ -122,13 +122,16 @@ def mul_basic(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     contracted against the fixed 0/1 anti-diagonal matrix on the MXU with
     Precision.HIGHEST (full f32: column sums <= 32*512^2 < 2^24 stay
     exact; the TPU default bf16 passes would truncate).  XLA compiles a
-    plain dot in well under a second where the previous padded-row
-    formulation (32 pads + stack + sum per mul) ballooned chain graphs —
-    a 20-mul chain measured 69s to compile vs 5s for this form, which is
-    what made the 10-bit comb build pay 130s+ of jit (VERDICT r4 #3).
-    Works for any rank (the conv form's >2-d Mosaic SIGABRT does not
-    apply); runtime is within ~25% of the conv on 2-d shapes, so the
-    conv stays the hot-verify mul and this serves everything else.
+    plain dot quickly where a padded-row formulation (32 pads + stack +
+    sum per mul) balloons chain graphs, which is what the long
+    table-build chains need.  Works for any rank.
+
+    Seen on jax 0.9.0 / libtpu 0.0.34, TPU v5 lite (PERF.md, PR 21): at
+    8,192 lanes this form and the conv form in `mul` agree limb for limb
+    and with Python ints on the +-512 extremes; one isolated product
+    compiles in 4.3 s (conv: 6.8 s) and both run in 0.44 ms, i.e. at the
+    dispatch floor — which form is faster inside the verify graph has
+    not been measured on this stack.
     """
     shape = jnp.broadcast_shapes(a.shape, b.shape)
     af = jnp.broadcast_to(a, shape).astype(jnp.float32)
@@ -149,13 +152,22 @@ def mul(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     under the |limb| <= 512 invariant every column sum is below
     32*512*512 < 2^24, so f32 accumulation is exact, and
     `Precision.HIGHEST` pins the TPU conv to f32-faithful passes.  The
-    conv edges out `mul_basic`'s matmul form by ~25% at steady state but
-    costs ~4x more XLA compile time, so it serves only the flat hot-path
-    shapes: big 2-d batches.  Small batches (< 4096 lanes — table-build
-    chains over V validators, recursion totals) take `mul_basic`, where
-    runtime is negligible and compile time is what hurts; shapes deeper
-    than 2-d also fall back (the conv+reshape combination SIGABRTs the
-    TPU compiler there).
+    conv serves only the flat hot-path shapes (2-d, >= 4096 lanes: the
+    verify kernel's lane batches); small batches — table-build chains
+    over V validators, recursion totals — and shapes deeper than 2-d take
+    `mul_basic`.
+
+    Seen on jax 0.9.0 / libtpu 0.0.34, TPU v5 lite (PERF.md, PR 21): the
+    conv compiles and is exact (see `mul_basic`), and the verify graphs
+    built on it agree with OpenSSL lane for lane at 8,192 and 65,536
+    lanes.  The >2-d restriction dates from a compiler build that
+    aborted on conv+reshape there; on this stack a [64, 128, 32] operand
+    compiled and ran, alone and inside a `lax.scan` body, with results
+    equal to the 2-d form.  The split is kept as it was: lifting it
+    changes the table-build and inversion graphs and is a perf_opt
+    issue's to measure.  On the CPU XLA backend the grouped conv is
+    pathological (33 s per call at 8,192 groups); the test suite never
+    reaches 4,096 lanes.
     """
     shape = jnp.broadcast_shapes(a.shape, b.shape)
     flat = 1
@@ -332,8 +344,8 @@ _E40 = 40  # per-limb lift clearing the [-39, +] residual range
 def canonical(x: jnp.ndarray) -> jnp.ndarray:
     """Fully reduce to the canonical representative in [0, p), limbs [0,255].
 
-    Fully parallel (VERDICT r3: the sequential 64-step carry chain here
-    was ~20% of the grouped-verify step): parallel carry passes leave
+    Fully parallel (a sequential 64-step carry chain here was a fifth of
+    the grouped-verify step): parallel carry passes leave
     limbs in [-39, 333]; lifting by +40 per limb makes them nonnegative
     for an exact Kogge-Stone normalize, a borrow-lookahead subtraction
     takes the lift back out, the net 2^256 wrap folds by 38, and two

@@ -88,13 +88,26 @@ def _compress(state, w16):
     return tuple(s + n for s, n in zip(state, out[:8]))
 
 
+# Messages longer than this many 64-byte blocks scan over blocks instead
+# of unrolling them: a 64 KiB block part is 1,025 blocks, and unrolled
+# that is 1,025 copies of the compression scan for XLA to compile (the
+# CPU backend was still lowering it after 10 minutes).  Short messages
+# (tree nodes, tx leaves: 1-3 blocks) keep the unrolled form.
+_SCAN_BLOCKS = 8
+
+
 def sha256_blocks(blocks: jnp.ndarray) -> jnp.ndarray:
     """Hash pre-padded big-endian words uint32[B, nblocks, 16] -> uint32[B, 8]."""
     nblocks = blocks.shape[-2]
     state = tuple(jnp.broadcast_to(jnp.uint32(h), blocks.shape[:-2])
                   for h in _H0)
-    for i in range(nblocks):
-        state = _compress(state, blocks[..., i, :])
+    if nblocks <= _SCAN_BLOCKS:
+        for i in range(nblocks):
+            state = _compress(state, blocks[..., i, :])
+    else:
+        state, _ = jax.lax.scan(
+            lambda st, w16: (_compress(st, w16), None), state,
+            jnp.moveaxis(blocks, -2, 0))
     return jnp.stack(state, axis=-1)
 
 

@@ -170,9 +170,8 @@ def sharded_grouped_verify_fn(mesh: Mesh, axis: str = "batch"):
     chip runs the whole kernel locally and only the output gather
     touches ICI.
     """
-    from jax.experimental.shard_map import shard_map
-    fn = shard_map(
+    fn = jax.shard_map(
         _ed.verify_grouped, mesh=mesh,
         in_specs=(P(), P(), P(axis), P(axis), P(axis), P(axis), P()),
-        out_specs=P(axis), check_rep=False)
+        out_specs=P(axis), check_vma=False)
     return jax.jit(fn)

@@ -23,6 +23,7 @@ import numpy as np
 
 from tendermint_tpu.types import merkle
 from tendermint_tpu.types.codec import Reader, lp_bytes, u32
+from tendermint_tpu.utils import tracing
 
 PART_SIZE = 64 * 1024  # reference types/block.go:19
 
@@ -149,22 +150,23 @@ class PartSet:
 def _device_full_chunk_hashes(chunks: list[bytes],
                               part_size: int) -> list[bytes] | None:
     """Leaf-hash equal-size chunks in one lockstep device batch; None when
-    the device would lose to host hashlib (small batch, no tpu backend)."""
+    the device would lose to host hashlib (small batch) or the crypto
+    plane is not on the device right now (python/native backend, or a
+    supervised ladder demoted off its tpu rung)."""
     if len(chunks) < DEVICE_MIN_CHUNKS:
         return None
     from tendermint_tpu.crypto import backend as cb
-    if cb.get_backend().name != "tpu":
+    if cb.active_backend_name() != "tpu":
         return None
-    try:
-        from tendermint_tpu.ops import merkle as dev_merkle
-    except ImportError:                  # pragma: no cover - env dependent
-        return None
+    from tendermint_tpu.ops import merkle as dev_merkle
     n = len(chunks)
     b = 1 << (n - 1).bit_length()        # pad count to a power of two so a
     pad = b - n                          # few compiled shapes cover any load
     arr = np.frombuffer(b"".join(chunks) + b"\x00" * (pad * part_size),
                         np.uint8).reshape(b, part_size)
-    h = np.asarray(dev_merkle.leaf_hashes_jit(arr))
+    with tracing.span("parthash.device", cat=tracing.CAT_DEVICE, chunks=n,
+                      bucket=b):
+        h = np.asarray(dev_merkle.leaf_hashes_jit(arr))
     return [h[i].tobytes() for i in range(n)]
 
 

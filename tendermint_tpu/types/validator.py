@@ -190,7 +190,7 @@ class ValidatorSet:
 
         Vectorized: the per-step Python max over (accum, sort_key)
         tuples was ~0.2 ms/block at V=100 — a leading slice of the
-        fast-sync apply stage (VERDICT r4 #5).  numpy argmax decides;
+        fast-sync apply stage.  numpy argmax decides;
         the byte-string tie-break only runs on actual accum ties
         (equal-power sets at specific heights)."""
         vals = self.validators

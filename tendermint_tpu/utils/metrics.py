@@ -319,6 +319,7 @@ class Registry:
         # to kill; a recompile on a warm entry means SHAPE DRIFT — the
         # bucketing in crypto/backend._bucket() leaked a new padded shape
         self.xla_compiles = Counter()           # real backend compiles
+        self.xla_persistent_cache_hits = Counter()  # loads from disk cache
         self.xla_compile_seconds = Summary()    # per-compile duration
         self.xla_first_call_seconds = Summary()  # first dispatch per entry
         self.xla_cache_hits = Counter()         # dispatch on a warm shape
@@ -432,6 +433,8 @@ class Registry:
             "crypto_rung_calls": dict(self.crypto_rung_calls.items()),
             "crypto_rung_faults": dict(self.crypto_rung_faults.items()),
             "xla_compiles": self.xla_compiles.value,
+            "xla_persistent_cache_hits":
+                self.xla_persistent_cache_hits.value,
             "xla_compile_seconds_mean":
                 round(self.xla_compile_seconds.mean, 3),
             "xla_cache_hits": self.xla_cache_hits.value,

@@ -2,13 +2,17 @@
 
 Builds the shared library on demand with g++ (the environment's native
 toolchain; no pybind11) into the repo's native/ dir, caching the .so next
-to its source.  Every entry point degrades to None when the toolchain or
-library is unavailable — callers fall back to hashlib paths.
+to its source.  The .so is git-ignored, so a checkout never carries one:
+it is rebuilt when absent or when the source's SHA-256 differs from the
+one recorded beside it at build time (mtimes say nothing after a copy).
+Every entry point degrades to None when the toolchain or library is
+unavailable — callers fall back to hashlib paths.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -28,9 +32,26 @@ _NATIVE_DIR = os.path.join(
         os.path.abspath(__file__)))), "native")
 _SRC = os.path.join(_NATIVE_DIR, "tmhash.cpp")
 _SO = os.path.join(_NATIVE_DIR, "libtmhash.so")
+_SO_SRC_HASH = _SO + ".src.sha256"     # hash of the source _SO was built from
+
+# "built" | "reused" | "unavailable" once get() has run, else None
+build_status: str | None = None
 
 
-def _build() -> bool:
+def _src_hash() -> str:
+    with open(_SRC, "rb") as f:
+        return hashlib.sha256(f.read()).hexdigest()
+
+
+def _up_to_date(src_hash: str) -> bool:
+    try:
+        with open(_SO_SRC_HASH) as f:
+            return os.path.exists(_SO) and f.read().strip() == src_hash
+    except OSError:
+        return False
+
+
+def _build(src_hash: str) -> bool:
     try:
         r = subprocess.run(
             ["g++", "-O2", "-std=c++17", "-fPIC", "-pthread", "-shared",
@@ -39,6 +60,8 @@ def _build() -> bool:
         if r.returncode != 0:
             log.warn("native build failed", err=r.stderr[-500:])
             return False
+        with open(_SO_SRC_HASH, "w") as f:
+            f.write(src_hash + "\n")
         return True
     except (OSError, subprocess.TimeoutExpired) as e:
         log.warn("native build unavailable", err=str(e))
@@ -47,22 +70,24 @@ def _build() -> bool:
 
 def get() -> ctypes.CDLL | None:
     """The loaded library, building it if needed; None when unavailable."""
-    global _lib, _tried
+    global _lib, _tried, build_status
     with _lock:
         if _lib is not None or _tried:
             return _lib
         _tried = True
+        build_status = "unavailable"
         if not os.path.exists(_SRC):
             return None
-        if (not os.path.exists(_SO) or
-                os.path.getmtime(_SO) < os.path.getmtime(_SRC)):
-            if not _build():
-                return None
+        src_hash = _src_hash()
+        reused = _up_to_date(src_hash)
+        if not reused and not _build(src_hash):
+            return None
         try:
             lib = ctypes.CDLL(_SO)
         except OSError as e:
             log.warn("native lib load failed", err=str(e))
             return None
+        build_status = "reused" if reused else "built"
         u8p = ctypes.POINTER(ctypes.c_uint8)
         lib.tm_leaf_hashes.argtypes = [u8p, ctypes.c_uint64,
                                        ctypes.c_uint64, u8p,

@@ -20,10 +20,12 @@ import pytest  # noqa: E402
 jax.config.update("jax_platforms", "cpu")
 
 # Persistent compilation cache: the crypto kernels take ~1min to compile on
-# the CPU backend; cache them across test runs.
-_cache_dir = os.path.join(os.path.dirname(__file__), ".jax_cache")
-jax.config.update("jax_compilation_cache_dir", _cache_dir)
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+# the CPU backend; cache them across test runs — in the one directory the
+# program itself uses (JAX_COMPILATION_CACHE_DIR, else .tm_cache/ in the
+# checkout), enabled here before any test can compile.
+from tendermint_tpu.crypto import backend as _cb  # noqa: E402
+
+_cb.enable_compile_cache()
 
 
 @pytest.hookimpl(hookwrapper=True)
@@ -62,7 +64,7 @@ def _flight_recorder_postmortem(request):
 @pytest.fixture(autouse=True)
 def _isolate_table_disk_cache(tmp_path, monkeypatch):
     """Every test gets a private comb-table disk cache: without this,
-    tests would persist tables into the developer's real ~/.cache and
+    tests would persist tables into the shared cache directory and
     later runs could verify against STALE tables whenever a test changes
     its key generation under an unchanged set_key label."""
     monkeypatch.setenv("TM_TABLE_CACHE_DIR", str(tmp_path / "_tblcache"))

@@ -1,8 +1,8 @@
 """Capture-proof bench harness tests (bench.py): atomic partial-results
 checkpointing, headline-so-far selection, the wall-clock budget manager,
 the fixture cache, and the SIGTERM flush path — the guarantee that a
-`timeout`-killed bench still leaves a parseable report (BENCH_r05 died
-at rc=124 with parsed: null)."""
+`timeout`-killed bench still leaves a parseable report (one such run
+died at rc=124 with nothing parsed)."""
 
 import json
 import os

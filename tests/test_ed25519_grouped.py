@@ -163,15 +163,29 @@ def test_backend_templated_matches_plain():
     assert not got[4] and got[5]
 
 
-def test_table_cache_byte_bounded_keeps_small_sets():
+def test_table_cache_byte_bounded_keeps_small_sets(monkeypatch):
     """Regression for the multi-chain churn: one big validator set plus
     many small light-chain sets must ALL stay resident (the old count
     bound of 8 evicted small tables whenever big ones rotated in, and
-    the streaming loop then paid full rebuilds mid-flight)."""
+    the streaming loop then paid full rebuilds mid-flight).
+
+    What is under test is the backend's residency accounting, so the
+    device build is stubbed with zero tables of the real shape: eleven
+    real builds cost 4.5 minutes on the CPU backend — a third of the
+    whole tier-1 budget — and the build itself is covered by the tests
+    around this one."""
+    import jax.numpy as jnp
     import numpy as np
     from tendermint_tpu.crypto import pure_ed25519 as ref
     from tendermint_tpu.crypto.backend import TpuBackend
+    from tendermint_tpu.ops import ed25519 as dev
+    from tendermint_tpu.ops.curve import COMB_DIGITS, COMB_WINDOWS
 
+    monkeypatch.setattr(
+        dev, "build_neg_comb_jit",
+        lambda pubs: (jnp.zeros((COMB_WINDOWS, COMB_DIGITS, len(pubs), 3,
+                                 32), jnp.uint8),
+                      jnp.ones((len(pubs),), bool)))
     be = TpuBackend()
     sigs = np.zeros((4, 64), np.uint8)
     msgs = np.zeros((4, 128), np.uint8)

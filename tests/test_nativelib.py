@@ -26,3 +26,24 @@ def test_merkle_roots_match_host(n):
     for t in range(4):
         want = host.root([leaves[t, i].tobytes() for i in range(n)])
         assert got[t].tobytes() == want
+
+
+def test_reuse_is_decided_by_source_hash_not_mtime(tmp_path, monkeypatch):
+    """The .so is reused only when the hash recorded beside it at build
+    time equals the source's: a copied tree has fresh mtimes everywhere,
+    and git never carries the binary."""
+    so = tmp_path / "libtmhash.so"
+    rec = tmp_path / "libtmhash.so.src.sha256"
+    monkeypatch.setattr(nativelib, "_SO", str(so))
+    monkeypatch.setattr(nativelib, "_SO_SRC_HASH", str(rec))
+    h = nativelib._src_hash()
+    assert not nativelib._up_to_date(h)          # nothing built yet
+    so.write_bytes(b"\x7fELF")
+    assert not nativelib._up_to_date(h)          # binary, no record
+    rec.write_text("0" * 64 + "\n")
+    assert not nativelib._up_to_date(h)          # built from other source
+    rec.write_text(h + "\n")
+    assert nativelib._up_to_date(h)
+    so.unlink()
+    assert not nativelib._up_to_date(h)          # record, no binary
+    assert nativelib.build_status in ("built", "reused")

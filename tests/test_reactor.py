@@ -144,7 +144,7 @@ def test_late_joiner_catches_up_through_gossip():
 
 
 def test_sleeper_recovers_through_gossip():
-    """VERDICT r4 regression: a node that sleeps through commits must
+    """Regression: a node that sleeps through commits must
     recover via consensus gossip alone, within seconds, without
     fast-sync.  The victim's consensus mutex is held from outside — its
     receive loop, gossip snapshots, and vote handling all block, exactly
