@@ -1,0 +1,231 @@
+"""The served chain, made from the seed by code that is not under test.
+
+Copied from `chip_smoke.build_chain` (listed in PERF.md for a later PR to
+merge) and changed where the benchmark needs it: every signature is
+OpenSSL's (`cryptography`), with ONE key object per validator kept for
+the whole build, and a height's signatures fanned out to worker
+processes when the set is large; hashes are hashlib's (no crypto backend
+is installed in the process that builds); app hashes come from a plain
+reference of the kvstore app (`RefKVStore`) the chain is applied to here.
+The program's own types only give the blocks their wire format.  What the builder keeps per height
+is what a source peer serves (the encoded block) and what the check
+needs (block hash, app hash after the height, encoded size).
+
+Nothing in this module may import jax: it runs in the source child.
+"""
+
+from __future__ import annotations
+
+import hashlib
+import os
+import subprocess
+import sys
+
+from cryptography.hazmat.primitives.asymmetric.ed25519 import \
+    Ed25519PrivateKey
+
+from benchmark.lib import signer
+
+POWER = 10
+GENESIS_TIME_NS = 1_000_000_000
+# below this many validators a pipe round trip costs more than signing
+MIN_VALS_FOR_WORKERS = 32
+
+
+def val_seed(seed: int, i: int) -> bytes:
+    return hashlib.sha256(b"tm-bench/%d/val/%d" % (seed, i)).digest()
+
+
+def make_validators(seed: int, n: int):
+    """(seeds aligned with the set's validator order, ValidatorSet).
+    Public keys are OpenSSL's; the program's ValidatorSet only orders
+    them (by address) and hashes the set for the headers."""
+    from tendermint_tpu.types import Validator, ValidatorSet
+    from tendermint_tpu.types.keys import PubKey
+    seeds = [val_seed(seed, i) for i in range(n)]
+    pubs = {s: Ed25519PrivateKey.from_private_bytes(s).public_key()
+            .public_bytes_raw() for s in seeds}
+    vs = ValidatorSet([Validator(PubKey(pubs[s]), POWER) for s in seeds])
+    by_addr = {PubKey(pubs[s]).address: s for s in seeds}
+    return [by_addr[v.address] for v in vs.validators], vs
+
+
+def genesis_dict(chain_id: str, vs) -> dict:
+    return {"chain_id": chain_id, "genesis_time_ns": GENESIS_TIME_NS,
+            "power": POWER,
+            "validators": [v.pub_key.bytes_.hex() for v in vs.validators]}
+
+
+def genesis_doc(g: dict):
+    from tendermint_tpu.types import GenesisDoc, GenesisValidator
+    return GenesisDoc(chain_id=g["chain_id"],
+                      genesis_time_ns=g["genesis_time_ns"],
+                      validators=[GenesisValidator(bytes.fromhex(p),
+                                                   g["power"])
+                                  for p in g["validators"]])
+
+
+class RefKVStore:
+    """The plain reference of the kvstore app's state commitment, written
+    from its description (`abci/apps/kvstore.py`: keys shard into 256
+    buckets by the first byte of sha256(key); a bucket's digest is
+    sha256 over its sorted length-prefixed pairs, never-written buckets
+    are 32 zero bytes; the app hash is the first 20 bytes of sha256 over
+    the 256 digests and the height).  It re-hashes a bucket once per
+    commit, not once per write, and shares no code with the program."""
+
+    def __init__(self):
+        self.height = 0
+        self._buckets = [{} for _ in range(256)]
+        self._digests = [bytes(32)] * 256
+        self._dirty: set[int] = set()
+
+    def deliver_tx(self, tx: bytes) -> None:
+        k, _, v = tx.partition(b"=") if b"=" in tx else (tx, b"", tx)
+        b = hashlib.sha256(k).digest()[0]
+        self._buckets[b][k] = v
+        self._dirty.add(b)
+
+    def commit(self) -> bytes:
+        for b in self._dirty:
+            h = hashlib.sha256()
+            for k in sorted(self._buckets[b]):
+                v = self._buckets[b][k]
+                h.update(len(k).to_bytes(4, "big") + k +
+                         len(v).to_bytes(4, "big") + v)
+            self._digests[b] = h.digest()
+        self._dirty.clear()
+        self.height += 1
+        return hashlib.sha256(b"".join(self._digests) +
+                              self.height.to_bytes(8, "big")).digest()[:20]
+
+
+class Signers:
+    """Signs one message with every validator key, in set order."""
+
+    def __init__(self, seeds: list[bytes], workers: int | None = None):
+        self._n = len(seeds)
+        if workers is None:
+            workers = (min(8, max(1, (os.cpu_count() or 2) - 3))
+                       if self._n >= MIN_VALS_FOR_WORKERS else 0)
+        self._procs: list[subprocess.Popen] = []
+        self._keys = None
+        if workers <= 1:
+            self._keys = [Ed25519PrivateKey.from_private_bytes(s)
+                          for s in seeds]
+            return
+        # contiguous slices, so the answers concatenate in set order
+        per = -(-self._n // workers)
+        root = os.path.dirname(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))))
+        try:
+            for w in range(0, self._n, per):
+                p = subprocess.Popen(
+                    [sys.executable, os.path.abspath(signer.__file__)],
+                    stdin=subprocess.PIPE, stdout=subprocess.PIPE, cwd=root)
+                self._procs.append(p)
+                signer.write_frame(p.stdin, b"".join(seeds[w:w + per]))
+            for p in self._procs:
+                if signer.read_frame(p.stdout) != b"ok":
+                    raise RuntimeError("a signing worker did not start")
+        except BaseException:
+            self.close()
+            raise
+
+    def sign_all(self, msg: bytes) -> list[bytes]:
+        if self._keys is not None:
+            return [k.sign(msg) for k in self._keys]
+        for p in self._procs:
+            signer.write_frame(p.stdin, msg)
+        out = b"".join(signer.read_frame(p.stdout) or b""
+                       for p in self._procs)
+        if len(out) != 64 * self._n:
+            raise RuntimeError("a signing worker died")
+        return [out[i:i + 64] for i in range(0, len(out), 64)]
+
+    def close(self) -> None:
+        for p in self._procs:
+            try:
+                p.stdin.close()
+            except OSError:
+                pass
+        for p in self._procs:
+            try:
+                p.wait(timeout=5)
+            except subprocess.TimeoutExpired:
+                p.kill()
+                p.wait()
+        self._procs = []
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        self.close()
+
+
+def block_txs(block: dict, seed: int, h: int) -> list[bytes]:
+    """The txs of height h under a traffic mix's `block`: `txs_per_block`
+    kvstore txs `k<key>=v<height>.<filler>` of `tx_bytes` bytes over
+    `keys` reused keys (constant app state, so a block costs the same to
+    apply at every height).  The filler is one seeded stream per block."""
+    n, size, keys = block["txs_per_block"], block["tx_bytes"], block["keys"]
+    heads = [b"k%d=v%d." % ((h * n + i) % keys, h) for i in range(n)]
+    need = max(0, size - min(len(x) for x in heads))
+    fill = b"".join(
+        hashlib.sha256(b"%d/%d/%d" % (seed, h, j)).hexdigest().encode()
+        for j in range(-(-(need + n) // 64)))
+    return [x + fill[i:i + size - len(x)] if len(x) < size else x
+            for i, x in enumerate(heads)]
+
+
+def build_chain(chain_id: str, seeds, vs, n_blocks: int, block_spec: dict,
+                seed: int, signers: Signers | None = None,
+                keep_objects: bool = False):
+    """Heights 1..n_blocks, each block embedding the +2/3 LastCommit of
+    its predecessor.  Returns a dict of per-height lists (index h-1):
+    `encoded`, `block_hash`, `app_hash` (state after h), and with
+    `keep_objects` also `objects` = (block, part_set, seen_commit)."""
+    from tendermint_tpu.types import (TYPE_PRECOMMIT, Block, BlockID, Commit,
+                                      EMPTY_COMMIT, Vote, ZERO_BLOCK_ID,
+                                      canonical)
+    from tendermint_tpu.types.part_set import PartSet
+    own = signers is None
+    signers = signers or Signers(seeds)
+    app = RefKVStore()
+    vals_hash = vs.hash()
+    addrs = [v.address for v in vs.validators]
+    out = {"encoded": [], "block_hash": [], "app_hash": [], "objects": []}
+    last_commit, last_block_id, app_hash = EMPTY_COMMIT, ZERO_BLOCK_ID, b""
+    try:
+        for h in range(1, n_blocks + 1):
+            txs = block_txs(block_spec, seed, h)
+            block = Block.make(chain_id=chain_id, height=h,
+                               time_ns=GENESIS_TIME_NS + h, txs=txs,
+                               last_commit=last_commit,
+                               last_block_id=last_block_id,
+                               validators_hash=vals_hash, app_hash=app_hash)
+            enc = block.encode()
+            ps = PartSet.from_data(enc)
+            bid = BlockID(block.hash(), ps.header)
+            msg = canonical.sign_bytes(
+                chain_id, TYPE_PRECOMMIT, h, 0, block_hash=bid.hash,
+                parts_hash=bid.parts.hash, parts_total=bid.parts.total)
+            sigs = signers.sign_all(msg)
+            seen = Commit(block_id=bid, precommits=[
+                Vote(validator_address=addrs[i], validator_index=i, height=h,
+                     round=0, type=TYPE_PRECOMMIT, block_id=bid, signature=s)
+                for i, s in enumerate(sigs)])
+            for tx in txs:
+                app.deliver_tx(tx)
+            app_hash = app.commit()
+            out["encoded"].append(enc)
+            out["block_hash"].append(bid.hash)
+            out["app_hash"].append(app_hash)
+            if keep_objects:
+                out["objects"].append((block, ps, seen))
+            last_commit, last_block_id = seen, bid
+    finally:
+        if own:
+            signers.close()
+    return out

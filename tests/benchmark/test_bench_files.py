@@ -1,0 +1,152 @@
+"""BENCHMARK.json against the contract it was written to, and against
+the files it names."""
+
+import json
+import os
+import re
+
+import pytest
+
+from benchutil import REPO
+from benchmark.lib import cell as cell_mod
+from benchmark.lib import reducers, roofline
+
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+PATH = re.compile(r"^[A-Za-z0-9_.\-/]{1,200}$")
+SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
+
+with open(os.path.join(REPO, "BENCHMARK.json")) as _f:
+    BENCH = json.load(_f)
+
+
+def test_top_level_keys_and_limits():
+    assert set(BENCH) == {"command", "paths", "run_seconds", "configs",
+                          "workloads", "end_to_end", "per_layer"}
+    assert os.path.getsize(os.path.join(REPO, "BENCHMARK.json")) <= 65536
+    assert 1 <= BENCH["run_seconds"] <= 51
+    assert all(PATH.match(p) and os.path.isdir(os.path.join(REPO, p))
+               for p in BENCH["paths"])
+    # 2 + 14 x 24 runs of run_seconds + 60, 24 x 180 to compile, 1200 spare
+    assert (2 + 14 * 24) * (BENCH["run_seconds"] + 60) + 24 * 180 + 1200 \
+        <= 43200
+
+
+def test_files_under_paths_are_named_from_allowed_characters():
+    for p in BENCH["paths"]:
+        for d, _dirs, files in os.walk(os.path.join(REPO, p)):
+            if "__pycache__" in d:
+                continue
+            for f in files:
+                rel = os.path.relpath(os.path.join(d, f), REPO)
+                assert PATH.match(rel), rel
+
+
+@pytest.mark.parametrize("c", BENCH["configs"], ids=lambda c: c["name"])
+def test_config_entry_and_file(c):
+    assert set(c) == {"name", "source", "file", "reduced", "why"}
+    assert NAME.match(c["name"]) and len(c["source"]) <= 200
+    assert len(c["why"]) <= 200 and len(c["reduced"]) <= 16
+    assert any(c["file"].startswith(p + "/") for p in BENCH["paths"])
+    with open(os.path.join(REPO, c["file"])) as f:
+        cfg = json.load(f)
+    assert cfg["name"] == c["name"] and cfg["reduced"] == c["reduced"]
+    assert cfg["guarantees"] and cfg["assumed"] and cfg["chips"] == 1
+    assert all(NAME.match(k) and k in cfg for k in c["reduced"])
+    assert any(w["config"] == c["name"] for w in BENCH["workloads"])
+
+
+@pytest.mark.parametrize("w", BENCH["workloads"], ids=lambda w: w["name"])
+def test_workload_entry_and_files(w):
+    assert set(w) == {"name", "config", "traffic", "chips", "why"}
+    assert NAME.match(w["name"]) and NAME.match(w["traffic"])
+    assert w["name"] == f"{w['config']}.{w['traffic']}"
+    assert w["chips"] in (1, 4) and 1 <= len(w["why"]) <= 200
+    cell = cell_mod.load_cell(REPO, w["name"])
+    assert cell["traffic"]["name"] == w["traffic"]
+    assert set(cell["traffic"]["block"]) == {"txs_per_block", "tx_bytes",
+                                             "keys"}
+    # the chain served is whole windows plus the block with the last
+    # commit, and grows with the window
+    n = cell_mod.chain_blocks(cell, BENCH["run_seconds"])
+    assert n % 64 == 1 and n > cell_mod.chain_blocks(cell, 5)
+    assert {m["name"] for m in cell["end_to_end"]} >= {"setup_s"}
+    assert len(cell["end_to_end"]) >= 2 and cell["per_layer"]
+
+
+def test_metrics_follow_the_contract():
+    e2e = {m["name"] for m in BENCH["end_to_end"]}
+    assert "setup_s" in e2e and "sync_blocks_per_s" in e2e
+    for m in BENCH["end_to_end"]:
+        assert set(m) - {"workloads"} == {"name", "unit", "better", "bound",
+                                          "source"}
+        assert 0.01 <= m["bound"] <= 0.25
+        assert m["source"] in ("host_clock", "device_trace")
+    names = [m["name"] for m in BENCH["end_to_end"] + BENCH["per_layer"]]
+    assert len(names) == len(set(names))
+    for m in BENCH["end_to_end"] + BENCH["per_layer"]:
+        assert NAME.match(m["name"]) and UNIT.match(m["unit"]), m
+        assert m["better"] in ("lower", "higher")
+        assert m["source"] in SOURCES
+
+
+@pytest.mark.parametrize("m", BENCH["per_layer"], ids=lambda m: m["name"])
+def test_every_per_layer_metric_is_a_file_with_a_known_reducer(m):
+    assert set(m) - {"workloads"} == {"name", "unit", "better", "source",
+                                      "layer", "moves"}
+    assert m["moves"] in {e["name"] for e in BENCH["end_to_end"]}
+    spec = reducers.load_layer(REPO, m["name"])
+    for k in ("unit", "better", "source", "layer", "moves"):
+        assert spec[k] == m[k], (m["name"], k)
+    if m["name"].endswith("_roofline"):
+        assert m["unit"] == "%"
+
+
+def test_no_layer_file_is_left_out_of_benchmark_json():
+    have = {f[:-5] for f in os.listdir(os.path.join(REPO, "benchmark",
+                                                    "layers"))}
+    assert have == {m["name"] for m in BENCH["per_layer"]}
+
+
+def test_peaks_and_the_kernels_least_time():
+    peaks = roofline.load_peaks()
+    assert "TPU v5 lite" in peaks
+    flops, nbytes = roofline.verify_ops_bytes(8192, 64)
+    assert flops == 8192 * 350 * 2048
+    assert nbytes == 8192 * (48 * 96 + 73) + 64 * 128
+    t, bound = roofline.least_time_s("TPU v5 lite", flops, nbytes)
+    assert bound == "memory" and t == pytest.approx(nbytes / 819e9)
+    pct, _ = roofline.roofline_pct("TPU v5 lite", 8192, 64, 10 * t)
+    assert pct == pytest.approx(10.0)
+    with pytest.raises(KeyError):
+        roofline.least_time_s("TPU v9", flops, nbytes)
+
+
+def test_reducers_read_spans_and_return_nothing_when_nothing_is_there():
+    spans = [{"name": "fastsync.window", "ts": 0.0, "dur": 0.2},
+             {"name": "fastsync.window", "ts": 0.2, "dur": 0.4},
+             {"name": "fastsync.prepare", "ts": 0.2, "dur": 0.1},
+             {"name": "xla.compile", "ts": 0.0, "dur": 2.0,
+              "args": {"fn": "jit(verify)", "cached": True}},
+             {"name": "xla.compile", "ts": 0.0, "dur": 0.5,
+              "args": {"fn": "jit(zeros)", "cached": False}}]
+    ctx = {"spans": spans, "boot_spans": spans, "trace": None, "notes": [],
+           "hists": {"batchplane_wait_seconds": {"fastsync": (4, 0.1)}},
+           "harness": {"hbm_peak_MiB": None}}
+    assert reducers.span_ms_per(ctx, ["fastsync.window"],
+                                "fastsync.window") == pytest.approx(300.0)
+    assert reducers.span_ms_per(ctx, ["fastsync.prepare"],
+                                "fastsync.window") == pytest.approx(50.0)
+    assert reducers.span_ms_per(ctx, ["x"], "fastsync.apply") is None
+    assert reducers.span_hit_pct(ctx, "fastsync.prepare",
+                                 "fastsync.window") == pytest.approx(50.0)
+    assert reducers.span_sum_s(ctx, "xla.compile", {"cached": True},
+                               "boot") == pytest.approx(2.0)
+    assert reducers.hist_mean_ms(ctx, "batchplane_wait_seconds",
+                                 "fastsync") == pytest.approx(25.0)
+    assert reducers.hist_mean_ms(ctx, "batchplane_wait_seconds",
+                                 "light") is None
+    assert reducers.harness(ctx, "hbm_peak_MiB") is None
+    assert reducers.trace_idle_pct(ctx) is None
+    assert reducers.trace_kernel_ms_per_window(
+        ctx, "verify_grouped_templated") is None
