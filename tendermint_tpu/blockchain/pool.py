@@ -141,6 +141,11 @@ class BlockPool:
                 self._peer_pending[peer] = \
                     self._peer_pending.get(peer, 0) + 1
                 out.append((slot.height, peer))
+                # the protocol working, not a refusal (`pool.redo`):
+                # `old` may still answer, and the reactor then counts
+                # that block as `pool.late_block`
+                tracing.instant("pool.rerequest", height=slot.height,
+                                old=old[:12], new=peer[:12])
             # new requests
             h = self.next_height
             while len(self._slots) < MAX_PENDING:

@@ -30,7 +30,7 @@ from tendermint_tpu.types.validator import (CommitFormatError,
                                             CommitPowerError,
                                             CommitSignatureError,
                                             verify_commits_batched)
-from tendermint_tpu.utils import tracing
+from tendermint_tpu.utils import attribution, tracing
 from tendermint_tpu.utils.chaos import DeviceFault
 from tendermint_tpu.utils.log import get_logger
 from tendermint_tpu.utils.metrics import REGISTRY
@@ -69,13 +69,17 @@ class _Lookahead:
         try:
             with tracing.span("fastsync.lookahead",
                               first_height=self.first_height,
-                              blocks=len(self._blocks)):
+                              blocks=len(self._blocks)) as args:
+                cpu0 = time.thread_time()
                 window, parts_list, items = \
                     BlockchainReactor._prepare_window(self._blocks,
                                                       self.vals_hash)
                 if window:
                     verify_commits_batched(self._vals, self._chain_id,
                                            items)
+                # this thread's own CPU: wall - cpu_s is what it waited,
+                # for the GIL (apply runs meanwhile) or for the device
+                args["cpu_s"] = time.thread_time() - cpu0
             self.window, self.parts_list, self.items = (window, parts_list,
                                                         items)
         except BaseException as e:
@@ -167,16 +171,29 @@ class BlockchainReactor(Reactor):
                 peer.try_send(BLOCKCHAIN_CHANNEL, BM.encode_msg(
                     BM.NoBlockResponse(msg.height)))
         elif isinstance(msg, BM.BlockResponse):
+            t0 = time.perf_counter()
             try:
                 block = msg.block()
             except (ValueError, IndexError) as e:
                 self.switch.report_misbehavior(peer.id, f"bad block: {e}")
                 self.switch.stop_peer_for_error(peer, f"bad block: {e}")
                 return
+            # one record a block, in the p2p receive thread, which
+            # decodes the next windows while apply runs; bookkeeping
+            # (CAT_NONE), so the window histograms read as before
+            tracing.RECORDER.record(
+                "fastsync.decode", tracing.perf_to_epoch(t0),
+                time.perf_counter() - t0, None, cat=tracing.CAT_NONE)
             if self.pool.add_block(peer.id, block):
                 # feed the peer's flowrate meter — the slow-drip
                 # eviction (reference minRecvRate) keys off this
                 self.pool.record_bytes(peer.id, len(raw))
+            else:
+                # delivered and dropped: the slot is no longer this
+                # peer's (re-requested after a timeout), is already
+                # filled, or was never asked for
+                tracing.instant("pool.late_block", height=block.height,
+                                peer=peer.id[:12], bytes=len(raw))
         elif isinstance(msg, BM.StatusRequest):
             peer.try_send(BLOCKCHAIN_CHANNEL, BM.encode_msg(
                 BM.StatusResponse(self.store.height)))
@@ -243,11 +260,15 @@ class BlockchainReactor(Reactor):
         # full 64KB chunks lockstep on device, tails + trees on host —
         # proving data integrity like the reference's per-block re-hash
         # (`blockchain/reactor.go:224`) at batch rates
-        parts_list = from_data_batched([b.encode() for b in window])
+        with tracing.span("fastsync.prepare.encode"):
+            datas = [b.encode() for b in window]
+        with tracing.span("fastsync.prepare.parts"):
+            parts_list = from_data_batched(datas)
         items = []
-        for i, b in enumerate(window):
-            bid = BlockID(b.hash(), parts_list[i].header)
-            items.append((bid, b.height, blocks[i + 1].last_commit))
+        with tracing.span("fastsync.prepare.block_ids"):
+            for i, b in enumerate(window):
+                bid = BlockID(b.hash(), parts_list[i].header)
+                items.append((bid, b.height, blocks[i + 1].last_commit))
         return window, parts_list, items
 
     def _window_ready(self, blocks) -> bool:
@@ -387,37 +408,43 @@ class BlockchainReactor(Reactor):
             return moved
 
         with tracing.span("fastsync.apply", first_height=window[0].height,
-                          blocks=len(window)):
+                          blocks=len(window)) as args:
             # the window-batched apply: per-block validate/exec/save
             # discipline identical to apply_block (save_every=1 — a
             # durable node must keep store <= state+1 for the
             # handshake), but the app conn's lock is held once for the
             # whole window instead of ~4 acquisitions per block
+            cpu0 = time.thread_time()
             applied = execution.apply_window(
                 self.state, None, self.proxy,
                 [(b, p.header) for b, p in zip(window, parts_list)],
                 execution.MockMempool(), check_last_commit=False,
                 save_every=1, before_block=_save_to_store,
                 on_applied=_advance, stop_when=_valset_moved)
+            # this thread's own CPU: wall - cpu_s is what apply waited,
+            # for the GIL (look-ahead and p2p decode run meanwhile) or
+            # for sqlite's I/O
+            args["cpu_s"] = time.thread_time() - cpu0
         # the window-boundary span: covers verify (or lookahead reuse)
         # through apply under one window=<first_height> key, which is
         # what the attribution profiler groups by
+        lo = tracing.perf_to_epoch(t0)
+        hi = tracing.perf_to_epoch(time.perf_counter())
         tracing.RECORDER.record(
-            "fastsync.window", tracing.perf_to_epoch(t0),
-            time.perf_counter() - t0,
+            "fastsync.window", lo, hi - lo,
             {"window": window[0].height, "blocks": applied})
         try:
-            # per-window pipeline health -> Prometheus histograms; a
-            # failure here must never fail the sync itself
-            from tendermint_tpu.utils import attribution
-            spans = tracing.RECORDER.snapshot()
-            iv = attribution.find_windows(spans).get(window[0].height)
-            if iv is not None:
-                attribution.observe_window_metrics(
-                    attribution.attribute_interval(
-                        attribution.spans_by_category(spans), *iv))
-        except Exception:
-            pass
+            # per-window pipeline health -> Prometheus histograms, from
+            # this window's own categorized records: the read costs what
+            # the window recorded, not what the ring holds.  A failure
+            # here must never fail the sync itself
+            attribution.observe_window_metrics(
+                attribution.attribute_interval(
+                    attribution.spans_by_category(
+                        tracing.RECORDER.since(lo, categorized=True)),
+                    lo, hi))
+        except Exception as e:
+            log.debug("window metrics not observed", error=repr(e)[:200])
         log.debug("synced window", blocks=applied,
                   sigs=sum(len(i[2].precommits) for i in items),
                   verify_seconds=round(dt, 4),

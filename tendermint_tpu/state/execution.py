@@ -11,6 +11,7 @@ LastValidators.VerifyCommit (`:177-202`) — here one batched device call;
 
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass
 
 from tendermint_tpu.abci.types import RequestBeginBlock
@@ -18,6 +19,7 @@ from tendermint_tpu.state.state import ABCIResponses, State
 from tendermint_tpu.types import BlockID
 from tendermint_tpu.types.events import EventCache, event_tx
 from tendermint_tpu.utils.fail import fail_point
+from tendermint_tpu.utils.tracing import CAT_NONE, RECORDER, perf_to_epoch
 
 
 class MockMempool:
@@ -118,6 +120,19 @@ def apply_block(state: State, event_cache, proxy_consensus, block,
     return state
 
 
+def _stage(name: str, t0: float) -> float:
+    """Record the apply stage `name` from `t0` (perf_counter) to now and
+    return now: the steps of a block are contiguous, so each stage
+    starts where the last ended, one clock read a boundary.  A bare
+    record() with no args (the span() context manager costs about three
+    times as much, 512 times a window) and CAT_NONE: the stages nest
+    under the reactor's `fastsync.apply`, which carries the category, so
+    the attribution has their wall clock already and skips them."""
+    t1 = time.perf_counter()
+    RECORDER.record(name, perf_to_epoch(t0), t1 - t0, None, cat=CAT_NONE)
+    return t1
+
+
 def apply_window(state: State, event_cache, proxy_consensus, items,
                  mempool, tx_indexer=None, check_last_commit: bool = False,
                  save_every: int = 1, before_block=None, on_applied=None,
@@ -144,6 +159,10 @@ def apply_window(state: State, event_cache, proxy_consensus, items,
     on_applied) ends the window early — the reactor stops when the
     validator set changes, since later blocks were verified against a
     stale set.  Returns the number of blocks applied.
+
+    Every step of every block is one flight-recorder record,
+    `fastsync.apply.<stage>` (`_stage`), so the eight stages of a window
+    sum to the reactor's `fastsync.apply` span around this call.
     """
     batched = getattr(proxy_consensus, "batched", None)
     if batched is None:
@@ -153,28 +172,38 @@ def apply_window(state: State, event_cache, proxy_consensus, items,
         ctx = batched()
     applied = 0
     with ctx as app:
+        t = time.perf_counter()
         for block, psh in items:
             if before_block is not None:
                 before_block(block, psh)
+            t = _stage("fastsync.apply.store_save", t)
             validate_block(state, block, check_last_commit=check_last_commit)
             fail_point("ApplyBlock.validated")
+            t = _stage("fastsync.apply.validate", t)
             resp = exec_block_on_app(app, block, event_cache)
             fail_point("ApplyBlock.executed")
             if tx_indexer is not None:
                 tx_indexer.index_block(block, resp)
+            t = _stage("fastsync.apply.abci_exec", t)
             state.save_abci_responses(resp)
             fail_point("ApplyBlock.savedResponses")
+            t = _stage("fastsync.apply.save_responses", t)
             block_id = BlockID(hash=block.hash(), parts=psh)
             state.set_block_and_validators(block.header, block_id,
                                            resp.end_block_diffs)
+            t = _stage("fastsync.apply.update_state", t)
             commit_state_update_mempool(state, app, block, mempool)
             fail_point("ApplyBlock.committed")
+            t = _stage("fastsync.apply.abci_commit", t)
             applied += 1
             if save_every and applied % save_every == 0:
                 state.save()
+            t = _stage("fastsync.apply.state_save", t)
             if on_applied is not None:
                 on_applied(block)
-            if stop_when is not None and stop_when():
+            stop = stop_when is not None and stop_when()
+            t = _stage("fastsync.apply.advance", t)
+            if stop:
                 break
     if applied and not (save_every and applied % save_every == 0):
         state.save()

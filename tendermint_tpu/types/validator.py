@@ -10,6 +10,7 @@ as one crypto-backend batch instead of a scalar loop.
 
 from __future__ import annotations
 
+from contextlib import nullcontext
 from dataclasses import dataclass
 
 import numpy as np
@@ -17,6 +18,7 @@ import numpy as np
 from tendermint_tpu.types import canonical, merkle
 from tendermint_tpu.types.codec import Reader, i64, lp_bytes, u32
 from tendermint_tpu.types.keys import PubKey
+from tendermint_tpu.utils import tracing
 
 
 class CommitSignatureError(ValueError):
@@ -645,14 +647,22 @@ def verify_commits_batched(val_set: ValidatorSet, chain_id: str,
     from tendermint_tpu import batchplane
     if not items:
         return
-    templates, tmpl_idx, sigs, idxs, counts, tallied, foreign = \
-        window_commit_lanes(val_set, chain_id, items)
+
+    def phase(name: str):
+        # the host's two phases around the device call, as spans of a
+        # fast-sync window only (a light client's sessions are not)
+        return (tracing.span(name, cat=tracing.CAT_PREP)
+                if producer == "fastsync" else nullcontext())
+    with phase("fastsync.commit.lanes"):
+        templates, tmpl_idx, sigs, idxs, counts, tallied, foreign = \
+            window_commit_lanes(val_set, chain_id, items)
     ok = batchplane.verify_grouped_templated(
         val_set.set_key(), val_set.pubs_matrix(), idxs,
         tmpl_idx, templates, sigs, producer=producer,
         klass=klass or batchplane.CLASS_FASTSYNC)
-    window_tally_check(items, ok, counts, tallied, foreign,
-                       val_set.total_voting_power())
+    with phase("fastsync.commit.tally"):
+        window_tally_check(items, ok, counts, tallied, foreign,
+                           val_set.total_voting_power())
 
 
 def _foreign_explains_shortfall(tallied: int, foreign_power: int,

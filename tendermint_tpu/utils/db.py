@@ -10,6 +10,9 @@ from __future__ import annotations
 
 import sqlite3
 import threading
+import time
+
+from tendermint_tpu.utils.tracing import CAT_NONE, RECORDER, perf_to_epoch
 
 
 class MemDB:
@@ -72,18 +75,24 @@ class SQLiteDB:
 
     def set(self, key: bytes, value: bytes) -> None:
         conn = self._conn()
+        t0 = time.perf_counter()
         conn.execute("INSERT OR REPLACE INTO kv VALUES (?,?)", (key, value))
         conn.commit()
+        _wrote(t0)
 
     def set_batch(self, kvs: list[tuple[bytes, bytes]]) -> None:
         conn = self._conn()
+        t0 = time.perf_counter()
         conn.executemany("INSERT OR REPLACE INTO kv VALUES (?,?)", kvs)
         conn.commit()
+        _wrote(t0)
 
     def delete(self, key: bytes) -> None:
         conn = self._conn()
+        t0 = time.perf_counter()
         conn.execute("DELETE FROM kv WHERE k=?", (key,))
         conn.commit()
+        _wrote(t0)
 
     def iterate_prefix(self, prefix: bytes):
         hi = _prefix_upper_bound(prefix)
@@ -100,6 +109,15 @@ class SQLiteDB:
         if conn is not None:
             conn.close()
             self._local.conn = None
+
+
+def _wrote(t0: float) -> None:
+    """One `db.write` flight-recorder record around a transaction
+    (execute + commit): what a store's caller spent in sqlite, so that
+    its own encoding and hashing is the rest of its span.  Bookkeeping,
+    so outside the attribution partition (CAT_NONE); MemDB has none."""
+    RECORDER.record("db.write", perf_to_epoch(t0), time.perf_counter() - t0,
+                    None, cat=CAT_NONE)
 
 
 def _prefix_upper_bound(prefix: bytes) -> bytes | None:

@@ -117,6 +117,17 @@ def default_category(name: str) -> str | None:
     return None
 
 
+def _as_dict(rec: tuple) -> dict:
+    n, ph, ts, dur, tid, tname, cat, lane, args = rec
+    d = {"name": n, "ph": ph, "ts": ts, "dur": dur, "tid": tid,
+         "thread": tname, "lane": lane}
+    if cat:
+        d["cat"] = cat
+    if args:
+        d["args"] = args
+    return d
+
+
 class FlightRecorder:
     """Fixed-capacity ring of span records, oldest overwritten first.
 
@@ -156,10 +167,12 @@ class FlightRecorder:
         (a span that vanishes on failure hides exactly the interesting
         case), with error=<type> appended to its args.  `cat` and `lane`
         are reserved keywords feeding the attribution profiler; every
-        other keyword lands in the span's args."""
+        other keyword lands in the span's args.  Yields the args dict:
+        what the block adds to it (a `cpu_s` read at its end) is
+        recorded with the span."""
         p0 = time.perf_counter()
         try:
-            yield
+            yield args
         except BaseException as e:
             args = {**args, "error": type(e).__name__}
             raise
@@ -179,13 +192,30 @@ class FlightRecorder:
                 recs = self._buf[self._head:] + self._buf[:self._head]
             else:
                 recs = self._buf[:self._head]
-        return [{"name": n, "ph": ph, "ts": ts, "dur": dur,
-                 "tid": tid, "thread": tname, "lane": lane,
-                 **({"cat": cat} if cat else {}),
-                 **({"args": args} if args else {})}
-                for rec in recs if rec is not None
-                for (n, ph, ts, dur, tid, tname, cat, lane, args)
-                in (rec,)]
+        return [_as_dict(rec) for rec in recs]
+
+    def since(self, ts_s: float, categorized: bool = False) -> list[dict]:
+        """The newest records back to the first that ENDED before
+        `ts_s`, oldest-first, in snapshot()'s form: what was running at
+        or after that instant.  The ring is in order of recording, which
+        is the order of ends, so the walk stops at the first record that
+        was over by then and costs what was recorded since, whatever
+        the ring holds (a span that began earlier and ended later is
+        part of the answer, not the end of the walk).  With
+        `categorized`, only the records that carry a category, which is
+        all the attribution partition reads: the per-block bookkeeping
+        records of a catch-up (CAT_NONE) are most of a window."""
+        recs = []
+        with self._lock:
+            i = self._head
+            for _ in range(min(self._total, self.capacity)):
+                i = (i - 1) % self.capacity
+                rec = self._buf[i]
+                if rec[2] + rec[3] < ts_s:
+                    break
+                if rec[6] or not categorized:
+                    recs.append(rec)
+        return [_as_dict(rec) for rec in reversed(recs)]
 
     def last(self, name: str) -> dict | None:
         """Most recent span with `name` (bench's budget manager reads the

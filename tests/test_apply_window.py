@@ -3,6 +3,8 @@ app hash and state, same per-block hook order, and (with save_every=1)
 byte-identical persisted state — the license for the reactor and bench
 to amortize app-lock and state-save costs across a fast-sync window."""
 
+import threading
+
 import pytest
 
 from tendermint_tpu.proxy import ClientCreator
@@ -105,3 +107,97 @@ def test_apply_window_validation_failure_keeps_prefix(fixture):
                                execution.MockMempool(), save_every=1)
     # blocks before the bad one are applied and saved
     assert state.last_block_height == 3
+
+
+# -- the stage records (flight recorder) -------------------------------------
+
+STAGES = ["store_save", "validate", "abci_exec", "save_responses",
+          "update_state", "abci_commit", "state_save", "advance"]
+# sqlite transactions a block makes, by the stage that makes them
+WRITES = {"store_save": 1, "save_responses": 1, "state_save": 2}
+CLOCK = 2e-6       # an epoch timestamp holds a quarter of a microsecond
+
+
+def _sqlite_apply(tmp_path, gen, chain, name, windowed):
+    """Apply the first 3 blocks on sqlite stores, store saved before
+    state as the reactor does; returns (state, state db, store db)."""
+    from tendermint_tpu.blockchain.store import BlockStore
+    from tendermint_tpu.utils.db import SQLiteDB
+    sdb = SQLiteDB(str(tmp_path / f"{name}-state.db"))
+    bdb = SQLiteDB(str(tmp_path / f"{name}-blocks.db"))
+    state = get_state(sdb, gen)
+    store = BlockStore(bdb)
+    conns = ClientCreator("kvstore").new_app_conns()
+    seen = {b.height: c for b, _ps, c in chain}
+    parts = {b.height: ps for b, ps, _c in chain}
+    if windowed:
+        n = execution.apply_window(
+            state, None, conns.consensus,
+            [(b, ps.header) for b, ps, _ in chain[:3]],
+            execution.MockMempool(), save_every=1,
+            before_block=lambda b, _psh: store.save_block(
+                b, parts[b.height], seen[b.height]))
+        assert n == 3
+    else:
+        for b, ps, c in chain[:3]:
+            store.save_block(b, ps, c)
+            execution.apply_block(state, None, conns.consensus, b, ps.header,
+                                  execution.MockMempool(),
+                                  check_last_commit=False)
+    return state, sdb, bdb
+
+
+def test_apply_window_records_eight_contiguous_stages_a_block(fixture,
+                                                              tmp_path):
+    from tendermint_tpu.utils import tracing
+    gen, chain = fixture
+    t_start = tracing.now_epoch()
+    state, sdb, bdb = _sqlite_apply(tmp_path, gen, chain, "w", windowed=True)
+    me = [s for s in tracing.RECORDER.since(t_start)
+          if s["ts"] >= t_start and
+          s["tid"] == threading.current_thread().ident]
+    stages = [s for s in me if s["name"].startswith("fastsync.apply.")]
+    # each of the 8 once a block, in loop order, bare: no args, and no
+    # category of their own (they nest under the reactor's apply span)
+    assert [s["name"] for s in stages] == \
+        [f"fastsync.apply.{st}" for st in STAGES] * 3
+    assert all("args" not in s and "cat" not in s for s in stages)
+    # contiguous: each starts where the one before it ended, so nothing
+    # of a block is outside a stage
+    for a, b in zip(stages, stages[1:]):
+        assert abs(a["ts"] + a["dur"] - b["ts"]) < CLOCK, (a, b)
+    # every sqlite transaction is a db.write inside its store's stage
+    # (those before the first stage are the genesis state's)
+    writes = [s for s in me if s["name"] == "db.write" and
+              s["ts"] >= stages[0]["ts"] - CLOCK]
+    inside = {st: 0 for st in STAGES}
+    for w in writes:
+        (host,) = [s for s in stages if s["ts"] - CLOCK <= w["ts"] and
+                   w["ts"] + w["dur"] <= s["ts"] + s["dur"] + CLOCK]
+        inside[host["name"].rsplit(".", 1)[1]] += 1
+    assert inside == {st: 3 * WRITES.get(st, 0) for st in STAGES}
+    assert all("cat" not in w and "args" not in w for w in writes)
+
+    # and the records change nothing: the live path's apply_block, which
+    # has none, leaves the same state, app hash and stored bytes
+    ref_state, ref_sdb, ref_bdb = _sqlite_apply(tmp_path, gen, chain, "r",
+                                                windowed=False)
+    assert state.app_hash == ref_state.app_hash
+    assert state.encode() == ref_state.encode()
+    assert sdb.iterate_prefix(b"") == ref_sdb.iterate_prefix(b"")
+    assert bdb.iterate_prefix(b"") == ref_bdb.iterate_prefix(b"")
+    assert len(bdb.iterate_prefix(b"")) > 3 * 4
+
+
+def test_memdb_records_no_db_write(fixture):
+    from tendermint_tpu.utils import tracing
+    gen, chain = fixture
+    t_start = tracing.now_epoch()
+    db, state, conns = _fresh(gen)
+    execution.apply_window(state, None, conns.consensus,
+                           [(b, ps.header) for b, ps, _ in chain],
+                           execution.MockMempool(), save_every=1)
+    names = [s["name"] for s in tracing.RECORDER.since(t_start)
+             if s["ts"] >= t_start]
+    assert "db.write" not in names
+    assert names.count("fastsync.apply.state_save") == N

@@ -296,3 +296,47 @@ def test_observe_window_metrics_feeds_registry():
     after = REGISTRY.window_scalar_seconds.snapshot()["count"]
     assert after == before + 1
     at.observe_window_metrics({"wall": 0.0})         # no-op, no crash
+
+
+def test_reactor_observes_a_window_from_its_own_records_only(monkeypatch,
+                                                             tmp_path):
+    """The reactor's four window histograms are observed once a window
+    from `RECORDER.since(window start)`: never a snapshot() of the ring,
+    and a read as long as the window's own records whatever the ring
+    holds (here 50,000 older records: the ring full and wrapped)."""
+    from chainutil import fast_sync_in_process
+    from tendermint_tpu.utils.metrics import REGISTRY
+    rec = tracing.RECORDER
+    old = tracing.now_epoch() - 3600.0
+    for i in range(50_000):
+        rec.record("unrelated", old + i * 1e-3, 5e-4, None,
+                   cat=tracing.CAT_DEVICE)
+    assert len(rec.since(0.0)) == min(50_000, rec.capacity)
+
+    def no_snapshot():
+        raise AssertionError("the sync loop read the whole ring")
+    reads = []
+    real_since = rec.since
+
+    def counted_since(ts, categorized=False):
+        out = real_since(ts, categorized)
+        reads.append(len(out))
+        return out
+    monkeypatch.setattr(rec, "snapshot", no_snapshot)
+    monkeypatch.setattr(rec, "since", counted_since)
+    hists = (REGISTRY.window_overlap_frac_hist,
+             REGISTRY.window_device_busy_frac_hist,
+             REGISTRY.window_device_idle_frac_hist,
+             REGISTRY.window_scalar_seconds)
+    before = [h.snapshot()["count"] for h in hists]
+    t_start = tracing.now_epoch()
+    fast_sync_in_process("window-hist-chain", 40, 8, sqlite_dir=str(tmp_path))
+    windows = [s for s in real_since(t_start)
+               if s["name"] == "fastsync.window"]
+    assert len(windows) >= 4
+    assert [h.snapshot()["count"] - b for h, b in zip(hists, before)] == \
+        [len(windows)] * 4
+    assert len(reads) == len(windows)
+    # a window's categorized records: its phases and the look-ahead's
+    # beside it, not the 8 x 13 per-block records, not the ring's 16,384
+    assert 3 <= max(reads) < 8 * 13 < rec.capacity

@@ -103,6 +103,56 @@ def test_pool_timeout_redo_and_eviction(monkeypatch):
     assert [b.height for b in pool.peek_contiguous(5)] == [1, 2, 3, 4, 5]
 
 
+def test_timed_out_slot_is_rerequested_and_the_late_answer_dropped(
+        monkeypatch):
+    """A request that times out goes to another peer (one
+    `pool.rerequest`); the first peer's answer then arrives for a slot
+    that is no longer its own: `add_block` says no and the reactor
+    counts one `pool.late_block` with the bytes that were thrown away."""
+    import tendermint_tpu.blockchain.pool as pool_mod
+    from tendermint_tpu.utils import tracing
+    monkeypatch.setattr(pool_mod, "REQUEST_TIMEOUT", 0.05)
+    privs, vs = make_validators(4)
+    gen = make_genesis(CHAIN, privs)
+    (block, _ps, _seen), = build_chain(privs, vs, CHAIN, 1,
+                                       app_hashes=kvstore_app_hashes(1))
+    state = get_state(MemDB(), gen)
+    conns = ClientCreator("kvstore").new_app_conns()
+    bc = BlockchainReactor(state, conns.consensus, BlockStore(MemDB()),
+                           fast_sync=True)
+
+    class FakePeer:
+        def __init__(self, pid):
+            self.id = pid
+
+    t_start = tracing.now_epoch()
+    bc.pool.set_peer_height("first-peer-id", 1)
+    assert bc.pool.schedule() == [(1, "first-peer-id")]
+    bc.pool.set_peer_height("second-peer-id", 1)
+    time.sleep(0.08)
+    assert bc.pool.schedule() == [(1, "second-peer-id")]
+    raw = BM.encode_msg(BM.BlockResponse(block.encode()))
+    assert not bc.pool.add_block("first-peer-id", block)
+    bc.receive(BLOCKCHAIN_CHANNEL, FakePeer("first-peer-id"), raw)
+    assert bc.pool.peek_contiguous(2) == []        # dropped, not stored
+    bc.receive(BLOCKCHAIN_CHANNEL, FakePeer("second-peer-id"), raw)
+    assert [b.height for b in bc.pool.peek_contiguous(2)] == [1]
+    # a second answer for a slot already filled is late too
+    bc.receive(BLOCKCHAIN_CHANNEL, FakePeer("second-peer-id"), raw)
+
+    mine = [s for s in tracing.RECORDER.since(t_start)
+            if s["ts"] >= t_start and s["name"].startswith("pool.")]
+    assert [(s["name"], s["ph"], s["args"]) for s in mine] == [
+        ("pool.rerequest", "i", {"height": 1, "old": "first-peer-i",
+                                 "new": "second-peer-"}),
+        ("pool.late_block", "i", {"height": 1, "peer": "first-peer-i",
+                                  "bytes": len(raw)}),
+        ("pool.late_block", "i", {"height": 1, "peer": "second-peer-",
+                                  "bytes": len(raw)})]
+    # neither is a refusal: nobody was evicted, nothing redone
+    assert bc.pool.num_peers() == 2
+
+
 def test_pool_caught_up():
     pool = BlockPool(start_height=11)
     assert not pool.is_caught_up()     # no peers yet

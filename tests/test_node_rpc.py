@@ -132,6 +132,12 @@ def test_status_reports_live_state(node, client):
     after = client.status()
     assert after["latest_block_height"] > before["latest_block_height"]
     assert after["latest_app_hash"] != before["latest_app_hash"]
+    # blocks commit every ~85 ms here: one may land between the answer
+    # and the read of the node's own state, so read the pair again
+    for _ in range(10):
+        if after["latest_app_hash"] == node.consensus.state.app_hash.hex():
+            break
+        after = client.status()
     assert after["latest_app_hash"] == node.consensus.state.app_hash.hex()
 
 

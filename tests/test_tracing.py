@@ -7,8 +7,8 @@ import threading
 
 import pytest
 
-from tendermint_tpu.utils.tracing import (PH_INSTANT, PH_SPAN,
-                                          FlightRecorder)
+from tendermint_tpu.utils.tracing import (CAT_APPLY, CAT_NONE, PH_INSTANT,
+                                          PH_SPAN, FlightRecorder)
 
 
 def test_ring_overflow_keeps_newest_in_order():
@@ -47,6 +47,54 @@ def test_span_recorded_on_exception_with_error_arg():
             raise ValueError("x")
     (s,) = rec.snapshot()
     assert s["args"] == {"height": 1, "error": "ValueError"}
+
+
+def test_span_yields_its_args_for_what_is_known_only_at_the_end():
+    rec = FlightRecorder(capacity=8)
+    with rec.span("fastsync.apply", blocks=64) as args:
+        args["cpu_s"] = 0.25
+    with pytest.raises(ValueError):
+        with rec.span("fastsync.apply") as args:
+            args["cpu_s"] = 0.5
+            raise ValueError("x")
+    a, b = rec.snapshot()
+    assert a["args"] == {"blocks": 64, "cpu_s": 0.25}
+    assert b["args"] == {"cpu_s": 0.5, "error": "ValueError"}
+
+
+@pytest.mark.parametrize("n_records", [2, 4, 6, 20],
+                         ids=["before-wrap", "full", "wrapped", "wrapped-5x"])
+@pytest.mark.parametrize("since_ts", [-1.0, 0.0, 2.05, 3.85, 18.95, 100.0])
+def test_since_equals_the_filtered_snapshot(n_records, since_ts):
+    """since(ts) is what snapshot() holds of the records that ended at
+    or after ts, oldest first, before and after the ring wraps; a span
+    that began before ts and ended after it is in, and does not end the
+    walk."""
+    rec = FlightRecorder(capacity=8)
+    for i in range(n_records):
+        # recorded in order of ends, as a running program records:
+        # every third is a long span that started well before the rest
+        dur = 2.5 if i % 3 == 0 else 0.1
+        rec.record(f"ev{i}", ts_s=float(i) + 0.9 - dur, dur_s=dur,
+                   args={"i": i} if i % 2 else None,
+                   cat=CAT_APPLY if i % 4 else None)
+        rec.record(f"bookkeeping{i}", float(i) + 0.85, 0.1, cat=CAT_NONE)
+    want = [s for s in rec.snapshot() if s["ts"] + s["dur"] >= since_ts]
+    assert rec.since(since_ts) == want
+    # the attribution's read: the same walk, categorized records only
+    assert rec.since(since_ts, categorized=True) == \
+        [s for s in want if "cat" in s]
+    if since_ts <= 0.0:
+        assert len(want) == min(2 * n_records, 8)  # the whole ring, once
+    assert rec.since(float(n_records) + 10.0) == []
+
+
+def test_since_on_an_empty_and_a_cleared_ring():
+    rec = FlightRecorder(capacity=4)
+    assert rec.since(0.0) == []
+    rec.record("a", 1.0, 0.5)
+    rec.clear()
+    assert rec.since(0.0) == []
 
 
 def test_instant_and_last():
