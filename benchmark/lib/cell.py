@@ -74,16 +74,27 @@ def load_cell(root: str, workload: str) -> dict:
     }
 
 
-def chain_blocks(cell: dict, seconds: float) -> int:
-    """How long a chain to serve: twice what the parent syncs in warm-up
-    plus the window (`chain` of the traffic file, by configuration, else
-    its default; a configuration file may override by traffic name), in
-    whole reactor windows, plus the block that carries the last commit."""
+DEFAULT_HEADROOM = 2.0           # a chain plan that names none
+
+
+def chain_plan(cell: dict) -> dict:
+    """The cell's chain plan (`chain` of the traffic file, by
+    configuration, else its default; a configuration file may override
+    by traffic name), with its `headroom`."""
     plan = cell["config"].get("chain", {}).get(cell["traffic_name"])
     if plan is None:
         by_cfg = cell["traffic"]["chain"]
         plan = by_cfg.get(cell["config_name"], by_cfg["default"])
-    blocks = 2.0 * plan["parent_blocks_per_s"] * (plan["warmup_s"] + seconds)
+    return dict(plan, headroom=plan.get("headroom", DEFAULT_HEADROOM))
+
+
+def chain_blocks(cell: dict, seconds: float) -> int:
+    """How long a chain to serve: `headroom` times what the parent syncs
+    in warm-up plus the window, in whole reactor windows, plus the block
+    that carries the last commit."""
+    plan = chain_plan(cell)
+    blocks = plan["headroom"] * plan["parent_blocks_per_s"] * \
+        (plan["warmup_s"] + seconds)
     windows = max(WARM_WINDOWS + 4, -(-int(blocks) // WINDOW_BLOCKS))
     return windows * WINDOW_BLOCKS + 1
 
@@ -171,22 +182,78 @@ def hist_delta(before: dict, after: dict) -> dict:
     return out
 
 
+class Closer(threading.Thread):
+    """Takes the run's close when it is due, on a thread of its own.
+
+    In a traced run the thread of the harness's clock is inside
+    `jax.profiler.stop_trace()` when the window closes: it collects and
+    writes the trace before it returns, a minute and more for 20 s of a
+    cell that runs 250 windows.  So the close (`take()`: read the node,
+    then stop the sync) is taken here at `at_mono`, and the harness
+    collects it with `taken()` when it gets there."""
+
+    def __init__(self, at_mono: float, take):
+        super().__init__(name="bench-close", daemon=True)
+        self.at_mono, self._take = at_mono, take
+        self._off = threading.Event()
+        self.close = None
+        self.error = None
+
+    def run(self) -> None:
+        if self._off.wait(max(0.0, self.at_mono - time.monotonic())):
+            return
+        try:
+            self.close = self._take()
+        except Exception as e:        # raised again by taken()
+            self.error = e
+
+    def call_off(self) -> None:
+        self._off.set()
+
+    def taken(self, timeout: float) -> dict:
+        """What `take()` returned, once the close is due and taken."""
+        self.join(max(0.0, self.at_mono - time.monotonic()) + timeout)
+        if self.error is not None:
+            raise self.error
+        if self.close is None:
+            raise TimeoutError(f"the close was not taken {timeout:.0f}s "
+                               "after it was due")
+        return self.close
+
+
 class Checks:
-    """Every number compared, printed beside its limit."""
+    """Every number compared, printed beside its limit as it is taken,
+    and kept under a short name for the result's line and the last
+    lines of standard error (`report_compared`)."""
 
     def __init__(self):
         self.ok = True
+        self.compared: dict[str, dict] = {}
 
-    def _note(self, name: str, value, word: str, limit, good) -> None:
+    def _note(self, key: str, name: str, value, word: str, limit,
+              good) -> None:
         self.ok &= bool(good)
+        self.compared[key] = {"value": value, f"at_{word}": limit,
+                              "ok": bool(good)}
         say(f"check {name}: {value} (limit: at {word} {limit}) "
             f"{'ok' if good else 'NOT OK'}")
 
-    def at_most(self, name: str, value, limit) -> None:
-        self._note(name, value, "most", limit, value <= limit)
+    def at_most(self, key: str, name: str, value, limit) -> None:
+        self._note(key, name, value, "most", limit, value <= limit)
 
-    def at_least(self, name: str, value, limit) -> None:
-        self._note(name, value, "least", limit, value >= limit)
+    def at_least(self, key: str, name: str, value, limit) -> None:
+        self._note(key, name, value, "least", limit, value >= limit)
+
+
+def report_compared(compared: dict) -> None:
+    """Each number compared beside its limit, as the last lines of
+    standard error (the result's line carries the same under `checks`)."""
+    for key, c in compared.items():
+        word = "at_most" if "at_most" in c else "at_least"
+        print(f"benchmark: compared {key} = {c['value']} (limit "
+              f"{word.replace('_', ' ')} {c[word]})"
+              f"{'' if c['ok'] else ' NOT OK'}", file=sys.stderr)
+    sys.stderr.flush()
 
 
 def kernel_programs(spans) -> int:
@@ -228,6 +295,7 @@ def run_cell(root: str, cell: dict, seed: int, seconds: float, trace: bool,
     kids = children_mod.Children(root)
     dog = start_watchdog(kids)
     node = None
+    closer = None
     tracing_on = False
     try:
         # -- the children first: the chain builds while jax imports -----
@@ -321,43 +389,61 @@ def run_cell(root: str, cell: dict, seed: int, seconds: float, trace: bool,
             "t_open": t_open_mono, "t_close": t_close_mono,
             "interval_s": PROBE_INTERVAL_S})
         setup_s = t_open_mono - t_start
+        height_open = node.block_store.height
         say(f"window open after {setup_s:.1f}s of set-up, node at "
-            f"{node.block_store.height}")
+            f"{height_open}")
+
+        def take_close() -> dict:
+            """Read the close, then stop the sync where it is, before
+            anything that can take long: nothing the node does after the
+            close reaches a check, a counter or the ring."""
+            late_s = time.monotonic() - t_close_mono
+            close = {"height": node.block_store.height,
+                     "switched": bc._switched,
+                     "counters": REGISTRY.snapshot(),
+                     "hists": hist_delta(hist_open, hist_state(REGISTRY)),
+                     "read_late_s": late_s}
+            bc.stop()
+            bc._thread.join(timeout=120)
+            if bc._thread.is_alive():
+                raise RuntimeError("the fast-sync thread did not stop")
+            close["stopped_late_s"] = time.monotonic() - t_close_mono
+            close["height_stopped"] = node.block_store.height
+            return close
+
+        closer = Closer(t_close_mono, take_close)
+        closer.start()
         trace_end_epoch = None
-
-        def stop_trace():
-            nonlocal trace_end_epoch, tracing_on
-            trace_end_epoch = tracing.now_epoch()
-            jax.profiler.stop_trace()
-            tracing_on = False
-
-        if trace and trace_s < seconds:
+        if trace:
             time.sleep(max(0.0, t_open_mono + trace_s - time.monotonic()))
-            stop_trace()
-        time.sleep(max(0.0, t_close_mono - time.monotonic()))
+            trace_end_epoch = tracing.now_epoch()
+            jax.profiler.stop_trace()     # may return long after the close
+            tracing_on = False
+            say(f"trace: stop asked {trace_end_epoch - t_open:.3f}s after "
+                f"the open, written {time.monotonic() - t_close_mono:.1f}s "
+                "after the close")
+        close = closer.taken(150)
         t_close = t_open + seconds
-        height_close = node.block_store.height
-        switched = bc._switched
-        counters_close = REGISTRY.snapshot()
-        hists = hist_delta(hist_open, hist_state(REGISTRY))
-        if tracing_on:
-            stop_trace()
+        height_close, switched = close["height"], close["switched"]
+        counters_close, hists = close["counters"], close["hists"]
+        say(f"close: read {1e3 * close['read_late_s']:.1f} ms after it was "
+            f"due, node at {height_close}; the sync stopped "
+            f"{close['stopped_late_s']:.3f}s after the close, node at "
+            f"{close['height_stopped']}")
         spans = [s for s in tracing.RECORDER.snapshot() if s["ts"] >= t_boot]
-        overflow = tracing.RECORDER.total - spans_before > \
-            tracing.RECORDER.capacity
+        ring_records = tracing.RECORDER.total - spans_before
+        overflow = ring_records > tracing.RECORDER.capacity
         if switched or height_close >= n_blocks - 2 * WINDOW_BLOCKS:
             raise MeasuredNothing(
                 f"the node reached height {height_close} of {n_blocks - 1} "
-                "served before the window closed: the chain is too short "
-                "for this rate, the run has measured nothing")
+                "served before the window closed (it opened at height "
+                f"{height_open}; {'a traced' if trace else 'an untraced'} "
+                "run): the chain is too short for this rate, the run has "
+                "measured nothing")
 
         probe = kids.read_json_line(prober, 60, "the prober child")
 
-        # -- stop the sync where it is, then check -----------------------
-        bc.stop()
-        bc._thread.join(timeout=120)
-        if bc._thread.is_alive():
-            raise RuntimeError("the fast-sync thread did not stop")
+        # -- check ----------------------------------------------------------
         from tendermint_tpu.rpc.client import HTTPClient
 
         def stored_hash(h):
@@ -372,15 +458,17 @@ def run_cell(root: str, cell: dict, seed: int, seconds: float, trace: bool,
             raise MeasuredNothing(str(e)) from e
         checks = Checks()
         tip = bc.state.last_block_height
-        checks.at_most("refused heights in the interval",
+        checks.at_most("refused", "refused heights in the interval",
                        len(acct["refused"]), 0)
-        checks.at_most("applied heights whose stored hash differs from the "
-                       "builder's", len(acct["wrong_hash"]), 0)
-        checks.at_most("tip block hash differs from the builder's "
-                       f"(height {tip})",
+        checks.at_most("wrong_hash", "applied heights whose stored hash "
+                       "differs from the builder's",
+                       len(acct["wrong_hash"]), 0)
+        checks.at_most("tip_hash_differs", "tip block hash differs from the "
+                       f"builder's (height {tip})",
                        int(stored_hash(tip) != index["block_hash"][tip - 1]),
                        0)
-        checks.at_most("app hash at the tip differs from the builder's",
+        checks.at_most("app_hash_differs", "app hash at the tip differs "
+                       "from the builder's",
                        int(bc.state.app_hash.hex() !=
                            index["app_hash"][tip - 1]), 0)
         rpc = HTTPClient(node.rpc_server.addr)
@@ -395,24 +483,29 @@ def run_cell(root: str, cell: dict, seed: int, seconds: float, trace: bool,
             blk["header"]["height"] != tip,
             blk["last_commit"]["precommits"] != (n_vals if tip > 1 else 0),
             [v["pub_key"] for v in vals] != ready["genesis"]["validators"]))
-        checks.at_most("/status, /block, /validators answers that differ",
-                       rpc_wrong, 0)
+        checks.at_most("rpc_answers_differ", "/status, /block, /validators "
+                       "answers that differ", rpc_wrong, 0)
         moved = {k: counters_close[k] - counters_boot[k] for k in
                  ("sigs_verified", "blocks_synced", "crypto_fallback_calls")}
-        checks.at_most("crypto_fallback_calls moved by",
+        checks.at_most("fallback_calls", "crypto_fallback_calls moved by",
                        moved["crypto_fallback_calls"], 0)
-        checks.at_most("scalar.verify spans", sum(
+        checks.at_most("scalar_verify_spans", "scalar.verify spans", sum(
             s["name"] == "scalar.verify" for s in spans), 0)
-        checks.at_least("sigs_verified moved by (heights x validators "
-                        "synced since boot)", moved["sigs_verified"],
+        checks.at_least("sigs_verified", "sigs_verified moved by (heights x "
+                        "validators synced since boot)",
+                        moved["sigs_verified"],
                         moved["blocks_synced"] * n_vals)
         in_window = [s for s in spans if s["name"] == "xla.compile" and
                      t_open <= accounting.span_end(s) <= t_close]
-        checks.at_most("kernel compiles or loads inside the window",
+        checks.at_most("kernel_programs_in_window", "kernel compiles or "
+                       "loads inside the window",
                        kernel_programs(in_window), 0)
         say(f"one-op helper compiles inside the window: {len(in_window)}")
-        checks.at_most("flight recorder overflowed", int(overflow), 0)
-        checks.at_most("probes answered with an error", probe["errors"], 0)
+        checks.at_most("ring_overflowed", "flight recorder overflowed "
+                       f"({ring_records} records of "
+                       f"{tracing.RECORDER.capacity})", int(overflow), 0)
+        checks.at_most("probe_errors", "probes answered with an error",
+                       probe["errors"], 0)
 
         # the verdict control: the window's own bucket, after the window
         val_seeds, vs = chain.make_validators(seed, n_vals)
@@ -423,10 +516,12 @@ def run_cell(root: str, cell: dict, seed: int, seconds: float, trace: bool,
         t_control = tracing.now_epoch()
         batch = control.build(seed, val_seeds, WINDOW_BLOCKS)
         got = control.device_verdicts(bc.state.validators, batch)
-        checks.at_most(f"verdict control: lanes of {got.size} "
-                       f"({batch['forged']} forged) where the device is not "
-                       "OpenSSL", control.mismatches(got, batch), 0)
-        checks.at_most("programs compiled or loaded for the verdict control",
+        checks.at_most("control_lanes_differ", "verdict control: lanes of "
+                       f"{got.size} ({batch['forged']} forged) where the "
+                       "device is not OpenSSL",
+                       control.mismatches(got, batch), 0)
+        checks.at_most("control_programs", "programs compiled or loaded for "
+                       "the verdict control",
                        kernel_programs(s for s in tracing.RECORDER.snapshot()
                                        if s["ts"] >= t_control), 0)
 
@@ -457,11 +552,15 @@ def run_cell(root: str, cell: dict, seed: int, seconds: float, trace: bool,
             f"{1e3 * max(probe['late_s'], default=0.0):.2f} ms (mean "
             f"{1e3 * sum(probe['late_s']) / max(1, len(probe['late_s'])):.2f}"
             f" ms), unanswered at the close {probe['unanswered']}")
+        headroom = chain_plan(cell)["headroom"]
         say(f"interval: {acct['windows']} whole reactor windows, "
             f"{acct['applied']} heights in {acct['elapsed_s']:.3f}s; node at "
-            f"{height_close} of {n_blocks - 1} served at the close (level "
-            f"{2.0 * height_close / (n_blocks - 1):.2f} of the parent's "
-            "expected)")
+            f"{height_open} at the open and {height_close} of "
+            f"{n_blocks - 1} served at the close "
+            f"({height_close - height_open - acct['applied']} heights "
+            "outside the interval); level "
+            f"{headroom * height_close / (n_blocks - 1):.2f} of the "
+            f"parent's expected, the tip at {headroom:.2f}")
         reduced = None
         if trace and platform == "tpu":
             reduced = reduce_device_trace(devtrace, trace_dir, spans,
@@ -500,9 +599,12 @@ def run_cell(root: str, cell: dict, seed: int, seconds: float, trace: bool,
                                     window_s=reduced["window_s"])
             result["breakdown"] = {"device_ops": reduced["device_ops"],
                                    "idle_gaps": reduced["idle_gaps"]}
+        result["checks"] = checks.compared    # last in the line
         return result
     finally:
         dog.cancel()
+        if closer is not None:
+            closer.call_off()
         if tracing_on:
             try:
                 jax.profiler.stop_trace()

@@ -22,8 +22,11 @@ import sys                       # noqa: E402
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-# the whole run stays inspectable: no span may fall off the ring
-os.environ.setdefault("TM_FLIGHT_RECORDER_CAP", "1048576")
+# the whole run stays inspectable: no span may fall off the ring.  A run
+# of testnet-4v writes 13 records a height from boot, 280,000 in a
+# checkout's first run (my chip runs, PR 26): 4 Mi slots (32 MB of
+# pointers) hold a change 2.5x the parent several times over
+os.environ.setdefault("TM_FLIGHT_RECORDER_CAP", "4194304")
 
 
 def execute(workload: str, seed: int, seconds: float, trace: bool,
@@ -33,8 +36,10 @@ def execute(workload: str, seed: int, seconds: float, trace: bool,
     from benchmark.lib import cell as cell_mod
     cell = cell_mod.load_cell(ROOT, workload)
     try:
-        return 0, cell_mod.run_cell(ROOT, cell, seed, seconds, trace,
-                                    T_START, fault=fault)
+        result = cell_mod.run_cell(ROOT, cell, seed, seconds, trace,
+                                   T_START, fault=fault)
+        cell_mod.report_compared(result["checks"])
+        return 0, result
     except cell_mod.MeasuredNothing as e:
         print(f"benchmark: {e}", file=sys.stderr, flush=True)
         return cell_mod.EXIT_MEASURED_NOTHING, None

@@ -74,6 +74,41 @@ def test_workload_entry_and_files(w):
     assert len(cell["end_to_end"]) >= 2 and cell["per_layer"]
 
 
+def _cell_of(config: str, traffic: str, plan=None) -> dict:
+    """The parts of a cell `chain_blocks` reads, for a pair that need not
+    be a workload; `plan` stands in the configuration file's override."""
+    with open(os.path.join(REPO, "benchmark", "traffic",
+                           traffic + ".json")) as f:
+        mix = json.load(f)
+    cfg = {"chain": {traffic: plan}} if plan else {}
+    return {"config": cfg, "config_name": config, "traffic": mix,
+            "traffic_name": traffic}
+
+
+PARENT_100V = {"parent_blocks_per_s": 90, "warmup_s": 22}
+PARENT_4V = {"parent_blocks_per_s": 355, "warmup_s": 15}
+
+
+@pytest.mark.parametrize("config,traffic,plan,headroom,blocks", [
+    # a plan that names no headroom reads as before PR 26: twice
+    ("catchup-100v", "empty-blocks", PARENT_100V, 2.0, 12097),
+    ("testnet-4v", "empty-blocks", PARENT_4V, 2.0, 42625),
+    ("testnet-4v", "empty-blocks", dict(PARENT_4V, headroom=2.6), 2.6, 55425),
+    # the traffic file's own plans, and its default
+    ("catchup-100v", "empty-blocks", None, 3.0, 18113),
+    ("testnet-4v", "empty-blocks", None, 2.6, 55425),
+    ("some-other", "empty-blocks", None, 2.0, 12097),
+    # full-blocks is as long as it was
+    ("catchup-100v", "full-blocks", None, 2.0, 2241),
+    ("some-other", "full-blocks", None, 2.0, 2241),
+])
+def test_chain_length_follows_the_plans_headroom(config, traffic, plan,
+                                                 headroom, blocks):
+    cell = _cell_of(config, traffic, plan)
+    assert cell_mod.chain_plan(cell)["headroom"] == headroom
+    assert cell_mod.chain_blocks(cell, 45) == blocks
+
+
 def test_metrics_follow_the_contract():
     e2e = {m["name"] for m in BENCH["end_to_end"]}
     assert "setup_s" in e2e and "sync_blocks_per_s" in e2e

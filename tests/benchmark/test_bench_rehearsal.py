@@ -4,6 +4,8 @@ without a chip.  The rehearsal skips only the harness's look for a chip:
 chain builder, children, node, window, accounting, check and result line
 are the real ones."""
 
+import json
+import os
 import subprocess
 import sys
 
@@ -12,12 +14,15 @@ import pytest
 import benchutil
 from benchutil import REPO
 
-PER_LAYER_ON_CPU = {
-    "pool.link_util_pct", "pool.redos", "pool.evictions",
-    "reactor.window_ms", "reactor.prepare_ms", "reactor.lookahead_ms",
-    "reactor.lookahead_hit_pct", "batchplane.wait_ms",
-    "backend.verify_call_ms", "backend.boot_load_s",
-    "backend.boot_compile_s", "apply.window_ms", "rpc.status_p95_ms"}
+# what a CPU run reports of `per_layer`: what the harness takes itself,
+# and every metric read from the program's own spans (`program_span`),
+# which are there on a CPU as on the chip.  Read from BENCHMARK.json, so
+# that a PR that adds such a metric as data files is held to it too
+with open(os.path.join(REPO, "BENCHMARK.json")) as _f:
+    PER_LAYER_ON_CPU = {
+        "pool.link_util_pct", "batchplane.wait_ms", "rpc.status_p95_ms"} | {
+        m["name"] for m in json.load(_f)["per_layer"]
+        if m["source"] == "program_span"}
 DEVICE_ONLY = {"kernel.verify_ms", "verify_grouped_templated_roofline",
                "device.idle_pct", "device.hbm_peak_MiB"}
 
@@ -36,6 +41,10 @@ def test_sound_rehearsal_is_correct_names_the_cpu_and_leaves_no_child():
     assert result["metrics"]["pool.redos"]["value"] == 0
     assert result["metrics"]["reactor.window_ms"]["value"] > 0
     assert "verdict control" in out and "NOT OK" not in out
+    # every number compared, beside its limit, last in the line
+    assert list(result)[-1] == "checks" and len(result["checks"]) == 13
+    assert all(c["ok"] and ("at_most" in c or "at_least" in c)
+               for c in result["checks"].values())
     pids = benchutil.child_pids(out)
     assert len(pids) == 2 and not any(benchutil.alive(p) for p in pids)
 
@@ -52,6 +61,9 @@ def test_rehearsal_with_the_verdicts_thrown_away_is_not_correct():
         "sync_blocks_per_s", "boot_to_first_window_s", "setup_s"}
     bad = [ln for ln in out.splitlines() if "NOT OK" in ln]
     assert len(bad) == 1 and "verdict control" in bad[0]
+    assert [k for k, c in result["checks"].items() if not c["ok"]] == [
+        "control_lanes_differ"]
+    assert result["checks"]["control_lanes_differ"]["value"] > 0
     assert not any(benchutil.alive(p) for p in benchutil.child_pids(out))
 
 
