@@ -86,13 +86,17 @@ class State:
         return st
 
     def save(self) -> None:
+        """The state and, in the same atomic write, the validator-set
+        history: the set that signs votes AT height last_block_height+1
+        (for evidence/light verification against the right era's keys;
+        modern tendermint's LoadValidators).  State key first; a crash
+        leaves both records of the old height or both of the new, never
+        the state without its validators record."""
         assert self.db is not None
-        self.db.set(_STATE_KEY, self.encode())
-        # validator-set history: the set that signs votes AT height
-        # last_block_height+1 (for evidence/light verification against
-        # the right era's keys; modern tendermint's LoadValidators)
-        self.db.set(_validators_key(self.last_block_height + 1),
-                    self.validators.encode())
+        self.db.set_batch([
+            (_STATE_KEY, self.encode()),
+            (_validators_key(self.last_block_height + 1),
+             self.validators.encode())])
 
     def load_validators(self, height: int) -> ValidatorSet | None:
         """The set that signed votes at `height`, from saved history."""

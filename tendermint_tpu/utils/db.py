@@ -8,9 +8,11 @@ the block store, state store, and tx index.
 
 from __future__ import annotations
 
+import functools
 import sqlite3
 import threading
 import time
+from itertools import chain
 
 from tendermint_tpu.utils.tracing import CAT_NONE, RECORDER, perf_to_epoch
 
@@ -48,8 +50,39 @@ class MemDB:
         pass
 
 
+# Rows one INSERT statement takes: 2 bound variables a row, well under
+# the 999 a statement may bind in sqlite before 3.32 (32,766 since).
+_ROWS_A_STATEMENT = 400
+
+
+@functools.cache
+def _insert_sql(rows: int) -> str:
+    """The `INSERT OR REPLACE` of `rows` rows: one text a row count (at
+    most `_ROWS_A_STATEMENT` of them), so that each connection's
+    statement cache keeps the ones in use prepared."""
+    return "INSERT OR REPLACE INTO kv VALUES " + ",".join(["(?,?)"] * rows)
+
+
+def _insert(conn: sqlite3.Connection, kvs) -> None:
+    conn.execute(_insert_sql(len(kvs)), tuple(chain.from_iterable(kvs)))
+
+
 class SQLiteDB:
-    """Durable store: one `kv` table, WAL mode, synchronous=NORMAL."""
+    """Durable store: one `kv` table in WAL mode, a connection a thread,
+    every connection in sqlite's autocommit.
+
+    A write is one statement in one transaction: `set`, `delete` and a
+    `set_batch` that fits one statement are a single `execute`, which
+    begins, writes and commits inside its own `sqlite3_step` (one
+    release of the GIL); nobody calls `commit()`.  A write is durable
+    and visible to every other connection when the call returns, and a
+    batch is atomic whatever its size.
+
+    `synchronous` belongs to a connection, not to the file: NORMAL on
+    the connection of the thread that creates the store, sqlite's
+    default FULL (the WAL synced at every commit) on every other
+    thread's, the fast-sync thread's among them.  PERF.md section 4;
+    tests/test_db.py holds both."""
 
     def __init__(self, path: str):
         self.path = path
@@ -59,12 +92,11 @@ class SQLiteDB:
                      "(k BLOB PRIMARY KEY, v BLOB NOT NULL)")
         conn.execute("PRAGMA journal_mode=WAL")
         conn.execute("PRAGMA synchronous=NORMAL")
-        conn.commit()
 
     def _conn(self) -> sqlite3.Connection:
         conn = getattr(self._local, "conn", None)
         if conn is None:
-            conn = sqlite3.connect(self.path)
+            conn = sqlite3.connect(self.path, isolation_level=None)
             self._local.conn = conn
         return conn
 
@@ -76,22 +108,36 @@ class SQLiteDB:
     def set(self, key: bytes, value: bytes) -> None:
         conn = self._conn()
         t0 = time.perf_counter()
-        conn.execute("INSERT OR REPLACE INTO kv VALUES (?,?)", (key, value))
-        conn.commit()
+        conn.execute(_insert_sql(1), (key, value))
         _wrote(t0)
 
     def set_batch(self, kvs: list[tuple[bytes, bytes]]) -> None:
+        """All of `kvs` or none of it, in order (the last write of a
+        repeated key wins); an empty batch is no transaction.  One
+        statement where the rows fit one, else statements of
+        `_ROWS_A_STATEMENT` rows inside one explicit transaction."""
+        if not kvs:
+            return
         conn = self._conn()
         t0 = time.perf_counter()
-        conn.executemany("INSERT OR REPLACE INTO kv VALUES (?,?)", kvs)
-        conn.commit()
+        if len(kvs) <= _ROWS_A_STATEMENT:
+            _insert(conn, kvs)
+        else:
+            conn.execute("BEGIN IMMEDIATE")
+            try:
+                for i in range(0, len(kvs), _ROWS_A_STATEMENT):
+                    _insert(conn, kvs[i:i + _ROWS_A_STATEMENT])
+                conn.execute("COMMIT")
+            except BaseException:
+                if conn.in_transaction:   # some errors roll back themselves
+                    conn.execute("ROLLBACK")
+                raise
         _wrote(t0)
 
     def delete(self, key: bytes) -> None:
         conn = self._conn()
         t0 = time.perf_counter()
         conn.execute("DELETE FROM kv WHERE k=?", (key,))
-        conn.commit()
         _wrote(t0)
 
     def iterate_prefix(self, prefix: bytes):
@@ -112,10 +158,11 @@ class SQLiteDB:
 
 
 def _wrote(t0: float) -> None:
-    """One `db.write` flight-recorder record around a transaction
-    (execute + commit): what a store's caller spent in sqlite, so that
-    its own encoding and hashing is the rest of its span.  Bookkeeping,
-    so outside the attribution partition (CAT_NONE); MemDB has none."""
+    """One `db.write` flight-recorder record around a transaction (the
+    one statement, or BEGIN .. COMMIT of a chunked batch): what a
+    store's caller spent in sqlite, so that its own encoding and hashing
+    is the rest of its span.  Bookkeeping, so outside the attribution
+    partition (CAT_NONE); MemDB has none."""
     RECORDER.record("db.write", perf_to_epoch(t0), time.perf_counter() - t0,
                     None, cat=CAT_NONE)
 

@@ -60,10 +60,14 @@ def fast_sync_in_process(chain_id: str, n_blocks: int, batch_size: int,
     try:
         connect_switches(sync_sw, src_sw)
         deadline = time.time() + timeout
-        while bc.store.height < n_blocks - 1 and time.time() < deadline:
+        # a block is stored before it is applied: wait for the app hash
+        # too, or it is read a block early
+        want = chain[-1][0].header.app_hash
+        while ((bc.store.height < n_blocks - 1 or bc.state.app_hash != want)
+               and time.time() < deadline):
             time.sleep(0.02)
         assert bc.store.height >= n_blocks - 1, bc.pool.status()
-        assert bc.state.app_hash == chain[-1][0].header.app_hash
+        assert bc.state.app_hash == want
     finally:
         src_sw.stop()
         sync_sw.stop()

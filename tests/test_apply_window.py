@@ -3,6 +3,7 @@ app hash and state, same per-block hook order, and (with save_every=1)
 byte-identical persisted state — the license for the reactor and bench
 to amortize app-lock and state-save costs across a fast-sync window."""
 
+import sqlite3
 import threading
 
 import pytest
@@ -114,20 +115,21 @@ def test_apply_window_validation_failure_keeps_prefix(fixture):
 STAGES = ["store_save", "validate", "abci_exec", "save_responses",
           "update_state", "abci_commit", "state_save", "advance"]
 # sqlite transactions a block makes, by the stage that makes them
-WRITES = {"store_save": 1, "save_responses": 1, "state_save": 2}
+WRITES = {"store_save": 1, "save_responses": 1, "state_save": 1}
 CLOCK = 2e-6       # an epoch timestamp holds a quarter of a microsecond
 
 
-def _sqlite_apply(tmp_path, gen, chain, name, windowed):
+def _sqlite_apply(tmp_path, gen, chain, name, windowed, state_db=None,
+                  conns=None):
     """Apply the first 3 blocks on sqlite stores, store saved before
     state as the reactor does; returns (state, state db, store db)."""
     from tendermint_tpu.blockchain.store import BlockStore
     from tendermint_tpu.utils.db import SQLiteDB
-    sdb = SQLiteDB(str(tmp_path / f"{name}-state.db"))
+    sdb = (state_db or SQLiteDB)(str(tmp_path / f"{name}-state.db"))
     bdb = SQLiteDB(str(tmp_path / f"{name}-blocks.db"))
     state = get_state(sdb, gen)
     store = BlockStore(bdb)
-    conns = ClientCreator("kvstore").new_app_conns()
+    conns = conns or ClientCreator("kvstore").new_app_conns()
     seen = {b.height: c for b, _ps, c in chain}
     parts = {b.height: ps for b, ps, _c in chain}
     if windowed:
@@ -187,6 +189,95 @@ def test_apply_window_records_eight_contiguous_stages_a_block(fixture,
     assert sdb.iterate_prefix(b"") == ref_sdb.iterate_prefix(b"")
     assert bdb.iterate_prefix(b"") == ref_bdb.iterate_prefix(b"")
     assert len(bdb.iterate_prefix(b"")) > 3 * 4
+
+
+def test_apply_window_makes_three_transactions_a_block(fixture, tmp_path):
+    """Block store, ABCI responses, state: with `save_every=1` on
+    sqlite stores a block is three `db.write`, in that order in time
+    (the responses are durable before the app commits, so they are not
+    in the state's transaction)."""
+    from tendermint_tpu.utils import tracing
+    gen, chain = fixture
+    t_start = tracing.now_epoch()
+    _sqlite_apply(tmp_path, gen, chain, "t", windowed=True)
+    me = [s for s in tracing.RECORDER.since(t_start)
+          if s["ts"] >= t_start and
+          s["tid"] == threading.current_thread().ident]
+    starts = [s["ts"] for s in me
+              if s["name"] == "fastsync.apply.store_save"]
+    commits = [s["ts"] for s in me
+               if s["name"] == "fastsync.apply.abci_commit"]
+    assert len(starts) == len(commits) == 3
+    writes = [s["ts"] for s in me if s["name"] == "db.write" and
+              s["ts"] >= starts[0] - CLOCK]
+    assert len(writes) == 3 * 3
+    for i, (t0, t_commit) in enumerate(zip(starts, commits)):
+        t1 = starts[i + 1] if i + 1 < len(starts) else float("inf")
+        block = [w for w in writes if t0 - CLOCK <= w < t1 - CLOCK]
+        assert len(block) == 3
+        # two before the app commits (block, responses), the state after
+        assert [w < t_commit for w in block] == [True, True, False]
+
+
+def test_state_save_killed_between_its_rows_leaves_the_old_height(fixture,
+                                                                  tmp_path):
+    """`State.save` is one transaction.  A death after its first row
+    (the state) and before its second (the next validators record)
+    leaves both records of the height before, never the state without
+    its validators record; the handshake then recovers from store =
+    state + 1 with the app already committed."""
+    from tendermint_tpu.blockchain.store import BlockStore
+    from tendermint_tpu.consensus.replay import Handshaker
+    from tendermint_tpu.crypto import backend as cb
+    from tendermint_tpu.utils.db import SQLiteDB
+    gen, chain = fixture
+
+    class Killed(Exception):
+        pass
+
+    class DiesInThirdSave(SQLiteDB):
+        """The state db of a node that dies inside the save of height
+        3, after the state row and before `validatorsKey:4`: the batch
+        that ends in that key reaches sqlite with every row after its
+        first invalid, so that sqlite itself stops between the rows of
+        the one statement."""
+
+        def set_batch(self, kvs):
+            if kvs[-1][0] != b"validatorsKey:4":
+                return super().set_batch(kvs)
+            try:
+                super().set_batch(kvs[:1] + [(k, None) for k, _ in kvs[1:]])
+            except sqlite3.IntegrityError as e:
+                raise Killed(kvs[-1][0]) from e
+
+    conns = ClientCreator("kvstore").new_app_conns()
+    with pytest.raises(Killed):
+        _sqlite_apply(tmp_path, gen, chain, "k", windowed=True,
+                      state_db=DiesInThirdSave, conns=conns)
+
+    # the restart: both dbs opened anew, the app as the crash left it
+    sdb = SQLiteDB(str(tmp_path / "k-state.db"))
+    store = BlockStore(SQLiteDB(str(tmp_path / "k-blocks.db")))
+    state = get_state(sdb, gen)
+    assert state.last_block_height == 2
+    assert state.load_validators(3) is not None
+    assert sdb.get(b"validatorsKey:4") is None
+    assert state.load_abci_responses(3) is not None
+    assert store.height == 3
+    assert conns.query.info().last_block_height == 3
+    # the handshake checks block 3's last commit: on the host, as
+    # tests/test_replay.py does (the default backend compiles for it)
+    old = cb._current
+    cb.set_backend("python")
+    try:
+        Handshaker(state, store).handshake(conns)
+    finally:
+        cb._current = old
+    assert state.last_block_height == 3
+    ref_state, ref_sdb, _ = _sqlite_apply(tmp_path, gen, chain, "kr",
+                                          windowed=False)
+    assert state.encode() == ref_state.encode()
+    assert sdb.iterate_prefix(b"") == ref_sdb.iterate_prefix(b"")
 
 
 def test_memdb_records_no_db_write(fixture):
