@@ -261,6 +261,7 @@ def phase_sync(workdir, chain_id, chain, gen, sizes, facts) -> None:
                  f"fast-sync to height {target} (at "
                  f"{node.block_store.height})")
         sync_wall = time.monotonic() - t0
+        t_synced = tracing.now_epoch()
         wait_for(lambda: node.consensus.get_round_state_summary()["height"]
                  == target + 1, 30, "hand-off to consensus")
         wait_for(lambda: not precompile_running(), DEADLINE_S / 2,
@@ -327,8 +328,12 @@ def phase_sync(workdir, chain_id, chain, gen, sizes, facts) -> None:
                      ("fastsync.verify", "fastsync.lookahead")),
                     key=lambda s: s["ts"] + s["dur"])
         t_first = first["ts"] + first["dur"]
+        # ... and before the tip: at the hand-off the node starts warming
+        # the live path's programs (`Node._maybe_precompile`), and
+        # consensus may ask for one of them first
         late = kernel_compiles(s for s in compile_spans(t_first)
-                               if s["thread"] != "crypto-precompile")
+                               if s["thread"] != "crypto-precompile"
+                               and s["ts"] < t_synced)
         check(not late, f"kernel compiles after the warm-up window: "
               f"{[(s['args']['fn'], s['thread']) for s in late]}")
         # from here on (boot precompile done too) nothing may compile a

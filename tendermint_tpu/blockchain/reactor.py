@@ -103,6 +103,12 @@ class BlockchainReactor(Reactor):
             max(block_store.height, state.last_block_height) + 1)
         self.pool.on_evict = self._on_pool_evict
         self.on_caught_up = None          # cb(state) -> switch_to_consensus
+        # an Event the sync waits for before it requests a block, or None:
+        # the node sets it when the plane can verify a window.  Blocks
+        # that arrive sooner only wait, and the sync thread's first verify
+        # then races the warm-up to load the same program: a boot of 28
+        # or of 37 s with 273 KB blocks (PERF.md §6, PR 29)
+        self.request_when: threading.Event | None = None
         self._stopped = threading.Event()
         self._thread: threading.Thread | None = None
         self._switched = False
@@ -212,7 +218,8 @@ class BlockchainReactor(Reactor):
                         BLOCKCHAIN_CHANNEL,
                         BM.encode_msg(BM.StatusRequest()))
                 last_status = now
-            self._send_requests()
+            if self.request_when is None or self.request_when.is_set():
+                self._send_requests()
             try:
                 progressed = self._sync_step()
             except Exception:
@@ -232,6 +239,12 @@ class BlockchainReactor(Reactor):
     def _send_requests(self) -> None:
         if self.switch is None:
             return
+        # what has arrived of each peer's unfinished message: the pool
+        # sees whole blocks only, and a peer that is sending is not
+        # silent yet (`BlockPool._waiting_since` bounds what that buys)
+        self.pool.note_receiving({
+            peer.id: peer.receiving(BLOCKCHAIN_CHANNEL)
+            for peer in self.switch.peers()})
         for height, peer_id in self.pool.schedule():
             peer = self.switch.get_peer(peer_id)
             if peer is not None:

@@ -661,43 +661,67 @@ class TpuBackend:
         _d2h(out)
         return out[:n]
 
-    def precompile_for_validators(self, vals) -> None:
+    def precompile_for_validators(self, vals, stage: str = "all",
+                                  stop=None) -> None:
         """Warm the full crypto plane for a ValidatorSet: THE shared
         derivation of which (lanes, templates) shapes a node produces —
         node boot (`node/node.py _maybe_precompile`) and `cli init
         --warm-crypto` must warm the IDENTICAL set or the "warm first
-        boot" guarantee silently regresses when one site changes."""
+        boot" guarantee silently regresses when one site changes.
+
+        A node that fast-syncs warms that set in two stages: "catchup",
+        at boot, is the one program it runs before the tip (a window's
+        templated verify); "live" is the other five, once it has caught
+        up.  Each program costs seconds of Python tracing under the GIL
+        even where its executable loads from the cache, and during
+        catch-up the block download pays for them (PERF.md §6, PR 29).
+        "all" is both stages in one call.  `stop`, an Event, ends the
+        warm-up before its next program."""
         from tendermint_tpu.blockchain.reactor import DEFAULT_BATCH
         from tendermint_tpu.types import canonical
         v = max(vals.size(), 1)
         # a single gossiped vote, one commit (V lanes / 1 template), and
         # a full fast-sync verify window (DEFAULT_BATCH blocks x V
         # lanes, ~one template per block when commits are unanimous)
-        shapes = sorted({(MIN_BUCKET, 1), (_bucket(v), 1),
-                         (_bucket(DEFAULT_BATCH * v), DEFAULT_BATCH)})
-        self.precompile(vals.set_key(), vals.pubs_matrix(), shapes,
-                        canonical.SIGN_BYTES_LEN)
+        window = (_bucket(DEFAULT_BATCH * v), DEFAULT_BATCH)
+        shapes = sorted({(MIN_BUCKET, 1), (_bucket(v), 1), window})
+        # the plain path serves VoteSet.add_votes_batched, the templated
+        # path verify_commit and fast-sync windows
+        programs = [(kind, n, t) for n, t in shapes
+                    for kind in ("plain", "templated")]
+        catchup = ("templated",) + window
+        if stage == "catchup":
+            programs = [catchup]
+        elif stage == "live":
+            programs.remove(catchup)
+        elif stage != "all":
+            raise ValueError(f"unknown precompile stage {stage!r}")
+        self.precompile(vals.set_key(), vals.pubs_matrix(), programs,
+                        canonical.SIGN_BYTES_LEN, stop)
 
     def precompile(self, set_key: bytes, val_pubs: np.ndarray,
-                   shapes: list[tuple[int, int]], msg_len: int) -> None:
+                   programs: list[tuple[str, int, int]],
+                   msg_len: int, stop=None) -> None:
         """Warm the comb tables for a validator set and the verify
-        executables for the standard (lanes, templates) shapes — a cold
-        node joining a net must not stall for a minute of XLA compile on
-        its first commit (the compiles also land in the persistent
-        cache).  Run it from a background thread at boot; every call is
-        harmless dummy work through the real entry points.  Template
-        counts must be the PRE-bucket values the real workload produces
-        (the jit shape is the bucketed count, derived identically here)."""
+        executables for `programs`, each a (kind, lanes, templates) with
+        kind "plain" or "templated" — a cold node joining a net must not
+        stall for a minute of XLA compile on its first commit (the
+        compiles also land in the persistent cache).  Run it from a
+        background thread at boot; every call is harmless dummy work
+        through the real entry points.  Template counts must be the
+        PRE-bucket values the real workload produces (the jit shape is
+        the bucketed count, derived identically here)."""
         n_vals = len(val_pubs)
-        for n, t in shapes:
+        for kind, n, t in programs:
+            if stop is not None and stop.is_set():
+                return
             idx = (np.arange(n) % n_vals).astype(np.int32)
             sigs = np.zeros((n, 64), dtype=np.uint8)
-            # the plain path serves VoteSet.add_votes_batched ...
-            self.verify_grouped(set_key, val_pubs, idx,
-                                np.zeros((n, msg_len), dtype=np.uint8),
-                                sigs)
-            # ... and the templated path serves verify_commit /
-            # fast-sync windows
+            if kind == "plain":
+                self.verify_grouped(set_key, val_pubs, idx,
+                                    np.zeros((n, msg_len), dtype=np.uint8),
+                                    sigs)
+                continue
             t = max(1, t)
             self.verify_grouped_templated(
                 set_key, val_pubs, idx,

@@ -471,7 +471,8 @@ class SupervisedBackend:
             out[i] = np.frombuffer(sig, np.uint8)
         return out
 
-    def precompile_for_validators(self, vals) -> None:
+    def precompile_for_validators(self, vals, stage: str = "all",
+                                  stop=None) -> None:
         """Warm-up is best-effort: a fault during precompile must not
         trip the breaker (nothing was being verified) or crash boot."""
         for rung in self._rungs:
@@ -479,7 +480,7 @@ class SupervisedBackend:
             if fn is None:
                 continue
             try:
-                fn(vals)
+                fn(vals, stage, stop)
             except Exception:
                 log.exception("crypto precompile failed on rung",
                               rung=rung.name)

@@ -127,13 +127,15 @@ class Node:
         # A cold validator joining mid-height must not stall for the
         # first-verify XLA compile (SURVEY §5: measured ~1-2 min cold);
         # warm the current valset's tables + standard lane buckets while
-        # the node boots.  Daemon thread: never blocks startup/shutdown.
+        # the node boots.  Daemon threads: never block startup; `stop()`
+        # waits for the program one is warming.
+        self._stopped = threading.Event()
+        self._warm_ups: list[threading.Thread] = []
         self._maybe_precompile()
 
         # --- RPC ---
         self.rpc_server = None
         self.grpc_server = None
-        self._stopped = threading.Event()
 
     def _valset_at(self, height: int):
         """The validator set that signed votes at `height`: from saved
@@ -151,23 +153,52 @@ class Node:
         return self.consensus.state
 
     def _maybe_precompile(self) -> None:
+        """Warm the crypto plane on daemon threads named
+        `crypto-precompile`.  A node that fast-syncs warms only the
+        window's program at boot, asks for blocks once that is done, and
+        warms the rest when it has caught up (`precompile_for_validators`'
+        stages): each program is seconds of Python tracing under the
+        GIL, which the block download needs, and a sync that starts
+        beside the warm-up races it for the window's program."""
         from tendermint_tpu.crypto import backend as cb
         be = cb.get_backend()
         if not hasattr(be, "precompile_for_validators"):
             return
+
+        def warm(vals, stage: str, done=None) -> None:
+            def run():
+                try:
+                    t0 = time.time()
+                    be.precompile_for_validators(vals, stage, self._stopped)
+                    log.info("crypto precompile done", stage=stage,
+                             validators=vals.size(),
+                             seconds=round(time.time() - t0, 1))
+                except Exception:
+                    log.exception("crypto precompile failed", stage=stage)
+                finally:
+                    if done is not None:
+                        done.set()
+
+            t = threading.Thread(target=run, daemon=True,
+                                 name="crypto-precompile")
+            self._warm_ups.append(t)
+            t.start()
+
         vals = self.consensus.state.validators
+        bc = (self.switch.reactor("blockchain")
+              if self.switch is not None else None)
+        if bc is None or not bc.fast_sync:
+            warm(vals, "all")
+            return
+        bc.request_when = threading.Event()
+        warm(vals, "catchup", done=bc.request_when)
+        hand_over = bc.on_caught_up
 
-        def warm():
-            try:
-                t0 = time.time()
-                be.precompile_for_validators(vals)
-                log.info("crypto precompile done", validators=vals.size(),
-                         seconds=round(time.time() - t0, 1))
-            except Exception:
-                log.exception("crypto precompile failed")
+        def caught_up(state) -> None:
+            warm(state.validators, "live")
+            hand_over(state)
 
-        threading.Thread(target=warm, daemon=True,
-                         name="crypto-precompile").start()
+        bc.on_caught_up = caught_up
 
     def _maybe_build_p2p(self) -> None:
         """Wire the p2p stack when a listen address is configured; a
@@ -209,6 +240,10 @@ class Node:
             self.switch.stop()
         self.consensus.stop()
         self.mempool.close()
+        # a warm-up ends before its next program: a process that exits
+        # with a thread inside an XLA compile aborts instead
+        for t in self._warm_ups:
+            t.join(timeout=120)
 
     def run_forever(self) -> None:
         """Reference `RunForever` node/node.go:288."""

@@ -281,6 +281,13 @@ class MConnection:
         except Exception as e:
             self._die(e)
 
+    def receiving(self, ch_id: int) -> int:
+        """Bytes that have arrived of the message now being received on
+        `ch_id` (0 between messages): a reactor whose messages take
+        seconds to cross the link reads its peer's progress here."""
+        ch = self._channels.get(ch_id)
+        return len(ch.recving) if ch is not None else 0
+
     def status(self) -> dict:
         """Flowrate + channel-occupancy snapshot (reference
         `ConnectionStatus`, p2p/connection.go:485-515: SendMonitor /

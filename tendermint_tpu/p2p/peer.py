@@ -56,6 +56,10 @@ class Peer:
             return False
         return self.mconn.try_send(ch_id, msg)
 
+    def receiving(self, ch_id: int) -> int:
+        """Bytes arrived of the message now crossing the link on `ch_id`."""
+        return self.mconn.receiving(ch_id)
+
     def stop(self) -> None:
         self.mconn.stop()
 

@@ -144,7 +144,7 @@ def test_timed_out_slot_is_rerequested_and_the_late_answer_dropped(
             if s["ts"] >= t_start and s["name"].startswith("pool.")]
     assert [(s["name"], s["ph"], s["args"]) for s in mine] == [
         ("pool.rerequest", "i", {"height": 1, "old": "first-peer-i",
-                                 "new": "second-peer-"}),
+                                 "new": "second-peer-", "reason": "silent"}),
         ("pool.late_block", "i", {"height": 1, "peer": "first-peer-i",
                                   "bytes": len(raw)}),
         ("pool.late_block", "i", {"height": 1, "peer": "second-peer-",
@@ -211,8 +211,11 @@ def test_fast_sync_end_to_end():
         connect_switches(sync_sw, src_sw)
         # the tip block can't be verified without a successor, so fast-sync
         # stops at N-1 and hands off to consensus
+        # (a block is stored before it is applied: wait for both)
         deadline = time.time() + 30
-        while sync_store.height < N_BLOCKS - 1 and time.time() < deadline:
+        while (sync_store.height < N_BLOCKS - 1 or
+               bc.state.app_hash != hashes[N_BLOCKS - 1]) and \
+                time.time() < deadline:
             time.sleep(0.02)
         assert sync_store.height >= N_BLOCKS - 1, \
             f"synced only to {sync_store.height}: {bc.pool.status()}"

@@ -151,6 +151,26 @@ def test_mconnection_large_message_chunked():
         m1.stop(); m2.stop()
 
 
+def test_mconnection_reports_what_has_arrived_of_an_unfinished_message():
+    """`receiving(ch)` grows while a long message crosses a slow link
+    and is 0 again once it is whole: a reactor reads its peer's progress
+    there (the block pool's silence clock)."""
+    m1, m2, r1, r2 = _mconn_pair(send_rate=40_000, recv_rate=40_000)
+    try:
+        big = bytes(range(256)) * 160  # 40 KB: a second at this rate
+        assert m2.receiving(1) == 0 and m2.receiving(99) == 0
+        assert m1.send(1, big)
+        seen = []
+        assert _wait_for(lambda: seen.append(m2.receiving(1)) or
+                         len(r2) == 1, timeout=10)
+        assert r2[0] == (1, big) and m2.receiving(1) == 0
+        mid = [n for n in seen if n]
+        assert mid and mid == sorted(mid) and 0 < mid[0] <= mid[-1] < len(big)
+        assert m2.receiving(2) == 0
+    finally:
+        m1.stop(); m2.stop()
+
+
 def test_mconnection_on_error_fires_on_close():
     errs = []
     c1, c2 = mem_pair()
