@@ -18,6 +18,37 @@ from tendermint_tpu.utils.log import get_logger
 
 log = get_logger("p2p")
 
+_RECV_SIZE = 1 << 16
+
+
+class ReadBuffer:
+    """Exact reads out of pieces that come in sizes of their own: what a
+    socket hands over, or the frames of an encrypted link.  `fill(need)`
+    brings the next piece when `need` bytes are still missing, and
+    raises when no more can come.  One reader at a time."""
+
+    __slots__ = ("_fill", "_buf", "_pos")
+
+    def __init__(self, fill):
+        self._fill = fill
+        self._buf = b""
+        self._pos = 0          # _buf is read up to here
+
+    def read_exact(self, n: int) -> bytes:
+        buf, pos = self._buf, self._pos
+        end = pos + n
+        if end > len(buf):
+            pieces = [buf[pos:]]
+            need = end - len(buf)
+            while need > 0:
+                piece = self._fill(need)
+                pieces.append(piece)
+                need -= len(piece)
+            buf = self._buf = b"".join(pieces)
+            pos, end = 0, n
+        self._pos = end
+        return buf[pos:end]
+
 
 class StreamConn:
     """Blocking duplex byte stream over a socket with exact-read semantics."""
@@ -27,15 +58,19 @@ class StreamConn:
         self.label = label
         self._wlock = threading.Lock()
         self._closed = threading.Event()
+        self._reader = ReadBuffer(self._recv)
+
+    def _recv(self, need: int) -> bytes:
+        # whatever has arrived, up to _RECV_SIZE: a reader that has
+        # fallen behind finds its next reads in the buffer and does not
+        # go to the socket, and hand the GIL over, twice a frame
+        chunk = self._sock.recv(max(need, _RECV_SIZE))
+        if not chunk:
+            raise ConnectionError("connection closed")
+        return chunk
 
     def read_exact(self, n: int) -> bytes:
-        buf = bytearray()
-        while len(buf) < n:
-            chunk = self._sock.recv(n - len(buf))
-            if not chunk:
-                raise ConnectionError("connection closed")
-            buf += chunk
-        return bytes(buf)
+        return self._reader.read_exact(n)
 
     def write(self, data: bytes) -> None:
         with self._wlock:
