@@ -217,8 +217,7 @@ def compile_spans(since_epoch: float):
 # thread wins the race to load a table — counted and printed, not failed.
 KERNELS = frozenset(f"jit({n})" for n in (
     "verify", "verify_grouped", "verify_grouped_templated",
-    "sign_grouped_templated", "build_neg_comb", "leaf_hashes", "roots",
-    "root_from_leaf_hashes"))
+    "build_neg_comb", "leaf_hashes", "roots", "root_from_leaf_hashes"))
 
 
 def kernel_compiles(spans):

@@ -4,8 +4,8 @@ The framework carries three hand-maintained invariant families that
 nothing used to enforce: lock discipline across the threaded modules
 (the reference implementation leans on Go's race detector, which the
 Python port lost), JAX hot-path hygiene (the runtime doctor can only
-observe a shape-drift recompile or an implicit host sync on paths the
-bench happens to exercise), and registration conventions (unsafe-gating
+observe a shape-drift recompile or an implicit host sync on paths a
+run happens to exercise), and registration conventions (unsafe-gating
 of `debug_*`/`unsafe_*` RPC routes, category-prefixed span names feeding
 `utils/attribution.py`, Prometheus-valid metric names).  tmlint makes
 violations fail tier-1 instead of surfacing as a 12x bench regression or

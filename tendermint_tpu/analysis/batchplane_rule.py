@@ -4,14 +4,14 @@
   ``light/``, ``mempool/``, ``blockchain/``, ``types/``) must submit
   signature-verify work through ``tendermint_tpu.batchplane`` — never
   call ``crypto.backend``'s ``verify_batch`` / ``verify_grouped`` /
-  ``verify_grouped_templated[_async]`` directly.  A direct call bypasses
+  ``verify_grouped_templated`` directly.  A direct call bypasses
   the shared scheduler: its lanes cannot coalesce with concurrent
   producers, ignore priority classes (a light-client flood would no
   longer yield to consensus votes), and skip the plane's occupancy /
   wait-time accounting, so the doctor's half-full-batch attribution
   under-reports.  The scheduler itself (``batchplane/``), the backend
-  ladder (``crypto/``), device layers (``ops/``, ``parallel/``) and the
-  bench harness stay direct by design.
+  ladder (``crypto/``) and the device layers (``ops/``, ``parallel/``)
+  stay direct by design.
 """
 
 from __future__ import annotations
@@ -26,8 +26,7 @@ _PRODUCER_PREFIXES = ("consensus/", "light/", "mempool/", "blockchain/",
                       "types/")
 
 _VERIFY_METHODS = {"verify_batch", "verify_grouped",
-                   "verify_grouped_templated",
-                   "verify_grouped_templated_async"}
+                   "verify_grouped_templated"}
 
 _BACKEND_MODULE = "tendermint_tpu.crypto.backend"
 

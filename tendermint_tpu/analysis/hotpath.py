@@ -1,7 +1,7 @@
 """tmlint JAX hot-path hygiene rules.
 
 The PR-3 doctor can *observe* a shape-drift recompile or an implicit
-host sync at runtime, but only on paths the bench happens to exercise.
+host sync at runtime, but only on paths a run happens to exercise.
 These rules catch the same hazards statically, in the modules that are
 on the device hot path (``ops/``, ``crypto/``, ``parallel/``):
 
@@ -46,12 +46,11 @@ HOT_PATH_DIRS = ("ops/", "crypto/", "parallel/")
 # lane-mask back to return Python bools.  Function-scoped (not
 # line-numbered) so edits inside the file don't rot the allowlist.
 ALLOWED_SYNC_FUNCS = {
-    # verify/sign API boundary: device lane-masks become Python bools
+    # verify API boundary: device lane-masks become Python bools
     # for the consensus/fast-sync callers — the sync IS the contract
     ("crypto/backend.py", "TpuBackend.verify_batch"),
     ("crypto/backend.py", "TpuBackend.verify_grouped"),
     ("crypto/backend.py", "TpuBackend.verify_grouped_templated"),
-    ("crypto/backend.py", "TpuBackend.sign_grouped_templated"),
     # comb-table build commits tables to device memory before the
     # fsync'd on-disk cache write (backend.py "tbl.block_until_ready()")
     ("crypto/backend.py", "TpuBackend._build_tables"),
@@ -353,79 +352,6 @@ class RetraceRule(Rule):
         return [n for n in ast.walk(test)
                 if isinstance(n, ast.Name) and n.id in traced
                 and id(n) not in static_parents]
-
-
-@register
-class BenchScalarLoopRule(Rule):
-    """The replay pipeline's host stages (bench.prep / bench.apply spans)
-    overlap the device stage only while they hold the GIL briefly — a
-    per-item Python loop inside one turns the stage back into the scalar
-    tail the PR-12 vectorization removed (window_commit_lanes /
-    apply_window).  Statement loops only: comprehensions and
-    numpy/executor calls are the sanctioned idiom."""
-
-    name = "bench-scalar-loop"
-    description = ("per-item Python for/while inside a prep/apply-"
-                   "categorized bench.* tracing span; vectorize the "
-                   "window (window_commit_lanes, execution.apply_window) "
-                   "instead")
-
-    def visit_file(self, ctx: FileCtx):
-        # deliberately NOT hot-path-dir-gated: the spans live in bench.py
-        from tendermint_tpu.utils.tracing import (CAT_APPLY, CAT_PREP,
-                                                  default_category)
-        for node in ast.walk(ctx.tree):
-            if not isinstance(node, ast.With):
-                continue
-            span_name = self._span_name(node)
-            if span_name is None or not span_name.startswith("bench."):
-                continue
-            if default_category(span_name) not in (CAT_PREP, CAT_APPLY):
-                continue
-            for loop in self._stmt_loops(node.body):
-                yield ctx.finding(
-                    self.name, loop,
-                    f"per-item {type(loop).__name__.lower()} loop inside "
-                    f"the {span_name!r} span serializes a pipeline host "
-                    f"stage under the GIL; assemble the window in one "
-                    f"vectorized pass (window_commit_lanes / "
-                    f"execution.apply_window)")
-
-    @staticmethod
-    def _span_name(node: ast.With):
-        """The string-constant name of a tracing span opened by this
-        `with`, or None (dynamic names can't be categorized statically)."""
-        for item in node.items:
-            call = item.context_expr
-            if not isinstance(call, ast.Call) or not call.args:
-                continue
-            fn = call.func
-            if not ((isinstance(fn, ast.Name) and fn.id == "span")
-                    or (isinstance(fn, ast.Attribute)
-                        and fn.attr == "span")):
-                continue
-            arg0 = call.args[0]
-            if isinstance(arg0, ast.Constant) and isinstance(arg0.value,
-                                                             str):
-                return arg0.value
-        return None
-
-    @staticmethod
-    def _stmt_loops(stmts):
-        """Outermost statement-level loops under `stmts`, not descending
-        into nested function/lambda definitions (a helper DEFINED inside
-        the span body runs elsewhere)."""
-        out, stack = [], list(stmts)
-        while stack:
-            n = stack.pop()
-            if isinstance(n, (ast.FunctionDef, ast.AsyncFunctionDef,
-                              ast.Lambda)):
-                continue
-            if isinstance(n, (ast.For, ast.AsyncFor, ast.While)):
-                out.append(n)
-                continue
-            stack.extend(ast.iter_child_nodes(n))
-        return sorted(out, key=lambda n: n.lineno)
 
 
 @register

@@ -226,6 +226,11 @@ class BlockchainReactor(Reactor):
                 log.exception("sync step failed",
                               next_height=self.pool.next_height)
                 progressed = False
+            if self._stopped.is_set():
+                # stopped during this step (its last window, perhaps): a
+                # node that is going down hands nothing to consensus and
+                # starts no live warm-up (`caught_up` in node/node.py)
+                return
             if self.pool.is_caught_up() and not self._switched:
                 self._switched = True
                 log.info("fast-sync caught up",
@@ -423,8 +428,8 @@ class BlockchainReactor(Reactor):
         with tracing.span("fastsync.apply", first_height=window[0].height,
                           blocks=len(window)) as args:
             # the window-batched apply: per-block validate/exec/save
-            # discipline identical to apply_block (save_every=1 — a
-            # durable node must keep store <= state+1 for the
+            # discipline identical to apply_block (one state save a
+            # block: a durable node must keep store <= state+1 for the
             # handshake), but the app conn's lock is held once for the
             # whole window instead of ~4 acquisitions per block
             cpu0 = time.thread_time()
@@ -432,8 +437,8 @@ class BlockchainReactor(Reactor):
                 self.state, None, self.proxy,
                 [(b, p.header) for b, p in zip(window, parts_list)],
                 execution.MockMempool(), check_last_commit=False,
-                save_every=1, before_block=_save_to_store,
-                on_applied=_advance, stop_when=_valset_moved)
+                before_block=_save_to_store, on_applied=_advance,
+                stop_when=_valset_moved)
             # this thread's own CPU: wall - cpu_s is what apply waited,
             # for the GIL (look-ahead and p2p decode run meanwhile) or
             # for sqlite's I/O

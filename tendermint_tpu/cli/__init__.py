@@ -622,7 +622,7 @@ def cmd_doctor(args) -> int:
     """Pipeline attribution report: where the wall clock of a replay
     went (compile / transfer / device-busy / scalar / idle) and which
     component is the largest thief of the throughput target.  Reads a
-    dumped trace file (--trace, e.g. bench_trace.json) or a live node's
+    dumped trace file (--trace, as `cli trace` writes) or a live node's
     flight recorder over unsafe RPC (--rpc)."""
     from tendermint_tpu.utils import attribution, ledger as ledger_mod
     if args.trace:
@@ -727,9 +727,6 @@ def cmd_lint(args) -> int:
         paths, root = args.paths, None
     else:
         paths = [pkg_dir]
-        bench = os.path.join(repo_root, "bench.py")
-        if os.path.exists(bench):
-            paths.append(bench)
         root = repo_root
     missing = [p for p in paths if not os.path.exists(p)]
     if missing:
@@ -1267,7 +1264,7 @@ def main(argv=None) -> int:
                         help="render the bench regression ledger with "
                              "per-config deltas vs best prior run")
     sp.add_argument("--ledger", default="BENCH_LEDGER.jsonl",
-                    help="ledger JSONL path (bench.py --ledger)")
+                    help="ledger JSONL path")
     sp.set_defaults(fn=cmd_bench_history)
 
     sp = sub.add_parser("lint",
@@ -1276,7 +1273,7 @@ def main(argv=None) -> int:
                              "route gating, span/metric conventions)")
     sp.add_argument("paths", nargs="*",
                     help="files/dirs to lint (default: the installed "
-                         "tendermint_tpu package + bench.py)")
+                         "tendermint_tpu package)")
     sp.add_argument("--json", action="store_true",
                     help="machine-readable findings document")
     sp.add_argument("--rules", default="",

@@ -216,8 +216,6 @@ class TpuBackend:
         self._base_tbl = jnp.asarray(_curve._base_table())
         # set_key -> (tbl, ok, V, staged key matrix)
         self._tables: dict[bytes, tuple] = {}
-        # seed-set hash -> staged (a, prefix, pubkey) sign matrices
-        self._sign_keys: dict[bytes, tuple] = {}
         self._tables_lock = threading.Lock()
         self._builds: dict[bytes, threading.Event] = {}  # in-flight builds
         # multi-chip: shard verify lanes over every visible device (comb
@@ -481,56 +479,9 @@ class TpuBackend:
         """Grouped verify shipping only (sig, val_idx, tmpl_idx) lanes
         plus T message templates; messages and pubkeys assemble on
         device (see ops.ed25519.verify_grouped_templated)."""
-        return self.verify_grouped_templated_async(
-            set_key, val_pubs, val_idx, tmpl_idx, templates, sigs)()
-
-    def prefetch_grouped_lanes(self, val_idx, tmpl_idx, templates, sigs):
-        """Pad lanes/templates to THIS backend's buckets and start the
-        async host->device copies — for pipeline prep stages that want
-        the multi-MB transfer riding the link while they keep hashing.
-        Returns (val_idx, tmpl_idx, templates, sigs, real_n): device
-        arrays plus the REAL lane count to pass back through
-        `verify_grouped_templated_async(real_n=...)` so telemetry and
-        the result trim stay keyed to real lanes, not padding."""
-        import jax
         n = len(val_idx)
-        b = _bucket(n)
-        val_idx = np.asarray(val_idx, np.int32)
-        tmpl_idx = np.asarray(tmpl_idx, np.int32)
-        if b > n:
-            val_idx = np.concatenate(
-                [val_idx, np.repeat(val_idx[:1], b - n)])
-            tmpl_idx = np.concatenate(
-                [tmpl_idx, np.repeat(tmpl_idx[:1], b - n)])
-            sigs = np.concatenate([sigs, np.repeat(sigs[:1], b - n, 0)])
-        t = len(templates)
-        tb = _bucket(t)
-        if tb > t:
-            templates = np.concatenate(
-                [templates,
-                 np.zeros((tb - t, templates.shape[1]), np.uint8)])
-        _h2d(val_idx, tmpl_idx, templates, sigs)
-        with tracing.span("transfer.h2d", lanes=n,
-                          bytes=int(val_idx.nbytes + tmpl_idx.nbytes +
-                                    templates.nbytes + sigs.nbytes)):
-            return (jax.device_put(val_idx), jax.device_put(tmpl_idx),
-                    jax.device_put(templates), jax.device_put(sigs), n)
-
-    def verify_grouped_templated_async(self, set_key, val_pubs, val_idx,
-                                       tmpl_idx, templates, sigs,
-                                       real_n: int | None = None):
-        """Dispatching half of `verify_grouped_templated`: uploads the
-        lanes and queues the device step WITHOUT waiting, returning a
-        zero-arg closure that blocks for the result.  A pipeline caller
-        dispatches window k+1 before collecting window k, so the
-        multi-MB lane upload (the dominant per-window cost over a slow
-        host<->device link) overlaps the previous window's compute.
-        `real_n` marks inputs pre-padded by `prefetch_grouped_lanes`
-        (result trims and metrics key to it, not the padded length).
-        """
-        n = real_n if real_n is not None else len(val_idx)
         if n == 0:
-            return lambda: np.zeros(0, dtype=bool)
+            return np.zeros(0, dtype=bool)
         warm = self._warm_verify_if_cold(
             set_key, len(val_pubs), "templated",
             (_bucket(n), _bucket(len(templates)), templates.shape[1]))
@@ -545,13 +496,12 @@ class TpuBackend:
         if self._mesh_eligible(b):
             # mesh path: assemble messages host-side and ride the
             # sharded kernel (templates are tiny; the win is moot there)
-            out = self.verify_grouped(set_key, val_pubs,
-                                      np.asarray(val_idx)[:n],
-                                      np.asarray(templates)[
-                                          np.asarray(tmpl_idx)[:n]],
-                                      np.asarray(sigs)[:n])
-            return lambda: out
-        pad = b - len(val_idx)          # 0 for prefetched inputs
+            return self.verify_grouped(set_key, val_pubs,
+                                       np.asarray(val_idx),
+                                       np.asarray(templates)[
+                                           np.asarray(tmpl_idx)],
+                                       np.asarray(sigs))
+        pad = b - n
         if pad > 0:
             val_idx = np.concatenate([val_idx, np.repeat(val_idx[:1], pad)])
             tmpl_idx = np.concatenate([tmpl_idx,
@@ -564,8 +514,7 @@ class TpuBackend:
                 [templates, np.zeros((tb - t, templates.shape[1]),
                                      np.uint8)])
         jnp = self._jnp
-        if real_n is None:       # prefetched inputs were counted at put
-            _h2d(val_idx, tmpl_idx, templates, sigs)
+        _h2d(val_idx, tmpl_idx, templates, sigs)
         cold = _note_dispatch("verify_grouped_templated", tbl, val_idx,
                               tmpl_idx, templates, sigs)
         t0 = time.perf_counter()
@@ -575,90 +524,21 @@ class TpuBackend:
                 tbl, pub_ok, vp_dev, jnp.asarray(val_idx.astype(np.int32)),
                 jnp.asarray(tmpl_idx.astype(np.int32)),
                 jnp.asarray(templates), jnp.asarray(sigs), self._base_tbl)
-
-        def collect() -> np.ndarray:
-            # time only the wait-for-result here: a pipelined caller does
-            # host work for window k+1 between dispatch and collect, and
-            # folding that overlap into the histogram would skew the
-            # device-step metric upward (dispatch-to-collect wall is the
-            # caller's pipeline depth, not the device's step time)
-            t1 = time.perf_counter()
-            with tracing.span("verify.collect", lanes=n, bucket=b):
-                out = np.asarray(dev_out)
-            _d2h(out)
-            now = time.perf_counter()
-            REGISTRY.device_step_seconds.observe(now - t1)
-            REGISTRY.device_dispatch_seconds.observe(now - t0)
-            REGISTRY.device_step_hist.observe(now - t1)
-            REGISTRY.sigs_requested.inc(n)
-            REGISTRY.sigs_verified.inc(int(out[:n].sum()))
-            REGISTRY.verify_batches.inc()
-            REGISTRY.batch_occupancy.observe(n / b)
-            REGISTRY.batch_occupancy_hist.observe(n / b)
-            return out[:n]
-
-        return collect
-
-    def sign_grouped_templated(self, seeds, val_idx, tmpl_idx,
-                               templates) -> np.ndarray:
-        """Batched signing against a fixed seed set: lane i signs
-        templates[tmpl_idx[i]] with key seeds[val_idx[i]].  The device
-        runs the full RFC 8032 pipeline (`ops.ed25519
-        .sign_grouped_templated`); the host only derives each seed's
-        (clamped scalar, prefix, pubkey) triple once.  Bulk fixture and
-        testnet signing — the reference signs one vote at a time
-        (`types/priv_validator.go` SignVote)."""
-        import hashlib
-        n = len(val_idx)
-        if n == 0:
-            return np.zeros((0, 64), dtype=np.uint8)
-        key = hashlib.sha256(b"".join(bytes(s) for s in seeds)).digest()
-        with self._tables_lock:
-            ent = self._sign_keys.get(key)
-        if ent is None:
-            from tendermint_tpu.crypto import pure_ed25519 as _ref
-            v = len(seeds)
-            a = np.zeros((v, 32), np.uint8)
-            pre = np.zeros((v, 32), np.uint8)
-            pubs = np.zeros((v, 32), np.uint8)
-            for i, seed in enumerate(seeds):
-                ai, pi, pubi = _ref.expand_seed(bytes(seed))
-                a[i] = np.frombuffer(ai, np.uint8)
-                pre[i] = np.frombuffer(pi, np.uint8)
-                pubs[i] = np.frombuffer(pubi, np.uint8)
-            ent = tuple(self._jnp.asarray(x) for x in (a, pre, pubs))
-            with self._tables_lock:
-                # count-bounded (entries are three tiny device arrays),
-                # but rotating fixture sets must not accumulate forever
-                while len(self._sign_keys) >= 16:
-                    self._sign_keys.pop(next(iter(self._sign_keys)))
-                self._sign_keys.setdefault(key, ent)
-                ent = self._sign_keys[key]
-        a_dev, pre_dev, pubs_dev = ent
-        b = _bucket(n)
-        val_idx = np.asarray(val_idx, dtype=np.int32)
-        tmpl_idx = np.asarray(tmpl_idx, dtype=np.int32)
-        if b > n:
-            val_idx = np.concatenate([val_idx, np.repeat(val_idx[:1], b - n)])
-            tmpl_idx = np.concatenate([tmpl_idx,
-                                       np.repeat(tmpl_idx[:1], b - n)])
-        t = len(templates)
-        tb = _bucket(t)
-        if tb > t:
-            templates = np.concatenate(
-                [templates,
-                 np.zeros((tb - t, templates.shape[1]), np.uint8)])
-        jnp = self._jnp
-        _h2d(val_idx, tmpl_idx, templates)
-        cold = _note_dispatch("sign_grouped_templated", a_dev, val_idx,
-                              tmpl_idx, templates)
-        with _firstcall("sign_grouped_templated", cold), \
-                tracing.span("sign.batch", lanes=n, bucket=b):
-            out = np.asarray(self._dev.sign_grouped_templated_jit(
-                a_dev, pre_dev, pubs_dev, jnp.asarray(val_idx),
-                jnp.asarray(tmpl_idx), jnp.asarray(templates),
-                self._base_tbl))
+        # the step metrics time only the wait for the result: the
+        # dispatch above returns once the device step is queued
+        t1 = time.perf_counter()
+        with tracing.span("verify.collect", lanes=n, bucket=b):
+            out = np.asarray(dev_out)
         _d2h(out)
+        now = time.perf_counter()
+        REGISTRY.device_step_seconds.observe(now - t1)
+        REGISTRY.device_dispatch_seconds.observe(now - t0)
+        REGISTRY.device_step_hist.observe(now - t1)
+        REGISTRY.sigs_requested.inc(n)
+        REGISTRY.sigs_verified.inc(int(out[:n].sum()))
+        REGISTRY.verify_batches.inc()
+        REGISTRY.batch_occupancy.observe(n / b)
+        REGISTRY.batch_occupancy_hist.observe(n / b)
         return out[:n]
 
     def precompile_for_validators(self, vals, stage: str = "all",
@@ -811,7 +691,7 @@ class TpuBackend:
 # The persistent caches (XLA executables here, comb tables under
 # tables/) live where JAX_COMPILATION_CACHE_DIR says.  jax reads that
 # variable itself, so when it is set this module sets no directory in
-# code; when it is not, everything that compiles — the node, bench.py,
+# code; when it is not, everything that compiles — the node,
 # chip_smoke.py, the test suite — shares ONE fixed path inside the
 # checkout.  The path is part of jax's cache key, so a cache that moves
 # never hits; a sealed machine that carries this one directory from run
@@ -894,10 +774,6 @@ _lock = threading.Lock()
 _current: Backend | None = None
 
 
-def register(name: str, factory) -> None:
-    _BACKENDS[name] = factory
-
-
 def set_backend(name: str) -> Backend:
     global _current
     if name not in _BACKENDS:
@@ -975,20 +851,3 @@ def verify_grouped_templated(set_key: bytes, val_pubs, val_idx, tmpl_idx,
         return fn(set_key, val_pubs, val_idx, tmpl_idx, templates, sigs)
     return verify_grouped(set_key, val_pubs, val_idx,
                           templates[tmpl_idx], sigs)
-
-
-def verify_grouped_templated_async(set_key: bytes, val_pubs, val_idx,
-                                   tmpl_idx, templates, sigs,
-                                   real_n: int | None = None):
-    """Pipelined form: dispatch now, collect via the returned closure.
-    Backends without async dispatch run synchronously and hand back the
-    finished result.  `real_n` marks inputs pre-padded by the backend's
-    `prefetch_grouped_lanes` (no-op for backends without it)."""
-    be = get_backend()
-    fn = getattr(be, "verify_grouped_templated_async", None)
-    if fn is not None:
-        return fn(set_key, val_pubs, val_idx, tmpl_idx, templates, sigs,
-                  real_n=real_n)
-    out = verify_grouped_templated(set_key, val_pubs, val_idx, tmpl_idx,
-                                   templates, sigs)
-    return lambda: out

@@ -140,18 +140,6 @@ def pubkey_from_seed(seed: bytes) -> bytes:
     return pt_encode(pt_mul(a, BASE))
 
 
-def expand_seed(seed: bytes) -> tuple[bytes, bytes, bytes]:
-    """RFC 8032 key expansion: seed -> (clamped scalar a as little-endian
-    bytes, prefix, pubkey A).  The ONE home of the clamp layout for
-    byte-level consumers (the device batch signer stages these arrays);
-    `sign`/`pubkey_from_seed` share the same `_clamp`."""
-    assert len(seed) == 32
-    h = hashlib.sha512(seed).digest()
-    a = _clamp(h[:32])
-    return (int.to_bytes(a, 32, "little"), h[32:],
-            pt_encode(pt_mul(a, BASE)))
-
-
 def sign(seed: bytes, msg: bytes) -> bytes:
     """RFC 8032 deterministic signature: 64 bytes R || S."""
     h = hashlib.sha512(seed).digest()

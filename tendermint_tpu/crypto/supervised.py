@@ -351,76 +351,6 @@ class SupervisedBackend:
                     int(np.asarray(tmpl_idx)[i])].tobytes(),
                 np.asarray(sigs)[i].tobytes()))
 
-    def verify_grouped_templated_async(self, set_key, val_pubs, val_idx,
-                                       tmpl_idx, templates, sigs,
-                                       real_n: int | None = None):
-        """Async dispatch rides the active rung when it supports it; a
-        fault at dispatch OR collect re-verifies the batch synchronously
-        down the ladder — pipelined callers see a slow window, never an
-        exception or a wrong answer."""
-        def sync_fallback() -> np.ndarray:
-            vi = np.asarray(val_idx)
-            ti = np.asarray(tmpl_idx)
-            sg = np.asarray(sigs)
-            n = real_n if real_n is not None else len(vi)
-            return self.verify_grouped_templated(
-                set_key, np.asarray(val_pubs), vi[:n], ti[:n],
-                np.asarray(templates), sg[:n])
-
-        rung = self._active_rung()
-        fn = getattr(rung.backend, "verify_grouped_templated_async", None) \
-            if rung is not None else None
-        if fn is None:
-            out = sync_fallback()
-            return lambda: out
-        try:
-            collect = self._invoke_async_dispatch(rung, fn, (
-                set_key, val_pubs, val_idx, tmpl_idx, templates, sigs),
-                real_n)
-        except DeviceFault as e:
-            self._on_fault(rung, e)
-            out = sync_fallback()
-            return lambda: out
-
-        def supervised_collect() -> np.ndarray:
-            try:
-                out = collect()
-            except Exception as e:
-                fault = e if isinstance(e, DeviceFault) else DeviceFault(
-                    f"{rung.name}.collect failed: {e!r}")
-                self._on_fault(rung, fault)
-                return sync_fallback()
-            self._on_success(rung)
-            return out
-
-        return supervised_collect
-
-    def _invoke_async_dispatch(self, rung: _Rung, fn, args, real_n):
-        """Dispatch half of the async path (can block on table builds, so
-        it gets the same timeout + fault normalization as a sync call)."""
-        chaos = self.chaos if rung.is_device else None
-
-        def run():
-            if chaos is not None:
-                chaos.before_call()
-            collect = fn(*args, real_n=real_n)
-            if chaos is not None:
-                inner = collect
-                return lambda: chaos.corrupt(inner())
-            return collect
-
-        rung.calls += 1
-        REGISTRY.crypto_rung_calls.labels(rung.name).inc()
-        try:
-            if self.call_timeout_s > 0 and rung.is_device:
-                return self._await(self._pool.submit(run),
-                                   f"{rung.name}.dispatch")
-            return run()
-        except DeviceFault:
-            raise
-        except Exception as e:
-            raise DeviceFault(f"{rung.name}.dispatch failed: {e!r}") from e
-
     def _active_rung(self) -> _Rung | None:
         """First rung the breaker currently admits."""
         for rung in self._rungs:
@@ -446,30 +376,6 @@ class SupervisedBackend:
             return True
         fn = getattr(rung.backend, "tables_cached", None)
         return True if fn is None else fn(set_key)
-
-    def sign_grouped_templated(self, seeds, val_idx, tmpl_idx,
-                               templates) -> np.ndarray:
-        """Batch signing rides the device when healthy; the reference
-        signs lane-by-lane otherwise (fixture/testnet path — correctness
-        over speed)."""
-        for rung in self._rungs:
-            fn = getattr(rung.backend, "sign_grouped_templated", None)
-            if fn is None or not self._admit(rung):
-                continue
-            try:
-                out = self._invoke(rung, "sign_grouped_templated",
-                                   (seeds, val_idx, tmpl_idx, templates))
-                self._on_success(rung)
-                return out
-            except DeviceFault as e:
-                self._on_fault(rung, e)
-        from tendermint_tpu.crypto import pure_ed25519 as _ref
-        tm = np.asarray(templates)
-        out = np.zeros((len(val_idx), 64), dtype=np.uint8)
-        for i, (vi, ti) in enumerate(zip(val_idx, tmpl_idx)):
-            sig = _ref.sign(bytes(seeds[int(vi)]), tm[int(ti)].tobytes())
-            out[i] = np.frombuffer(sig, np.uint8)
-        return out
 
     def precompile_for_validators(self, vals, stage: str = "all",
                                   stop=None) -> None:

@@ -114,42 +114,6 @@ def verify_grouped(tables: jnp.ndarray, pub_ok: jnp.ndarray,
 verify_grouped_jit = jax.jit(verify_grouped)
 
 
-def sign_grouped_templated(a_scalars: jnp.ndarray, prefixes: jnp.ndarray,
-                           pubkeys: jnp.ndarray, val_idx: jnp.ndarray,
-                           tmpl_idx: jnp.ndarray, templates: jnp.ndarray,
-                           base_tbl: jnp.ndarray | None = None
-                           ) -> jnp.ndarray:
-    """Batched RFC 8032 signing against a fixed key set: lane i signs
-    templates[tmpl_idx[i]] with key val_idx[i].  Returns sigs uint8[N, 64].
-
-    The signing mirror of `verify_grouped_templated` — where the
-    reference signs one vote at a time on the CPU
-    (`types/priv_validator.go` SignVote -> ed25519 scalar path), this
-    runs R = [r]B, k = H(R||A||M), S = (r + k*a) mod L for thousands of
-    lanes in one device call (two fixed-base combs + two SHA-512 grids).
-    Used for bulk fixture/testnet signing and benchable workloads;
-    bit-identical to `crypto.pure_ed25519.sign` (RFC 8032 is
-    deterministic, differential-tested in tests/test_ed25519.py).
-
-    a_scalars/prefixes are the per-key halves of SHA-512(seed) (a
-    clamped, prefix raw); both [V, 32] uint8, host-derived once per set.
-    """
-    msgs = jnp.take(templates, tmpl_idx, axis=0)            # [N, M]
-    prefix = jnp.take(prefixes, val_idx, axis=0)            # [N, 32]
-    A = jnp.take(pubkeys, val_idx, axis=0)                  # [N, 32]
-    a = jnp.take(a_scalars, val_idx, axis=0)                # [N, 32]
-    r = sc.reduce512(s512.sha512(jnp.concatenate([prefix, msgs], axis=-1)))
-    R_bytes, _ = curve.encode_batch(curve.scalar_mul_base(r, base_tbl))
-    k = sc.reduce512(s512.sha512(
-        jnp.concatenate([R_bytes, A, msgs], axis=-1)))
-    s = sc.muladd_mod_L(k, a, r)
-    return jnp.concatenate(
-        [R_bytes, s.astype(jnp.uint8)], axis=-1)
-
-
-sign_grouped_templated_jit = jax.jit(sign_grouped_templated)
-
-
 def verify_grouped_templated(tables: jnp.ndarray, pub_ok: jnp.ndarray,
                              val_pubs: jnp.ndarray, val_idx: jnp.ndarray,
                              tmpl_idx: jnp.ndarray,

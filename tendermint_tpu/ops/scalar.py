@@ -114,25 +114,6 @@ def lt_L(s: jnp.ndarray) -> jnp.ndarray:
     return lt_const(s, L_LIMBS[:32])
 
 
-def muladd_mod_L(k: jnp.ndarray, a: jnp.ndarray,
-                 r: jnp.ndarray) -> jnp.ndarray:
-    """(r + k*a) mod L for little-endian limb vectors [..., 32] — the
-    signing-side scalar op (RFC 8032 step 5: S = (r + k*s) mod L).
-
-    k < L (a reduce512 output) and a < 2^255 (a clamped secret scalar),
-    so the 63-limb schoolbook product plus r stays < 2^508: column sums
-    are < 32*255*255 + 255 < 2^21, inside `_carry`'s exact-int32 bound,
-    and the carried value fits 64 bytes — `reduce512` finishes the fold.
-    """
-    acc = jnp.zeros(k.shape[:-1] + (63,), dtype=jnp.int32)
-    ka = k.astype(jnp.int32)
-    for i in range(32):
-        acc = acc.at[..., i:i + 32].add(ka * a[..., i:i + 1].astype(jnp.int32))
-    acc = acc.at[..., :32].add(r.astype(jnp.int32))
-    acc = jnp.pad(acc, [(0, 0)] * (acc.ndim - 1) + [(0, 1)])
-    return reduce512(_carry(acc)[..., :64])
-
-
 def nibbles(s: jnp.ndarray) -> jnp.ndarray:
     """Limbs/bytes [..., 32] -> 64 little-endian 4-bit windows int32[..., 64]."""
     x = s.astype(jnp.int32)

@@ -135,23 +135,17 @@ def _stage(name: str, t0: float) -> float:
 
 def apply_window(state: State, event_cache, proxy_consensus, items,
                  mempool, tx_indexer=None, check_last_commit: bool = False,
-                 save_every: int = 1, before_block=None, on_applied=None,
-                 stop_when=None) -> int:
+                 before_block=None, on_applied=None, stop_when=None) -> int:
     """Apply a verified fast-sync WINDOW of blocks (`items` =
     [(block, part_set_header)]) — `apply_block` unrolled across the
-    window so the per-block overheads amortize:
-
-    - the consensus conn's lock is acquired ONCE for the whole window
-      (via `AppConn.batched`, when the conn offers it) instead of ~4
-      round-trips per block;
-    - with `save_every=0` state persistence collapses to one `save()` at
-      the window end — ONLY for ephemeral replays (the bench): a crash
-      mid-window leaves the store more than one block ahead of state,
-      which the handshake calls unrecoverable.  Durable nodes keep
-      `save_every=1`, the exact per-block discipline `apply_block` has.
+    window so the per-block overheads amortize: the consensus conn's
+    lock is acquired ONCE for the whole window (via `AppConn.batched`,
+    when the conn offers it) instead of ~4 round-trips per block.
 
     Per-block semantics are otherwise identical — same validation, same
-    fail points, same mempool locking around each app Commit — so crash
+    fail points, same mempool locking around each app Commit, one
+    `state.save()` a block (the store is never more than one block ahead
+    of the state, which is what the handshake can recover) — so crash
     tests and fault injection see the same sequence.  Hooks:
     `before_block(block, psh)` runs pre-validate (the reactor saves to
     the block store here, keeping store-before-state); `on_applied(block)`
@@ -195,9 +189,8 @@ def apply_window(state: State, event_cache, proxy_consensus, items,
             commit_state_update_mempool(state, app, block, mempool)
             fail_point("ApplyBlock.committed")
             t = _stage("fastsync.apply.abci_commit", t)
+            state.save()
             applied += 1
-            if save_every and applied % save_every == 0:
-                state.save()
             t = _stage("fastsync.apply.state_save", t)
             if on_applied is not None:
                 on_applied(block)
@@ -205,8 +198,6 @@ def apply_window(state: State, event_cache, proxy_consensus, items,
             t = _stage("fastsync.apply.advance", t)
             if stop:
                 break
-    if applied and not (save_every and applied % save_every == 0):
-        state.save()
     return applied
 
 

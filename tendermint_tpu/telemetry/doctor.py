@@ -1,6 +1,6 @@
 """Consensus doctor: name the largest thief per height range.
 
-Same contract as `bench.py --doctor` (utils/attribution.doctor_report)
+Same contract as `cli doctor` (utils/attribution.doctor_report)
 but over the LIVE timeline: each height's wall clock is partitioned by
 the four lifecycle stages (sums-to-wall by construction), and the
 doctor aggregates contiguous height ranges, maps stages onto named

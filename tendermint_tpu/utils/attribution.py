@@ -1,8 +1,8 @@
 """Pipeline attribution: where did the wall clock go?
 
 The flight recorder (utils/tracing.py) answers "what spans ran"; this
-module answers the scoreboard's question — for a replay window (or a
-whole bench run), how much wall clock was XLA compile, host<->device
+module answers the scoreboard's question — for a fast-sync window (or a
+whole run), how much wall clock was XLA compile, host<->device
 transfer, device-busy compute, scalar-fallback crypto, and how much was
 the device simply sitting IDLE.  Blockchain Machine (arXiv:2104.06968)
 treats per-stage rate instrumentation as a first-class contribution of a
@@ -227,24 +227,6 @@ def window_attribution(spans, key: str = "window") -> list[dict]:
         row["start"] = lo
         out.append(row)
     return out
-
-
-def overlap_summary(rows: list[dict]) -> dict:
-    """Collapse a `window_attribution` table into the three numbers a
-    replay result carries: window count, wall-weighted mean
-    overlap_fraction, and the worst window's overlap.  The weighting
-    matters — a pipeline that overlaps beautifully on short windows and
-    serializes on the long ones must not report a flattering mean."""
-    rows = [r for r in rows if (r.get("wall") or 0.0) > 0]
-    if not rows:
-        return {"windows": 0, "overlap_fraction": 0.0,
-                "min_window_overlap": 0.0}
-    wall = sum(r["wall"] for r in rows)
-    mean = sum(r["overlap_fraction"] * r["wall"] for r in rows) / wall
-    return {"windows": len(rows),
-            "overlap_fraction": round(mean, 4),
-            "min_window_overlap": round(
-                min(r["overlap_fraction"] for r in rows), 4)}
 
 
 def observe_window_metrics(attr: dict) -> None:

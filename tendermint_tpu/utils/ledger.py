@@ -2,8 +2,9 @@
 
 The scoreboard problem: round 4 hit 28.77x, round 5 timed out at ~12x,
 and nothing in the repo recorded the trajectory in between.  The ledger
-fixes that — `bench.py` appends an entry per run (per-config rates plus
-the doctor's attribution partition), `cli bench-history` renders the
+fixes that — a harness appends an entry per run (per-config rates; the
+scenario engine's soak and nightly budgets do, `scenarios/engine.py`),
+`cli bench-history` renders the
 trajectory, and `compute_deltas` compares each config against the BEST
 prior run so a slow creep over five runs is as visible as a cliff in
 one.
@@ -27,7 +28,7 @@ DEFAULT_PATH = "BENCH_LEDGER.jsonl"
 # the best prior run's rate for the same config
 DEFAULT_REGRESSION_THRESHOLD = 0.15
 
-# headline rate key per bench config (bench.py result dicts)
+# headline rate key per bench config (a run's result dicts)
 RATE_KEYS = {
     "config0": "blocks_per_sec",
     "config1": "sigs_per_sec",
@@ -129,7 +130,7 @@ def render_history(entries: list[dict]) -> str:
     """Trajectory table for `cli bench-history`: one block per run with
     each config's rate and its delta vs the best of all PRIOR runs."""
     if not entries:
-        return "ledger is empty (run bench.py to append an entry)"
+        return "ledger is empty (no run has appended an entry)"
     lines = []
     for i, e in enumerate(entries):
         when = e.get("timestamp") or e.get("git") or f"run {i + 1}"

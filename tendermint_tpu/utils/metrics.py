@@ -7,7 +7,7 @@ padded device batches run), and device step latency.
 
 Global registry, lock-per-instrument, exposed as one dict via
 `snapshot()` for the `status` / `dump_consensus_state` RPC routes and for
-bench harnesses.
+the benchmark (`benchmark/lib/cell.py`).
 """
 
 from __future__ import annotations

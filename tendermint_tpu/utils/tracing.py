@@ -5,9 +5,8 @@ did the DEVICE do" at kernel granularity, but only while an operator has
 a capture running.  The flight recorder is the complement: an
 always-on, bounded record of what the HOST planes did — consensus step
 transitions, device batch dispatch/collect, WAL writes, fast-sync pool
-events, bench fixture/replay phases — cheap enough to leave recording
-in production (one lock + one list store per span) and dumpable after
-the fact, like an aircraft FDR.
+events — cheap enough to leave recording in production (one lock + one
+list store per span) and dumpable after the fact, like an aircraft FDR.
 
 Spans are written with the context manager::
 
@@ -24,7 +23,7 @@ Perfetto / chrome://tracing / TensorBoard all load, so a flight-recorder
 dump and an XPlane capture can be eyeballed side by side.
 
 Served by the `debug_flight_recorder` RPC route (`rpc/routes.py`) and
-the `trace` CLI subcommand; the bench harness dumps one per run.
+the `trace` CLI subcommand.
 """
 
 from __future__ import annotations
@@ -82,13 +81,8 @@ _CAT_BY_PREFIX = (
     ("verify.dispatch", CAT_DISPATCH),
     ("verify.collect", CAT_DEVICE),
     ("fastsync.verify", CAT_DEVICE),
-    ("bench.verify", CAT_DEVICE),
     ("verify.batch", CAT_DEVICE),
     ("verify.grouped", CAT_DEVICE),
-    ("sign.batch", CAT_DEVICE),
-    ("bench.prep", CAT_PREP),
-    ("bench.dispatch", CAT_DISPATCH),
-    ("bench.apply", CAT_APPLY),
     ("fastsync.prepare", CAT_PREP),
     ("fastsync.lookahead", CAT_PREP),
     ("fastsync.apply", CAT_APPLY),
@@ -216,14 +210,6 @@ class FlightRecorder:
                 if rec[6] or not categorized:
                     recs.append(rec)
         return [_as_dict(rec) for rec in reversed(recs)]
-
-    def last(self, name: str) -> dict | None:
-        """Most recent span with `name` (bench's budget manager reads the
-        last fixture-build cost here), or None."""
-        for rec in reversed(self.snapshot()):
-            if rec["name"] == name:
-                return rec
-        return None
 
     @property
     def total(self) -> int:

@@ -1,7 +1,7 @@
 """`execution.apply_window` must be `apply_block` unrolled: same final
-app hash and state, same per-block hook order, and (with save_every=1)
-byte-identical persisted state — the license for the reactor and bench
-to amortize app-lock and state-save costs across a fast-sync window."""
+app hash and state, same per-block hook order, one state save a block
+and byte-identical persisted state — the license for the reactor to
+amortize the app lock across a fast-sync window."""
 
 import sqlite3
 import threading
@@ -35,35 +35,66 @@ def _fresh(gen):
     return db, state, conns
 
 
-def _apply_per_block(gen, chain):
-    db, state, conns = _fresh(gen)
-    for block, ps, _seen in chain:
-        execution.apply_block(state, None, conns.consensus, block,
-                              ps.header, execution.MockMempool(),
-                              check_last_commit=False)
-    return db, state
+@pytest.fixture(scope="module")
+def long_chain():
+    """One full reactor window (64 blocks) of the 4-validator chain."""
+    privs, vs = make_validators(4)
+    return make_genesis(CHAIN, privs), build_chain(
+        privs, vs, CHAIN, 64, app_hashes=kvstore_app_hashes(64))
 
 
-@pytest.mark.parametrize("save_every", [1, 0, 4])
-def test_apply_window_matches_per_block(fixture, save_every):
-    gen, chain = fixture
-    ref_db, ref_state = _apply_per_block(gen, chain)
-
-    db, state, conns = _fresh(gen)
-    applied = execution.apply_window(
-        state, None, conns.consensus,
-        [(b, ps.header) for b, ps, _ in chain],
-        execution.MockMempool(), save_every=save_every)
-    assert applied == N
-    assert state.last_block_height == N
-    assert state.app_hash == ref_state.app_hash
-    assert state.last_block_id.key() == ref_state.last_block_id.key()
-    if save_every == 1:
-        # per-block persistence discipline: identical stored bytes
-        assert db._d == ref_db._d
+def _run(gen, chain, new_db, windowed):
+    """Apply `chain` per block or as one window, the block store's hook
+    before each block as the reactor has it; returns (state, state db,
+    the order in which the hooks and the saves were seen)."""
+    db = new_db("w" if windowed else "r")
+    state = get_state(db, gen)
+    conns = ClientCreator("kvstore").new_app_conns()
+    order = []
+    save = state.save
+    state.save = lambda: (order.append(("save", state.last_block_height)),
+                          save())[1]
+    if windowed:
+        assert execution.apply_window(
+            state, None, conns.consensus,
+            [(b, ps.header) for b, ps, _ in chain], execution.MockMempool(),
+            before_block=lambda b, _psh: order.append(("before", b.height)),
+            on_applied=lambda b: order.append(("applied", b.height))
+        ) == len(chain)
     else:
-        # deferred saves still land the final state on disk
-        assert db._d[b"stateKey"] == ref_db._d[b"stateKey"]
+        for b, ps, _seen in chain:
+            order.append(("before", b.height))
+            execution.apply_block(state, None, conns.consensus, b,
+                                  ps.header, execution.MockMempool(),
+                                  check_last_commit=False)
+            order.append(("applied", b.height))
+    return state, db, order
+
+
+@pytest.mark.parametrize("db", ["memdb", "sqlite"])
+@pytest.mark.parametrize("blocks", [1, 2, 64])
+def test_apply_window_matches_per_block(long_chain, tmp_path, blocks, db):
+    """A window of any length is `apply_block` a block: same app hash,
+    same state, the same rows in the state db, and one save a block
+    between that block's two hooks."""
+    from tendermint_tpu.utils.db import SQLiteDB
+    gen, chain = long_chain
+    chain = chain[:blocks]
+
+    def new_db(name):
+        return (MemDB() if db == "memdb"
+                else SQLiteDB(str(tmp_path / f"{name}.db")))
+
+    ref_state, ref_db, ref_order = _run(gen, chain, new_db, windowed=False)
+    state, got_db, order = _run(gen, chain, new_db, windowed=True)
+    assert state.last_block_height == blocks
+    assert state.app_hash == ref_state.app_hash
+    assert state.encode() == ref_state.encode()
+    assert got_db.iterate_prefix(b"") == ref_db.iterate_prefix(b"")
+    assert len(got_db.iterate_prefix(b"")) > 2 * blocks
+    assert order == ref_order == [
+        (what, h) for h in range(1, blocks + 1)
+        for what in ("before", "save", "applied")]
 
 
 def test_apply_window_hooks_and_early_stop(fixture):
@@ -73,7 +104,7 @@ def test_apply_window_hooks_and_early_stop(fixture):
     n = execution.apply_window(
         state, None, conns.consensus,
         [(b, ps.header) for b, ps, _ in chain],
-        execution.MockMempool(), save_every=1,
+        execution.MockMempool(),
         before_block=lambda b, psh: before.append(b.height),
         on_applied=lambda b: applied_blocks.append(b.height),
         stop_when=lambda: len(applied_blocks) >= 3)
@@ -81,7 +112,7 @@ def test_apply_window_hooks_and_early_stop(fixture):
     assert before == [1, 2, 3]
     assert applied_blocks == [1, 2, 3]
     assert state.last_block_height == 3
-    # stopping early with save_every=1 leaves state saved at height 3
+    # stopping early leaves state saved at height 3
     from tendermint_tpu.state.state import State
     assert State.decode_bytes(db._d[b"stateKey"]).last_block_height == 3
 
@@ -92,8 +123,8 @@ def test_apply_window_empty():
     db, state, conns = _fresh(gen)
     before = dict(db._d)
     assert execution.apply_window(
-        state, None, conns.consensus, [], execution.MockMempool(),
-        save_every=0) == 0
+        state, None, conns.consensus, [],
+        execution.MockMempool()) == 0
     # no spurious save of the untouched state
     assert db._d == before
 
@@ -105,7 +136,7 @@ def test_apply_window_validation_failure_keeps_prefix(fixture):
     items[3] = (chain[4][0], chain[4][1].header)   # wrong height at slot 3
     with pytest.raises(ValueError, match="wrong height"):
         execution.apply_window(state, None, conns.consensus, items,
-                               execution.MockMempool(), save_every=1)
+                               execution.MockMempool())
     # blocks before the bad one are applied and saved
     assert state.last_block_height == 3
 
@@ -136,7 +167,7 @@ def _sqlite_apply(tmp_path, gen, chain, name, windowed, state_db=None,
         n = execution.apply_window(
             state, None, conns.consensus,
             [(b, ps.header) for b, ps, _ in chain[:3]],
-            execution.MockMempool(), save_every=1,
+            execution.MockMempool(),
             before_block=lambda b, _psh: store.save_block(
                 b, parts[b.height], seen[b.height]))
         assert n == 3
@@ -192,8 +223,8 @@ def test_apply_window_records_eight_contiguous_stages_a_block(fixture,
 
 
 def test_apply_window_makes_three_transactions_a_block(fixture, tmp_path):
-    """Block store, ABCI responses, state: with `save_every=1` on
-    sqlite stores a block is three `db.write`, in that order in time
+    """Block store, ABCI responses, state: on sqlite stores a block
+    is three `db.write`, in that order in time
     (the responses are durable before the app commits, so they are not
     in the state's transaction)."""
     from tendermint_tpu.utils import tracing
@@ -287,7 +318,7 @@ def test_memdb_records_no_db_write(fixture):
     db, state, conns = _fresh(gen)
     execution.apply_window(state, None, conns.consensus,
                            [(b, ps.header) for b, ps, _ in chain],
-                           execution.MockMempool(), save_every=1)
+                           execution.MockMempool())
     names = [s["name"] for s in tracing.RECORDER.since(t_start)
              if s["ts"] >= t_start]
     assert "db.write" not in names

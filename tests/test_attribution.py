@@ -111,10 +111,10 @@ def test_spans_by_category_explicit_and_derived():
 
 def test_find_windows_sorted_and_extended():
     spans = [
-        _span("bench.prep", 10, 1, window=2),
-        _span("bench.apply", 12, 2, window=2),
-        _span("bench.prep", 0, 1, window=1),
-        _span("bench.apply", 3, 1, window=1),
+        _span("fastsync.prepare", 10, 1, window=2),
+        _span("fastsync.apply", 12, 2, window=2),
+        _span("fastsync.prepare", 0, 1, window=1),
+        _span("fastsync.apply", 3, 1, window=1),
         _span("xla.compile", 5, 1),                  # no key: no window
     ]
     wins = at.find_windows(spans)
@@ -127,8 +127,8 @@ def test_window_attribution_cross_thread_spans():
     """Category intervals come from ALL spans: a compile span on another
     thread (no window arg) still attributes to the window it overlaps."""
     spans = [
-        _span("bench.prep", 0, 1, tid=1, window=0),
-        _span("bench.apply", 8, 2, tid=1, window=0),
+        _span("fastsync.prepare", 0, 1, tid=1, window=0),
+        _span("fastsync.apply", 8, 2, tid=1, window=0),
         _span("xla.compile", 2, 3, tid=2),           # worker thread
         _span("verify.batch", 5, 3, tid=2),
     ]
@@ -146,11 +146,11 @@ def test_nested_spans_do_not_double_count():
     """A device span nested inside a scalar span (or overlapping same-
     category spans) must not attribute the same instant twice."""
     spans = [
-        _span("bench.prep", 0, 1, window=0),
+        _span("fastsync.prepare", 0, 1, window=0),
         _span("scalar.verify", 1, 8, window=0),
         _span("verify.batch", 3, 2),                 # nested inside scalar
         _span("scalar.verify", 2, 4),                # overlaps first scalar
-        _span("bench.apply", 9, 1, window=0),
+        _span("fastsync.apply", 9, 1, window=0),
     ]
     (row,) = at.window_attribution(spans)
     assert row["device_busy"] == 2                   # 3..5 wins over scalar
@@ -164,9 +164,9 @@ def test_nested_spans_do_not_double_count():
 
 def test_doctor_report_schema_and_thief():
     spans = [
-        _span("bench.prep", 0, 1, window=0),
+        _span("fastsync.prepare", 0, 1, window=0),
         _span("scalar.verify", 1, 7),
-        _span("bench.apply", 8, 2, window=0),
+        _span("fastsync.apply", 8, 2, window=0),
     ]
     rep = at.doctor_report(spans)
     assert rep["schema"] == at.DOCTOR_SCHEMA
@@ -205,9 +205,9 @@ def test_doctor_report_empty_and_regressions_folded():
 
 def test_render_report_names_largest_thief():
     spans = [
-        _span("bench.prep", 0, 1, window=0),
+        _span("fastsync.prepare", 0, 1, window=0),
         _span("scalar.verify", 1, 8),
-        _span("bench.apply", 9, 1, window=0),
+        _span("fastsync.apply", 9, 1, window=0),
     ]
     text = at.render_report(at.doctor_report(spans))
     assert text.startswith("largest thief: scalar_tail")
@@ -232,9 +232,9 @@ def _plane_metrics(occ_mean, flushes=10, mixed=4):
 
 def test_doctor_half_full_batches_named_thief():
     spans = [
-        _span("bench.prep", 0, 0.5, window=0),
+        _span("fastsync.prepare", 0, 0.5, window=0),
         _span("verify.batch", 0.5, 9, window=0),
-        _span("bench.apply", 9.5, 0.5, window=0),
+        _span("fastsync.apply", 9.5, 0.5, window=0),
     ]
     rep = at.doctor_report(spans, metrics=_plane_metrics(0.25))
     plane = rep["batchplane"]

@@ -1,5 +1,5 @@
-"""chip_smoke.py, bench.py and the compile cache as the chip tool and the
-driver use them — exercised here on the CPU.
+"""chip_smoke.py and the compile cache as the chip tool and the driver
+use them — exercised here on the CPU.
 
 The smoke itself only passes on the chip (`python chip_smoke.py` through
 the chip tool); tier-1 runs the same function at toy size with the
@@ -16,7 +16,6 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-import bench  # noqa: E402
 import chip_smoke  # noqa: E402
 
 from tendermint_tpu import batchplane  # noqa: E402
@@ -136,37 +135,3 @@ def test_compile_cache_goes_where_the_environment_says(tmp_path):
     assert got["jax_dir"] == got["ours"] == checkout
     assert got["table"].startswith(os.path.join(checkout, "tables") + os.sep)
     assert any(n.startswith("jit__lambda") for n in os.listdir(checkout))
-
-
-def test_bench_exits_nonzero_when_a_config_raises(tmp_path):
-    code = (
-        "import sys, bench\n"
-        "def boom(quick): raise RuntimeError('config 1 blew up')\n"
-        "bench.config1_batch_verify = boom\n"
-        "bench.native_scalar_rate = lambda n=0: 1000.0\n"
-        f"sys.argv = ['bench.py', '--config', '1', '--quick', '--ledger', '',"
-        f" '--partial-out', {str(tmp_path / 'p.json')!r},"
-        f" '--trace-out', {str(tmp_path / 't.json')!r}]\n"
-        "bench.main()\n")
-    r = subprocess.run([sys.executable, "-c", code], cwd=REPO,
-                       env=_cpu_env(), capture_output=True, text=True,
-                       timeout=120)
-    assert r.returncode == 1, r.stderr
-    assert json.loads(r.stdout.strip().splitlines()[-1])["metric"] == \
-        "bench_failed"
-    with open(tmp_path / "p.json") as f:
-        assert "config 1 blew up" in json.load(f)["results"]["config1"]["error"]
-
-
-def test_bench_config1_runs_the_size_it_names_or_fails(monkeypatch):
-    """No quiet retry at a smaller batch: one attempt, at the named size."""
-    asked = []
-
-    def fixture(n_vals, n_sigs, h0=1):
-        asked.append(n_sigs)
-        raise MemoryError("does not fit")
-    monkeypatch.setattr(bench, "_sign_batch_fixture", fixture)
-    monkeypatch.setattr(cb, "set_backend", lambda name: object())
-    with pytest.raises(MemoryError):
-        bench.config1_batch_verify(quick=True)
-    assert asked == [4096]

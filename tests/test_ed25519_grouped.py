@@ -163,6 +163,88 @@ def test_backend_templated_matches_plain():
     assert not got[4] and got[5]
 
 
+def _zero_tables(pubs):
+    """`build_neg_comb_jit`'s stand-in: tables of zeros of the real
+    shape (a real build RUNS half a minute a set on the CPU backend)."""
+    from tendermint_tpu.ops.curve import COMB_DIGITS, COMB_WINDOWS
+    return (jnp.zeros((COMB_WINDOWS, COMB_DIGITS, len(pubs), 3, 32),
+                      jnp.uint8), jnp.ones((len(pubs),), bool))
+
+
+@pytest.fixture()
+def host_kernels(monkeypatch):
+    """The backend's two device programs replaced where it looks them up
+    (`self._dev.<name>_jit`, at call time): a table of zeros of the real
+    shape, and a templated verify that checks every lane it is handed on
+    the host and notes the shapes it was handed.  Nothing compiles, so a
+    lane count may cross a bucket edge for free; the kernel itself is
+    `test_backend_templated_matches_plain`'s business."""
+    from tendermint_tpu.crypto import native
+    check = native.verify_one if native.AVAILABLE else ref.verify
+    handed = []
+
+    def verify(tbl, pub_ok, val_pubs, val_idx, tmpl_idx, templates, sigs,
+               base_tbl):
+        vp, tm = np.asarray(val_pubs), np.asarray(templates)
+        vi, ti, sg = (np.asarray(x) for x in (val_idx, tmpl_idx, sigs))
+        handed.append((len(vi), len(ti), len(sg), len(tm)))
+        return jnp.asarray([check(vp[v].tobytes(), tm[t].tobytes(),
+                                  s.tobytes())
+                            for v, t, s in zip(vi, ti, sg)])
+
+    monkeypatch.setattr(dev, "build_neg_comb_jit", _zero_tables)
+    monkeypatch.setattr(dev, "verify_grouped_templated_jit", verify)
+    return handed
+
+
+@pytest.mark.parametrize("t", [1, 3])
+@pytest.mark.parametrize("n", [15, 16, 17, 31, 32, 33])
+def test_the_templated_call_pads_trims_and_counts(host_kernels, n, t):
+    """What `backend.verify_call_ms` and the benchmark's counters read
+    (`benchmark/layers/backend.verify_call_ms.json`,
+    `benchmark/lib/cell.py`): a call of n lanes is one `verify.dispatch`
+    and one `verify.collect` with `lanes` n and `bucket` the padded
+    size, the device sees whole buckets, the caller gets n verdicts in
+    its own order, and the counters move by the real lanes only."""
+    import threading
+    from tendermint_tpu.crypto import backend as cb
+    from tendermint_tpu.crypto import native
+    from tendermint_tpu.utils import tracing
+    from tendermint_tpu.utils.metrics import REGISTRY
+    sign = native.sign_one if native.AVAILABLE else ref.sign
+    seeds = [bytes([n, t, i + 1]) + b"\x00" * 29 for i in range(V)]
+    vp = np.frombuffer(b"".join(ref.pubkey_from_seed(s) for s in seeds),
+                       np.uint8).reshape(V, 32)
+    templates = np.frombuffer(secrets.token_bytes(t * MSG_LEN),
+                              np.uint8).reshape(t, MSG_LEN)
+    idx = (np.arange(n) % V).astype(np.int32)
+    tmpl_idx = (np.arange(n) % t).astype(np.int32)
+    sigs = [sign(seeds[idx[i]], templates[tmpl_idx[i]].tobytes())
+            for i in range(n)]
+    # the last real lane is forged; every padding lane copies lane 0,
+    # which is good: a verdict or a count taken from the padding shows
+    sigs[-1] = sigs[-1][:63] + bytes([sigs[-1][63] ^ 0x01])
+    sa = np.frombuffer(b"".join(sigs), np.uint8).reshape(n, 64)
+
+    be = cb.TpuBackend()
+    counters = (REGISTRY.sigs_requested, REGISTRY.sigs_verified,
+                REGISTRY.verify_batches)
+    before = [c.value for c in counters]
+    t_start = tracing.now_epoch()
+    out = be.verify_grouped_templated(b"pad-set", vp, idx, tmpl_idx,
+                                      templates, sa)
+    spans = [(s["name"], s["args"]) for s in tracing.RECORDER.since(t_start)
+             if s["ts"] >= t_start and s["name"].startswith("verify.") and
+             s["tid"] == threading.current_thread().ident]
+
+    b = cb._bucket(n)
+    assert out.dtype == bool and out.tolist() == [True] * (n - 1) + [False]
+    assert host_kernels == [(b, b, b, cb._bucket(t))]
+    assert spans == [("verify.dispatch", {"lanes": n, "bucket": b}),
+                     ("verify.collect", {"lanes": n, "bucket": b})]
+    assert [c.value - v for c, v in zip(counters, before)] == [n, n - 1, 1]
+
+
 def test_table_cache_byte_bounded_keeps_small_sets(monkeypatch):
     """Regression for the multi-chain churn: one big validator set plus
     many small light-chain sets must ALL stay resident (the old count
@@ -174,18 +256,9 @@ def test_table_cache_byte_bounded_keeps_small_sets(monkeypatch):
     real builds cost 4.5 minutes on the CPU backend — a third of the
     whole tier-1 budget — and the build itself is covered by the tests
     around this one."""
-    import jax.numpy as jnp
-    import numpy as np
-    from tendermint_tpu.crypto import pure_ed25519 as ref
     from tendermint_tpu.crypto.backend import TpuBackend
-    from tendermint_tpu.ops import ed25519 as dev
-    from tendermint_tpu.ops.curve import COMB_DIGITS, COMB_WINDOWS
 
-    monkeypatch.setattr(
-        dev, "build_neg_comb_jit",
-        lambda pubs: (jnp.zeros((COMB_WINDOWS, COMB_DIGITS, len(pubs), 3,
-                                 32), jnp.uint8),
-                      jnp.ones((len(pubs),), bool)))
+    monkeypatch.setattr(dev, "build_neg_comb_jit", _zero_tables)
     be = TpuBackend()
     sigs = np.zeros((4, 64), np.uint8)
     msgs = np.zeros((4, 128), np.uint8)

@@ -163,6 +163,46 @@ def test_pool_caught_up():
     assert not pool.is_caught_up()
 
 
+# -- the hand-over ----------------------------------------------------------
+
+def _reactor_at_the_tip(step):
+    """A reactor whose pool says it has caught up and whose sync step is
+    `step(bc)`: the routine runs in the test's own thread, one pass."""
+    privs, _vs = make_validators(4)
+    state = get_state(MemDB(), make_genesis(CHAIN, privs))
+    conns = ClientCreator("kvstore").new_app_conns()
+    bc = BlockchainReactor(state, conns.consensus, BlockStore(MemDB()),
+                           fast_sync=True)
+
+    class TipPool:
+        next_height = 1
+
+        def is_caught_up(self):
+            return True
+
+    bc.pool = TipPool()
+    bc._sync_step = lambda: step(bc)
+    handed = []
+    bc.on_caught_up = handed.append
+    bc._pool_routine()
+    return bc, handed
+
+
+def test_a_reactor_stopped_in_its_last_window_does_not_hand_over():
+    """`stop()` lands while the last window is applied: the routine ends
+    without switching to consensus, so no live warm-up starts either."""
+    def last_window(bc):
+        bc.stop()
+        return True
+    bc, handed = _reactor_at_the_tip(last_window)
+    assert handed == [] and not bc._switched
+
+
+def test_a_reactor_still_hands_over_when_not_stopped():
+    bc, handed = _reactor_at_the_tip(lambda bc: True)
+    assert handed == [bc.state] and bc._switched
+
+
 # -- e2e --------------------------------------------------------------------
 
 N_BLOCKS = 24

@@ -67,6 +67,12 @@ class FlakyBackend:
         return self.verify_batch(np.asarray(val_pubs)[np.asarray(val_idx)],
                                  msgs, sigs)
 
+    def verify_grouped_templated(self, set_key, val_pubs, val_idx,
+                                 tmpl_idx, templates, sigs):
+        return self.verify_grouped(
+            set_key, val_pubs, val_idx,
+            np.asarray(templates)[np.asarray(tmpl_idx)], sigs)
+
 
 def make_sup(device, **knobs):
     knobs.setdefault("breaker_cooldown_s", 0.05)
@@ -247,6 +253,50 @@ def test_chaos_wrong_mode_caught_by_spot_check(sigs):
     assert (out == want).all()
     assert REGISTRY.crypto_spot_check_mismatches.value > mism0
     assert sup._rungs[0].state == OPEN
+
+
+def _entry_args(entry, pubs, msgs, sg):
+    """The same lanes through each of the three verify entries: lane i
+    is validator i's and, templated, signs template i."""
+    lanes = np.arange(len(pubs), dtype=np.int32)
+    return {"verify_batch": (pubs, msgs, sg),
+            "verify_grouped": (b"set", pubs, lanes, msgs, sg),
+            "verify_grouped_templated": (b"set", pubs, lanes, lanes, msgs,
+                                         sg)}[entry]
+
+
+# chaos mode -> (the ladder's knobs that catch it, the chaos spec)
+DEVICE_FAULTS = {"raise": ({}, "raise:every=1"),
+                 "latency": ({"call_timeout_s": 0.05}, "latency:ms=300"),
+                 "wrong": ({"spot_check_every": 1}, "wrong:lanes=8")}
+
+
+@pytest.mark.parametrize("mode", list(DEVICE_FAULTS))
+@pytest.mark.parametrize("entry", ["verify_batch", "verify_grouped",
+                                   "verify_grouped_templated"])
+def test_every_verify_entry_survives_a_device_fault(sigs, entry, mode):
+    """A device that raises, hangs past the call timeout or answers
+    wrongly costs the caller of ANY entry a slow call: the floor's
+    answer comes back, the device rung's fault is counted, nothing is
+    raised.  The templated entry is the one a catching-up node calls."""
+    pubs, msgs, sg, want = sigs
+    knobs, spec = DEVICE_FAULTS[mode]
+    dev = FlakyBackend()
+    sup = make_sup(dev, breaker_threshold=100, **knobs)
+    sup.chaos = CryptoChaos.parse(spec)
+    faults0 = REGISTRY.crypto_device_faults.value
+    fell0 = REGISTRY.crypto_fallback_calls.value
+    out = getattr(sup, entry)(*_entry_args(entry, pubs, msgs, sg))
+    assert (out == want).all()
+    device, floor = sup._rungs
+    assert (device.faults, device.calls, floor.calls) == (1, 1, 1)
+    assert REGISTRY.crypto_device_faults.value - faults0 == 1
+    assert REGISTRY.crypto_fallback_calls.value - fell0 == 1
+    # a raise is injected before the device is reached and a wrong
+    # answer after it (only the spot check catches that one); a hung
+    # call is still on its way when the floor has answered
+    if mode != "latency":
+        assert dev.calls == (0 if mode == "raise" else 1)
 
 
 # -- the blame invariant ----------------------------------------------------

@@ -18,11 +18,7 @@ HOT_DIRS = ("ops/", "crypto/", "parallel/")
 
 
 def repo_paths():
-    paths = [os.path.join(REPO, "tendermint_tpu")]
-    bench = os.path.join(REPO, "bench.py")
-    if os.path.exists(bench):
-        paths.append(bench)
-    return paths
+    return [os.path.join(REPO, "tendermint_tpu")]
 
 
 @pytest.mark.lint

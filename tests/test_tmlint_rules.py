@@ -350,61 +350,6 @@ def test_metric_name_series_collision_and_bad_label(tmp_path):
     assert any("reserved" in m for m in msgs), findings
 
 
-def test_bench_scalar_loop_flags_loop_in_prep_span(tmp_path):
-    findings = lint_src(tmp_path, """
-        from tendermint_tpu.utils import tracing
-
-        def prep(blocks):
-            with tracing.span("bench.prep", blocks=len(blocks)):
-                lanes = []
-                for b in blocks:
-                    lanes.append(b.lanes())
-            return lanes
-
-        def apply(items):
-            with tracing.span("bench.apply", blocks=len(items)):
-                while items:
-                    items.pop()
-        """)
-    loops = [f for f in findings if f.rule == "bench-scalar-loop"]
-    assert len(loops) == 2, findings
-    assert "bench.prep" in loops[0].message
-    assert "bench.apply" in loops[1].message
-
-
-def test_bench_scalar_loop_quiet_on_vectorized_and_other_spans(tmp_path):
-    findings = lint_src(tmp_path, """
-        from tendermint_tpu.utils import tracing
-
-        def prep(blocks, window_commit_lanes, pool):
-            with tracing.span("bench.prep", blocks=len(blocks)):
-                parts = list(pool.map(hash, blocks))          # executor
-                items = [(b, p) for b, p in zip(blocks, parts)]
-                lanes = window_commit_lanes(items)            # one pass
-
-        def dispatch(items):
-            # dispatch/verify spans are not host-stage categories
-            with tracing.span("bench.dispatch", blocks=len(items)):
-                for it in items:
-                    it.upload()
-
-        def fastsync_apply(items, apply_window):
-            # the reactor's span: same category, different prefix — the
-            # rule is scoped to the bench's spans
-            with tracing.span("fastsync.apply", blocks=len(items)):
-                for it in items:
-                    it.go()
-
-        def helper_defined_inside(items):
-            with tracing.span("bench.apply", blocks=len(items)):
-                def later():
-                    for it in items:    # runs elsewhere, not in-span
-                        it.go()
-                return later
-        """)
-    assert [f for f in findings if f.rule == "bench-scalar-loop"] == []
-
-
 def test_scenario_budget_flags_stress_without_budgets(tmp_path):
     findings = lint_src(tmp_path, """
         from tendermint_tpu.scenarios.engine import register
@@ -588,7 +533,7 @@ def test_batchplane_quiet_on_plane_submission_twin(tmp_path):
     assert not [f for f in findings if f.rule == "batchplane-producer"]
 
 
-def test_batchplane_allows_scheduler_and_bench_direct_calls(tmp_path):
+def test_batchplane_allows_scheduler_and_ladder_direct_calls(tmp_path):
     # the scheduler itself and non-producer layers stay direct by design
     src = """
         from tendermint_tpu.crypto import backend as cb
@@ -596,8 +541,7 @@ def test_batchplane_allows_scheduler_and_bench_direct_calls(tmp_path):
         def _run_grouped(set_key, val_pubs, idx, msgs, sigs):
             return cb.verify_grouped(set_key, val_pubs, idx, msgs, sigs)
         """
-    for rel in ("batchplane/scheduler.py", "crypto/supervised.py",
-                "bench.py"):
+    for rel in ("batchplane/scheduler.py", "crypto/supervised.py"):
         findings = lint_src(tmp_path, src, relpath=rel)
         assert not [f for f in findings
                     if f.rule == "batchplane-producer"], rel
@@ -608,6 +552,5 @@ def test_rule_catalog_covers_all_families():
     names = {n for n, _ in all_rules()}
     assert {"lock-order", "unlocked-write", "jax-host-sync",
             "jax-retrace", "jax-static-argnums", "route-gating",
-            "route-write-containment", "span-category",
-            "bench-scalar-loop", "metric-name",
+            "route-write-containment", "span-category", "metric-name",
             "scenario-budget", "batchplane-producer"} <= names

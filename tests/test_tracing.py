@@ -97,16 +97,16 @@ def test_since_on_an_empty_and_a_cleared_ring():
     assert rec.since(0.0) == []
 
 
-def test_instant_and_last():
+def test_instants_and_spans_share_the_ring_in_order():
     rec = FlightRecorder(capacity=8)
     rec.instant("tick", n=1)
     with rec.span("fixture"):
         pass
     rec.instant("tick", n=2)
-    assert rec.last("fixture")["name"] == "fixture"
-    assert rec.last("tick")["args"] == {"n": 2}
-    assert rec.last("missing") is None
-    assert rec.snapshot()[0]["ph"] == PH_INSTANT
+    snap = rec.snapshot()
+    assert [(s["name"], s.get("args")) for s in snap] == [
+        ("tick", {"n": 1}), ("fixture", None), ("tick", {"n": 2})]
+    assert [s["ph"] == PH_INSTANT for s in snap] == [True, False, True]
 
 
 def test_clear_resets_ring():
@@ -188,8 +188,8 @@ def test_category_inference_longest_prefix():
     assert tracing.default_category("verify.batch") == tracing.CAT_DEVICE
     assert tracing.default_category("verify.dispatch") == \
         tracing.CAT_DISPATCH
-    assert tracing.default_category("bench.prep") == tracing.CAT_PREP
-    assert tracing.default_category("bench.apply") == tracing.CAT_APPLY
+    assert tracing.default_category("fastsync.prepare") == tracing.CAT_PREP
+    assert tracing.default_category("fastsync.apply") == tracing.CAT_APPLY
     # window-boundary and unknown names stay uncategorized
     assert tracing.default_category("fastsync.window") is None
     assert tracing.default_category("wal.write") is None
