@@ -464,7 +464,7 @@ class BlockchainReactor(Reactor):
         except Exception as e:
             log.debug("window metrics not observed", error=repr(e)[:200])
         log.debug("synced window", blocks=applied,
-                  sigs=sum(len(i[2].precommits) for i in items),
+                  sigs=sum(i[2].size() for i in items),
                   verify_seconds=round(dt, 4),
                   height=self.state.last_block_height)
         return True

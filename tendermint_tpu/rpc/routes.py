@@ -52,8 +52,7 @@ def _block_dict(block) -> dict:
         "txs": [_hexb(tx) for tx in block.txs],
         "last_commit": {
             "block_id": {"hash": _hexb(block.last_commit.block_id.hash)},
-            "precommits": sum(v is not None
-                              for v in block.last_commit.precommits),
+            "precommits": block.last_commit.num_sigs(),
         },
     }
 
@@ -153,7 +152,7 @@ class Routes:
         return {
             "canonical": height != store.height,
             "block_id": {"hash": _hexb(commit.block_id.hash)},
-            "precommits": sum(v is not None for v in commit.precommits),
+            "precommits": commit.num_sigs(),
             "height": height,
         }
 

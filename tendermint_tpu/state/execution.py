@@ -68,7 +68,7 @@ def validate_block(state: State, block, check_last_commit: bool = True) -> None:
     if h.validators_hash != state.validators.hash():
         raise ValueError("wrong validators_hash")
     if h.height > 1:
-        if len(block.last_commit.precommits) != state.last_validators.size():
+        if block.last_commit.size() != state.last_validators.size():
             raise ValueError("last_commit size != last validator set")
         if check_last_commit:
             # THE hot verification: +2/3 of last_validators signed last

@@ -4,7 +4,7 @@ The layer every other layer compiles against (reference `types/`,
 SURVEY.md §2.2).
 """
 
-from tendermint_tpu.types.block import (Block, BlockID, Commit, CompactCommit, EMPTY_COMMIT,
+from tendermint_tpu.types.block import (Block, BlockID, Commit, EMPTY_COMMIT,
                                         Header, ZERO_BLOCK_ID)
 from tendermint_tpu.types.canonical import (SIGN_BYTES_LEN, TYPE_HEARTBEAT,
                                             TYPE_PRECOMMIT, TYPE_PREVOTE,
@@ -21,7 +21,7 @@ from tendermint_tpu.types.vote import (DuplicateVoteEvidence, ErrVoteConflict,
                                        Vote, VoteSet)
 
 __all__ = [
-    "Block", "BlockID", "Commit", "CompactCommit", "EMPTY_COMMIT", "Header",
+    "Block", "BlockID", "Commit", "EMPTY_COMMIT", "Header",
     "ZERO_BLOCK_ID",
     "SIGN_BYTES_LEN", "TYPE_HEARTBEAT", "TYPE_PRECOMMIT", "TYPE_PREVOTE",
     "TYPE_PROPOSAL", "GenesisDoc", "GenesisValidator", "PrivKey", "PubKey",

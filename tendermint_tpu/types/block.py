@@ -8,8 +8,10 @@ Reference: `types/block.go` — Block = Header + Data(Txs) + LastCommit
 
 from __future__ import annotations
 
+import struct
 import time
 from dataclasses import dataclass, field
+from functools import lru_cache
 
 from tendermint_tpu.types import merkle
 from tendermint_tpu.types.codec import (Reader, i64, lp_bytes, u32, u64, u8)
@@ -17,6 +19,7 @@ from tendermint_tpu.types.part_set import PartSet, PartSetHeader, ZERO_PSH
 from tendermint_tpu.types.tx import txs_hash
 from tendermint_tpu.types.vote import Vote
 from tendermint_tpu.utils import tracing
+from tendermint_tpu.utils.metrics import REGISTRY
 
 MAX_BLOCK_SIZE_TXS = 10_000   # reference config/config.go:373
 
@@ -91,52 +94,183 @@ class Header:
                    validators_hash=r.lp_bytes(), app_hash=r.lp_bytes())
 
 
-@dataclass
+# A present precommit's record on the wire without its block id: marker
+# (1), address (4 + 20), index (4), height (8), round (4), type (1) and
+# signature (4 + 64).  The block id sits at _OFF_BID, the signature last.
+_REC_FIXED = 110
+_OFF_ADDR, _OFF_INDEX, _OFF_HRT, _OFF_BID = 5, 25, 29, 42
+_REC_HEAD = u8(1) + u32(20)
+_SIG_PREFIX = u32(64)
+
+
+@lru_cache(maxsize=8)
+def _pinned(n: int, bid_len: int) -> tuple[int, int]:
+    """A regular body of n records read as ONE big-endian integer: the
+    mask of its pinned bytes (all but the address and signature columns)
+    and what its `validator_index` columns read, every other byte zero."""
+    width = _REC_FIXED + bid_len
+    mask = bytearray(b"\xff" * width)
+    mask[_OFF_ADDR:_OFF_INDEX] = bytes(20)
+    mask[-64:] = bytes(64)
+    index = b"".join(bytes(_OFF_INDEX) + u32(i) + bytes(width - _OFF_HRT)
+                     for i in range(n))
+    return (int.from_bytes(bytes(mask) * n, "big"),
+            int.from_bytes(index, "big"))
+
+
+@lru_cache(maxsize=8)
+def _columns(n: int, bid_len: int) -> struct.Struct:
+    """Unpacks a regular commit's wire bytes into its 2n columns, an
+    address then a signature a record, in one C call."""
+    return struct.Struct(f"{bid_len + 4}x" + (
+        f"{_OFF_ADDR}x20s{_REC_FIXED + bid_len - _OFF_INDEX - 64}x64s" * n))
+
+
+def _irregular(wire: bytes, n: int, bid_len: int) -> str | None:
+    """None when the body of a commit (`wire` after its block id and
+    count, n records) is REGULAR: every vote present, every record of
+    the one width, every vote's (height, round, type, block id) the
+    first's and its block id the commit's, every index its position.
+    Such a body parses to the same votes under the sequential decoder
+    (each length prefix is pinned, so each record ends where the next
+    row starts).  Otherwise the reason, in a word, and the caller
+    decodes vote by vote.
+
+    The records are compared as one big integer, an AND and an `==`: a
+    numpy compare of 18,600 bytes drops the GIL, and a receive thread
+    that dropped it beside the apply thread waits up to a switch
+    interval to get it back (PERF.md, PR 34)."""
+    width = _REC_FIXED + bid_len
+    body = bid_len + 4
+    if len(wire) - body != n * width:
+        return "length"
+    sig_at = _OFF_BID + bid_len
+    first = bytearray(wire[body:body + width])
+    if (first[:_OFF_ADDR] != _REC_HEAD
+            or first[_OFF_BID:sig_at] != wire[:bid_len]
+            or first[sig_at:sig_at + 4] != _SIG_PREFIX):
+        return "record"
+    first[_OFF_ADDR:_OFF_HRT] = bytes(24)       # address and index
+    first[-64:] = bytes(64)
+    mask, index = _pinned(n, bid_len)
+    if (int.from_bytes(memoryview(wire)[body:], "big") & mask
+            != int.from_bytes(bytes(first) * n, "big") | index):
+        return "votes"
+    return None
+
+
 class Commit:
     """+2/3 precommits for one block (reference `types/block.go:288-354`).
 
     `precommits` is validator-index-aligned with the validator set that
     signed it; absent votes are None.
-    """
-    block_id: BlockID
-    precommits: list[Vote | None]
 
-    _hash: bytes | None = field(default=None, repr=False, compare=False)
-    _bit_array: list[bool] | None = field(default=None, repr=False,
-                                          compare=False)
+    One type, two backings.  A commit built from votes holds the list.
+    A commit decoded from the wire whose body is regular (the common
+    case: see `_irregular`) stays in the bytes it was read from:
+    `encode()` is those bytes, the signature columns joined are the
+    verify plane's `sigs[V, 64]`, and the `Vote` objects are made when
+    somebody asks for `precommits`.  Any other body decodes vote by vote
+    as before.  Like `Block`, a commit is a value object: nobody edits
+    one that was decoded.
+    """
+
+    def __init__(self, block_id: BlockID,
+                 precommits: list[Vote | None] | None = None):
+        self.block_id = block_id
+        self._votes = precommits
+        # wire-backed: the bytes, the unpacker of their address and
+        # signature columns, the record count, (height, round, type)
+        self._wire: bytes | None = None
+        self._cols: struct.Struct | None = None
+        self._n = 0
+        self._hrt = (0, 0, 0)
+        self._hash: bytes | None = None
+        self._bit_array: list[bool] | None = None
+
+    @property
+    def precommits(self) -> list[Vote | None]:
+        votes = self._votes
+        if votes is None:
+            cols = self._cols.unpack(self._wire)
+            height, round_, type_ = self._hrt
+            bid = self.block_id
+            votes = self._votes = [
+                Vote(validator_address=addr, validator_index=i,
+                     height=height, round=round_, type=type_,
+                     block_id=bid, signature=sig)
+                for i, (addr, sig) in enumerate(zip(cols[0::2],
+                                                    cols[1::2]))]
+        return votes
+
+    def wire_columns(self) -> tuple | None:
+        """(the n addresses joined, the n signatures joined, height,
+        round, type) of a wire-backed commit; None for a commit that
+        holds votes.  Bytes and no arrays: a numpy copy of more than 500
+        elements drops the GIL, and the look-ahead that dropped it beside
+        the apply thread waits to get it back (PERF.md, PR 34)."""
+        if self._wire is None:
+            return None
+        cols = self._cols.unpack(self._wire)
+        return (b"".join(cols[0::2]), b"".join(cols[1::2])) + self._hrt
+
+    def __eq__(self, other):
+        if not isinstance(other, Commit):
+            return NotImplemented
+        return (self.block_id == other.block_id
+                and self.precommits == other.precommits)
+
+    __hash__ = None
+
+    def __repr__(self):
+        return (f"Commit(block_id={self.block_id!r}, "
+                f"precommits={self.precommits!r})")
 
     def height(self) -> int:
-        for v in self.precommits:
+        if self._wire is not None:
+            return self._hrt[0]
+        for v in self._votes:
             if v is not None:
                 return v.height
         return 0
 
     def round(self) -> int:
-        for v in self.precommits:
+        if self._wire is not None:
+            return self._hrt[1]
+        for v in self._votes:
             if v is not None:
                 return v.round
         return 0
 
     def size(self) -> int:
-        return len(self.precommits)
+        if self._wire is not None:
+            return self._n
+        return len(self._votes)
 
     def num_sigs(self) -> int:
-        return sum(1 for v in self.precommits if v is not None)
+        if self._wire is not None:
+            return self._n
+        return sum(1 for v in self._votes if v is not None)
 
     def is_commit(self) -> bool:
-        return bool(self.precommits)
+        return self.size() > 0
 
     def bit_array(self) -> list[bool]:
         if self._bit_array is None:
-            self._bit_array = [v is not None for v in self.precommits]
+            self._bit_array = (
+                [True] * self._n if self._wire is not None
+                else [v is not None for v in self._votes])
         return self._bit_array
 
     def hash(self) -> bytes:
         """Merkle over the precommit signatures
         (reference `types/block.go:345-354`)."""
         if self._hash is None:
-            items = [(v.signature if v is not None else b"")
-                     for v in self.precommits]
+            if self._wire is not None:
+                items = list(self._cols.unpack(self._wire)[1::2])
+            else:
+                items = [(v.signature if v is not None else b"")
+                         for v in self._votes]
             self._hash = merkle.root(items)
         return self._hash
 
@@ -144,11 +278,16 @@ class Commit:
         """Structural checks (reference `types/block.go:307-331`)."""
         if self.block_id.is_zero():
             raise ValueError("commit with zero block id")
-        if not self.precommits:
+        from tendermint_tpu.types.canonical import TYPE_PRECOMMIT
+        if self._wire is not None:
+            # one (height, round, type) for all by construction
+            if self._hrt[2] != TYPE_PRECOMMIT:
+                raise ValueError("commit vote 0 is not a precommit")
+            return
+        if not self._votes:
             raise ValueError("commit with no precommits")
         height, round_ = self.height(), self.round()
-        from tendermint_tpu.types.canonical import TYPE_PRECOMMIT
-        for i, v in enumerate(self.precommits):
+        for i, v in enumerate(self._votes):
             if v is None:
                 continue
             if v.type != TYPE_PRECOMMIT:
@@ -157,8 +296,10 @@ class Commit:
                 raise ValueError(f"commit vote {i} has wrong height/round")
 
     def encode(self) -> bytes:
-        out = self.block_id.encode() + u32(len(self.precommits))
-        for v in self.precommits:
+        if self._wire is not None:
+            return self._wire
+        out = self.block_id.encode() + u32(len(self._votes))
+        for v in self._votes:
             if v is None:
                 out += u8(0)
             else:
@@ -167,102 +308,38 @@ class Commit:
 
     @classmethod
     def decode(cls, r: Reader) -> "Commit":
+        start = r.pos
         block_id = BlockID.decode(r)
+        bid_len = r.pos - start
         n = r.u32()
+        if n == 0:
+            return cls(block_id=block_id, precommits=[])
+        end = r.pos + n * (_REC_FIXED + bid_len)
+        wire = bytes(r.buf[start:end])
+        reason = _irregular(wire, n, bid_len)
+        if reason is None:
+            r.pos = end
+            commit = cls(block_id=block_id)
+            commit._wire = wire
+            commit._cols = _columns(n, bid_len)
+            commit._n = n
+            at = bid_len + 4 + _OFF_HRT
+            hrt = wire[at:at + 13]
+            commit._hrt = (int.from_bytes(hrt[:8], "big"),
+                           int.from_bytes(hrt[8:12], "big"), hrt[12])
+            REGISTRY.commits_decoded_wire.inc()
+            return commit
         votes: list[Vote | None] = []
         for _ in range(n):
             votes.append(Vote.decode(r) if r.u8() else None)
-        return cls(block_id=block_id, precommits=votes)
+        commit = cls(block_id=block_id, precommits=votes)
+        REGISTRY.commits_decoded_objects.inc()
+        tracing.instant("commit.object_form", height=commit.height(),
+                        reason=reason)
+        return commit
 
 
 EMPTY_COMMIT = Commit(block_id=ZERO_BLOCK_ID, precommits=[])
-
-
-@dataclass
-class CompactCommit:
-    """Array-native commit: the device plane's representation.
-
-    A +2/3 commit whose signatures live as ONE uint8[V, 64] matrix with
-    a presence bitmap instead of V `Vote` objects — the form the batched
-    verifier consumes directly (`ValidatorSet.commit_verify_lanes`
-    accepts either).  At fast-sync scale the object form is real cost:
-    100k blocks x 100 validators is 10M Vote objects (~5 GB of heap and
-    tens of seconds of construction) whose fields the verify plane
-    immediately re-flattens into exactly these arrays.  All lanes share
-    the commit's (height, round, block_id) — the common case fast-sync
-    stores; commits with stray foreign/nil votes keep the object form.
-
-    Conversions are lossless both ways for same-block commits; the wire
-    codec stays `Commit` (this is an in-memory/device layout, not a new
-    wire type).
-    """
-    block_id: "BlockID"
-    height_: int
-    round_: int
-    sigs: "object"           # np.uint8[V, 64]
-    present: "object"        # np.bool_[V]
-
-    def height(self) -> int:
-        return self.height_
-
-    def round(self) -> int:
-        return self.round_
-
-    def size(self) -> int:
-        return len(self.present)
-
-    def num_sigs(self) -> int:
-        return int(self.present.sum())
-
-    def is_commit(self) -> bool:
-        return self.num_sigs() > 0
-
-    def bit_array(self) -> list[bool]:
-        return [bool(b) for b in self.present]
-
-    def validate_basic(self) -> None:
-        if self.block_id.is_zero():
-            raise ValueError("commit with zero block id")
-        if self.size() == 0:
-            raise ValueError("commit with no precommits")
-        if self.sigs.shape != (self.size(), 64):
-            raise ValueError("sigs matrix shape mismatch")
-
-    def to_commit(self, val_set) -> Commit:
-        """Expand to the Vote-object form (for wire encoding / stores)."""
-        from tendermint_tpu.types.canonical import TYPE_PRECOMMIT
-        votes: list[Vote | None] = []
-        for i in range(self.size()):
-            if not self.present[i]:
-                votes.append(None)
-                continue
-            votes.append(Vote(
-                validator_address=val_set.validators[i].address,
-                validator_index=i, height=self.height_, round=self.round_,
-                type=TYPE_PRECOMMIT, block_id=self.block_id,
-                signature=self.sigs[i].tobytes()))
-        return Commit(block_id=self.block_id, precommits=votes)
-
-    @classmethod
-    def from_commit(cls, commit: Commit) -> "CompactCommit | None":
-        """Compact a same-block commit; None if any vote targets a
-        different block (foreign/nil strays need the object form)."""
-        import numpy as np
-        n = commit.size()
-        if n == 0:
-            return None
-        key = commit.block_id.key()
-        sigs = np.zeros((n, 64), dtype=np.uint8)
-        present = np.zeros(n, dtype=bool)
-        for i, v in enumerate(commit.precommits):
-            if v is None:
-                continue
-            if v.block_id.key() != key or len(v.signature) != 64:
-                return None
-            sigs[i] = np.frombuffer(v.signature, np.uint8)
-            present[i] = True
-        return cls(block_id=commit.block_id, height_=commit.height(),
-                   round_=commit.round(), sigs=sigs, present=present)
 
 
 @dataclass

@@ -171,7 +171,8 @@ class ValidatorSet:
         # membership-derived caches survive a copy (invalidated only by
         # apply_updates); the hash also survives accum rotation because
         # hash_bytes excludes accum
-        for attr in ("_set_key", "_pubs_mat", "_hash", "_powers", "_enc"):
+        for attr in ("_set_key", "_pubs_mat", "_addrs", "_hash",
+                     "_powers", "_enc"):
             if attr in self.__dict__:
                 new.__dict__[attr] = self.__dict__[attr]
         return new
@@ -248,6 +249,15 @@ class ValidatorSet:
             self._pubs_mat = m
         return m
 
+    def _addrs_bytes(self) -> bytes:
+        """The members' 20-byte addresses in validator order, joined: what
+        the address column of a wire-backed commit has to read."""
+        b = self.__dict__.get("_addrs")
+        if b is None:
+            b = self.__dict__["_addrs"] = b"".join(
+                v.address for v in self.validators)
+        return b
+
     def encode(self) -> bytes:
         """Vectorized assembly: the state layer persists BOTH valsets on
         every committed block, so a per-validator Python loop (~200 calls
@@ -321,6 +331,7 @@ class ValidatorSet:
         self._by_addr = {v.address: i for i, v in enumerate(self.validators)}
         self._set_key = None     # membership/power changed: invalidate
         self._pubs_mat = None    # the grouped-verify identity + key matrix
+        self.__dict__.pop("_addrs", None)
         self.__dict__.pop("_hash", None)
         self.__dict__.pop("_enc", None)
         self.__dict__.pop("_powers", None)
@@ -373,10 +384,15 @@ class ValidatorSet:
         vote must not redirect fast-sync blame when the real defect is a
         pruned LastCommit).
         """
-        from tendermint_tpu.types.block import CompactCommit
-        if isinstance(commit, CompactCommit):
-            return self._compact_commit_lanes(chain_id, block_id, height,
-                                              commit)
+        cols = self._wire_columns(height, commit)
+        if cols is not None:
+            return self._wire_commit_lanes(chain_id, block_id, commit, cols)
+        return self._vote_lanes(chain_id, block_id, height, commit)
+
+    def _vote_lanes(self, chain_id: str, block_id, height: int,
+                    commit) -> tuple:
+        """`commit_verify_lanes` vote by vote: the one place where each
+        check has its message."""
         if self.size() != commit.size():
             raise ValueError(
                 f"commit size {commit.size()} != valset size {self.size()}")
@@ -430,40 +446,73 @@ class ValidatorSet:
             foreign_power,
         )
 
-    def _compact_commit_lanes(self, chain_id: str, block_id, height: int,
-                              cc) -> tuple:
-        """`commit_verify_lanes` for the array-native `CompactCommit`:
-        the per-vote Python loop collapses to numpy — every present lane
-        shares the commit's (height, round, block_id), so there is ONE
-        template, the sigs matrix slices directly into lanes, and powers
-        come from the cached power array.  Same return contract and the
-        same strictness (shape checks replace per-vote field checks —
-        fixed-width arrays cannot misalign lanes)."""
-        cc.validate_basic()
-        if self.size() != cc.size():
-            raise ValueError(
-                f"commit size {cc.size()} != valset size {self.size()}")
-        if cc.height() != height:
-            raise ValueError(f"commit height {cc.height()} != {height}")
-        tmpl = canonical.sign_bytes(
-            chain_id, canonical.TYPE_PRECOMMIT, height, cc.round(),
-            block_hash=cc.block_id.hash,
-            parts_hash=cc.block_id.parts.hash,
-            parts_total=cc.block_id.parts.total)
-        idxs = np.flatnonzero(cc.present).astype(np.int32)
-        sigs = np.ascontiguousarray(cc.sigs[idxs])
-        n = len(idxs)
-        if cc.block_id.key() == block_id.key():
-            powers = self._powers_arr()[idxs]
+    def _wire_refusal(self, height: int, commit, cols: tuple) -> str | None:
+        """Why the vectorized lane builders may not take a wire-backed
+        commit's columns, in a word; None when they may: every check the
+        per-vote loop of `commit_verify_lanes` makes then holds by
+        inspection (what `Commit.decode` pinned, plus the set's size and
+        addresses, the expected height, the type byte and 32-byte
+        hashes), so the two cannot diverge."""
+        addrs, _sigs, c_height, _round, type_ = cols
+        bid = commit.block_id
+        if commit.size() != self.size():
+            return "size"
+        if c_height != height or height < 1:
+            return "height"
+        if type_ != canonical.TYPE_PRECOMMIT:
+            return "type"
+        if len(bid.hash) != 32 or len(bid.parts.hash) != 32:
+            return "block id"
+        if addrs != self._addrs_bytes():
+            return "address"
+        return None
+
+    def _wire_columns(self, height: int, commit) -> tuple | None:
+        """`commit.wire_columns()` when `_wire_commit_lanes` may take
+        them.  None for a commit built from votes, and for a wire-backed
+        one that fails a check (recorded as `commit.object_form`): the
+        per-vote loop then raises the canonical error with its message."""
+        cols = commit.wire_columns()
+        if cols is None:
+            return None
+        reason = self._wire_refusal(height, commit, cols)
+        if reason is None:
+            return cols
+        tracing.instant("commit.object_form", height=height, reason=reason)
+        return None
+
+    def _window_wire_columns(self, items: list[tuple]) -> list | None:
+        """Every commit's `wire_columns()` when the whole window may take
+        the vectorized pass, else None (nothing recorded: the per-block
+        path the window then takes records what it refuses)."""
+        cols = []
+        for _bid, h, c in items:
+            col = c.wire_columns()
+            if col is None or self._wire_refusal(h, c, col) is not None:
+                return None
+            cols.append(col)
+        return cols
+
+    def _wire_commit_lanes(self, chain_id: str, block_id, commit,
+                           cols: tuple) -> tuple:
+        """`commit_verify_lanes` without the per-vote loop, for columns
+        `_wire_columns` passed: every lane shares the commit's (height,
+        round, block_id), so there is ONE template, the signature column
+        is the lanes' sigs and the powers are the set's power array."""
+        bid = commit.block_id
+        tmpl = _commit_template(chain_id, commit)
+        n = self.size()
+        if bid.key() == block_id.key():
+            powers = self._powers_arr().copy()
             foreign_power = 0
-        else:   # the whole commit endorses another (or nil) block
+        else:   # the whole commit endorses another block (32-byte hash)
             powers = np.zeros(n, dtype=np.int64)
-            foreign_power = (0 if cc.block_id.is_zero()
-                             else int(self._powers_arr()[idxs].sum()))
+            foreign_power = self._total
         return (np.frombuffer(tmpl, np.uint8).reshape(
                     1, canonical.SIGN_BYTES_LEN),
-                np.zeros(n, dtype=np.int32), sigs,
-                powers.astype(np.int64), idxs, foreign_power)
+                np.zeros(n, dtype=np.int32),
+                np.frombuffer(cols[1], np.uint8).reshape(n, 64),
+                powers, np.arange(n, dtype=np.int32), foreign_power)
 
     def verify_commit(self, chain_id: str, block_id, height: int,
                       commit, producer: str = "fastsync",
@@ -493,6 +542,15 @@ class ValidatorSet:
                 f"power {self._total}]")
 
 
+def _commit_template(chain_id: str, commit) -> bytes:
+    """The sign bytes every vote of a wire-backed commit shares."""
+    bid = commit.block_id
+    return canonical.sign_bytes(
+        chain_id, canonical.TYPE_PRECOMMIT, commit.height(), commit.round(),
+        block_hash=bid.hash, parts_hash=bid.parts.hash,
+        parts_total=bid.parts.total)
+
+
 def merge_commit_lanes(arrays: list[tuple]) -> tuple:
     """Concatenate per-commit `commit_verify_lanes` tuples into one
     device batch, rebasing each commit's template indices onto the
@@ -508,40 +566,21 @@ def merge_commit_lanes(arrays: list[tuple]) -> tuple:
             np.concatenate([a[4] for a in arrays]))
 
 
-def _window_fast_eligible(val_set: ValidatorSet, items: list[tuple]) -> bool:
-    """True when every commit in the window satisfies, by inspection, all
-    preconditions the per-block `_compact_commit_lanes` checks — so the
-    vectorized pass below cannot diverge from the loop it replaces.  Any
-    violation (or any object-form commit) routes to the per-block path,
-    which raises the canonical error with the canonical message."""
-    from tendermint_tpu.types.block import CompactCommit
-    v = val_set.size()
-    return v > 0 and all(
-        isinstance(c, CompactCommit)
-        and len(c.present) == v
-        and c.height_ == h
-        and c.sigs.shape == (v, 64)
-        and len(c.block_id.hash) == 32
-        and len(c.block_id.parts.hash) == 32
-        for _bid, h, c in items)
-
-
 def window_commit_lanes(val_set: ValidatorSet, chain_id: str,
                         items: list[tuple]) -> tuple:
     """Window-level lane builder: the vectorized fusion of per-block
     `commit_verify_lanes` + `merge_commit_lanes` over a whole fast-sync
     window (`items` = [(block_id, height, commit)]).
 
-    The per-block loop is the replay pipeline's scalar tail: 625 rounds
-    of sign-bytes assembly, flatnonzero, sig-slice copies, and a 625-way
-    concatenate, all holding the GIL inside the prep stage.  When every
-    commit is an array-native `CompactCommit` (the form fast-sync
-    stores), the whole window collapses to one `batch_sign_bytes` call,
-    one boolean-matrix nonzero, and one fancy-indexed sig gather —
-    byte-identical to the loop (property-tested), a couple of numpy
-    passes instead of ~6 x B Python-level array ops.  Any object-form
-    commit or precondition violation falls back to the per-block path so
-    results and errors match exactly.
+    The per-block loop is the look-ahead's scalar tail: a walk over V
+    `Vote` objects a block, B rounds of sign-bytes assembly and a B-way
+    concatenate, all holding the GIL beside the apply.  When every
+    commit is wire-backed and passes `_window_wire_columns` (what
+    fast-sync decodes from an honest peer), the whole window collapses
+    to one template a block and one gather of the signature columns:
+    byte-identical to the loop (property-tested).  Any commit built
+    from votes, or a check that fails, routes the window to the
+    per-block path so results and errors match exactly.
 
     Returns (templates[T,128], tmpl_idx[N], sigs[N,64], idxs[N],
     counts[B], tallied[B], foreign[B]): the first four are the merged
@@ -557,7 +596,8 @@ def window_commit_lanes(val_set: ValidatorSet, chain_id: str,
                 np.zeros(0, dtype=np.int32),
                 np.zeros((0, 64), dtype=np.uint8),
                 np.zeros(0, dtype=np.int32), z, z.copy(), z.copy())
-    if not _window_fast_eligible(val_set, items):
+    cols = val_set._window_wire_columns(items)
+    if cols is None:
         arrays = []
         for bid, h, c in items:
             try:
@@ -573,32 +613,23 @@ def window_commit_lanes(val_set: ValidatorSet, chain_id: str,
                              dtype=np.int64)
         foreign = np.asarray([a[5] for a in arrays], dtype=np.int64)
         return templates, tmpl_idx, sigs, idxs, counts, tallied, foreign
-    b = len(items)
-    heights = np.fromiter((c.height_ for _, _, c in items), np.int64, b)
-    rounds = np.fromiter((c.round_ for _, _, c in items), np.int64, b)
-    totals = np.fromiter((c.block_id.parts.total for _, _, c in items),
-                         np.int64, b)
-    bh = np.frombuffer(b"".join(c.block_id.hash for _, _, c in items),
-                       np.uint8).reshape(b, 32)
-    ph = np.frombuffer(b"".join(c.block_id.parts.hash for _, _, c in items),
-                       np.uint8).reshape(b, 32)
-    templates = canonical.batch_sign_bytes(
-        chain_id, np.full(b, canonical.TYPE_PRECOMMIT, dtype=np.int64),
-        heights, rounds, bh, ph, totals)
-    present = np.stack([c.present for _, _, c in items])    # bool[B,V]
-    # row-major nonzero == per-block flatnonzero, already in merge order
-    lane_b, lane_v = np.nonzero(present)
-    idxs = lane_v.astype(np.int32)
-    tmpl_idx = lane_b.astype(np.int32)   # one template per compact commit
-    all_sigs = np.stack([c.sigs for _, _, c in items])      # uint8[B,V,64]
-    sigs = np.ascontiguousarray(all_sigs[lane_b, lane_v])
-    counts = present.sum(axis=1, dtype=np.int64)
-    powers = np.where(present, val_set._powers_arr()[np.newaxis, :], 0)
-    row_power = powers.sum(axis=1, dtype=np.int64)
+    b, v = len(items), val_set.size()
+    # one template a commit, assembled as the per-vote loop's are; then
+    # one numpy call an array for the window, not one a block
+    templates = np.frombuffer(b"".join(
+        _commit_template(chain_id, c) for _bid, _h, c in items),
+        np.uint8).reshape(b, canonical.SIGN_BYTES_LEN)
+    # every vote present: block-major lanes, already in merge order
+    idxs = np.tile(np.arange(v, dtype=np.int32), b)
+    tmpl_idx = np.repeat(np.arange(b, dtype=np.int32), v)
+    sigs = np.frombuffer(b"".join(col[1] for col in cols),
+                         np.uint8).reshape(b * v, 64)
+    counts = np.full(b, v, dtype=np.int64)
+    row_power = np.full(b, val_set.total_voting_power(), dtype=np.int64)
     same = np.fromiter(
         (c.block_id.key() == bid.key() for bid, _, c in items), bool, b)
-    # validate_basic already rejects nil compact commits, so every
-    # non-matching commit endorses a foreign non-nil block
+    # a 32-byte hash is no nil block id, so every commit that does not
+    # match endorses a foreign non-nil block
     tallied = np.where(same, row_power, 0)
     foreign = np.where(same, 0, row_power)
     return templates, tmpl_idx, sigs, idxs, counts, tallied, foreign

@@ -294,6 +294,10 @@ class Registry:
         self.vote_microbatch_lanes = Counter()
         # sync plane
         self.blocks_synced = Counter()
+        # commits `Commit.decode` left in their wire bytes / decoded vote
+        # by vote (an absent, nil or foreign vote, any irregular record)
+        self.commits_decoded_wire = Counter()
+        self.commits_decoded_objects = Counter()
         # state-sync / snapshot plane (statesync/): chunks_verified vs
         # chunks_rejected is the no-silent-acceptance ledger — every
         # fetched chunk lands in exactly one of the two, and a rejected
@@ -413,6 +417,8 @@ class Registry:
             "vote_microbatches": self.vote_microbatches.value,
             "vote_microbatch_lanes": self.vote_microbatch_lanes.value,
             "blocks_synced": self.blocks_synced.value,
+            "commits_decoded_wire": self.commits_decoded_wire.value,
+            "commits_decoded_objects": self.commits_decoded_objects.value,
             "snapshots_created": self.snapshots_created.value,
             "snapshot_create_seconds_mean":
                 round(self.snapshot_create_seconds.mean, 6),

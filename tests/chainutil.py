@@ -13,8 +13,10 @@ from tendermint_tpu.scenarios.fixtures import (  # noqa: F401
 
 
 def fast_sync_in_process(chain_id: str, n_blocks: int, batch_size: int,
-                         sqlite_dir=None, timeout: float = 40.0):
-    """Fast-sync a fresh `n_blocks` chain from one in-process source peer
+                         sqlite_dir=None, timeout: float = 40.0,
+                         n_vals: int = 4):
+    """Fast-sync a fresh `n_blocks` chain of `n_vals` validators from one
+    in-process source peer
     through the real reactors (pool, look-ahead, `apply_window`), with
     the python crypto backend; the syncer keeps its stores in sqlite
     under `sqlite_dir` when given.  Returns the syncer's
@@ -31,7 +33,7 @@ def fast_sync_in_process(chain_id: str, n_blocks: int, batch_size: int,
     from tendermint_tpu.state.state import get_state
     from tendermint_tpu.utils.db import MemDB, SQLiteDB
 
-    privs, vs = make_validators(4)
+    privs, vs = make_validators(n_vals)
     gen = make_genesis(chain_id, privs)
     chain = build_chain(privs, vs, chain_id, n_blocks,
                         app_hashes=kvstore_app_hashes(n_blocks))

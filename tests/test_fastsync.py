@@ -559,3 +559,144 @@ def test_fast_sync_byzantine_pruned_commit_spares_honest_peer():
                 honest_store.load_block(h).hash()
     finally:
         _stop_nodes(bc, cons, byz_sw, honest_sw, sync_sw)
+
+
+# -- commits that stay in their wire bytes ------------------------------------
+
+def test_fast_sync_of_100_validators_stores_the_bytes_object_commits_would(
+        tmp_path):
+    """A 100-validator chain fast-synced through the real reactor into
+    sqlite: every commit reached the stores without a `Vote` being made
+    (`Commit.decode` left it in its wire bytes), and every `H:`, `P:`,
+    `C:` and `SC:` row is the row `BlockStore.save_block` writes for the
+    same chain with commits built from votes.  The stored commits load
+    back as what was signed, and a node restarted on those stores passes
+    the handshake and rebuilds its last commit from the seen one."""
+    from chainutil import fast_sync_in_process
+    from tendermint_tpu.consensus.replay import Handshaker
+    from tendermint_tpu.types import BlockID
+    from tendermint_tpu.utils.db import SQLiteDB
+    from tendermint_tpu.utils.metrics import REGISTRY
+    n, cid = 21, "wire-commit-chain"
+    before = (REGISTRY.commits_decoded_wire.value,
+              REGISTRY.commits_decoded_objects.value)
+    bc = fast_sync_in_process(cid, n, 8, sqlite_dir=str(tmp_path),
+                              n_vals=100)
+    # the syncer decoded blocks 2..n from the wire; nothing took the
+    # object path (the source serves stored bytes and decodes nothing
+    # but what it loads to serve, which is wire-backed too)
+    assert REGISTRY.commits_decoded_wire.value - before[0] >= n - 2
+    assert REGISTRY.commits_decoded_objects.value == before[1]
+    top = bc.store.height
+    assert top >= n - 1
+
+    privs, vs = make_validators(100)
+    chain = build_chain(privs, vs, cid, n, app_hashes=kvstore_app_hashes(n))
+    ref = BlockStore(MemDB())
+    for block, ps, seen in chain[:top]:
+        assert seen.wire_columns() is None          # built from votes
+        ref.save_block(block, ps, seen)
+    for prefix in (b"H:", b"P:", b"C:", b"SC:"):
+        got = bc.store.db.iterate_prefix(prefix)
+        assert got == ref.db.iterate_prefix(prefix), prefix
+        assert len(got) >= top
+
+    # the restart: both dbs opened anew, a fresh app
+    bc.store.db.close()
+    store = BlockStore(SQLiteDB(str(tmp_path / "blocks.db")))
+    state = get_state(SQLiteDB(str(tmp_path / "state.db")),
+                      make_genesis(cid, privs))
+    assert store.height == top
+    for h in range(1, top + 1):
+        block, ps, seen = chain[h - 1]
+        for got in (store.load_seen_commit(h),
+                    store.load_block_commit(h) if h < top else None):
+            if got is None:
+                continue
+            assert got.wire_columns() is not None
+            assert got == seen and got.encode() == seen.encode()
+            vs.verify_commit(cid, BlockID(block.hash(), ps.header), h, got)
+        assert store.load_block(h).last_commit == block.last_commit
+    conns = ClientCreator("kvstore").new_app_conns()
+    Handshaker(state, store).handshake(conns)
+    assert state.last_block_height == top
+    assert state.app_hash == conns.query.info().last_block_app_hash
+    cs = ConsensusState(fast_config().consensus, state, conns.consensus,
+                        store, Mempool(conns.mempool))
+    assert cs.last_commit.has_two_thirds_majority()
+    assert cs.last_commit.make_commit() == chain[top - 1][2]
+
+
+def _window_through_receive(blocks_encoded, batch_size):
+    """A syncer fed `blocks_encoded` (heights 1..) through
+    `BlockchainReactor.receive`, as a peer's answers arrive; returns the
+    reactor with every block in its pool, not yet synced."""
+    privs, _vs = make_validators(4)
+    state = get_state(MemDB(), make_genesis(CHAIN, privs))
+    conns = ClientCreator("kvstore").new_app_conns()
+    bc = BlockchainReactor(state, conns.consensus, BlockStore(MemDB()),
+                           fast_sync=True, batch_size=batch_size)
+
+    class Peer:
+        id = "source-peer"
+
+    bc.pool.on_evict = lambda peer_id, reason: None
+    bc.pool.set_peer_height(Peer.id, len(blocks_encoded))
+    assert len(bc.pool.schedule()) == len(blocks_encoded)
+    for enc in blocks_encoded:
+        bc.receive(BLOCKCHAIN_CHANNEL, Peer,
+                   BM.encode_msg(BM.BlockResponse(enc)))
+    return bc
+
+
+def test_a_regular_window_records_nothing_and_a_pruned_commit_one_instant():
+    """What the flight recorder and the counter pair say of a 64-block
+    window: nothing of commits that stayed in their bytes (6,400 lanes
+    must not buy 64 ring writes on the receive threads); of one pruned
+    commit one `commit.object_form` instant with its height, and the
+    blame the parent gives (`pool.redo` of the successor, which carried
+    it)."""
+    from tendermint_tpu.types import Block, Commit
+    from tendermint_tpu.utils import tracing
+    from tendermint_tpu.utils.metrics import REGISTRY
+    privs, vs = make_validators(4)
+    chain = build_chain(privs, vs, CHAIN, 65,
+                        app_hashes=kvstore_app_hashes(65))
+    encoded = [block.encode() for block, _ps, _seen in chain]
+
+    def since(t0, name):
+        return [s.get("args") for s in tracing.RECORDER.since(t0)
+                if s["name"] == name and s["ts"] >= t0]
+
+    t0 = tracing.now_epoch()
+    wire0 = REGISTRY.commits_decoded_wire.value
+    objects0 = REGISTRY.commits_decoded_objects.value
+    bc = _window_through_receive(encoded, 64)
+    assert bc._sync_step() is True
+    assert bc.state.last_block_height == 64
+    # block 1 carries the empty commit, which counts on neither side
+    assert REGISTRY.commits_decoded_wire.value - wire0 == 64
+    assert REGISTRY.commits_decoded_objects.value == objects0
+    assert since(t0, "commit.object_form") == []
+    assert len(since(t0, "fastsync.decode")) == 65
+
+    # block 40 arrives with its last commit (height 39's) pruned to one
+    # vote, as `scenarios/injectors.py` serves it
+    block = chain[39][0]
+    lc = block.last_commit
+    evil = Block(header=block.header, txs=block.txs, last_commit=Commit(
+        block_id=lc.block_id,
+        precommits=[v if i == 0 else None
+                    for i, v in enumerate(lc.precommits)]))
+    t0 = tracing.now_epoch()
+    wire0 = REGISTRY.commits_decoded_wire.value
+    bc = _window_through_receive(
+        encoded[:39] + [evil.encode()] + encoded[40:], 64)
+    assert REGISTRY.commits_decoded_wire.value - wire0 == 63
+    assert REGISTRY.commits_decoded_objects.value - objects0 == 1
+    assert since(t0, "commit.object_form") == [
+        {"height": 39, "reason": "length"}]
+    assert bc._sync_step() is False
+    assert bc.state.last_block_height == 0
+    assert [a["height"] for a in since(t0, "pool.redo")] == [40]
+    assert len(since(t0, "commit.object_form")) == 1

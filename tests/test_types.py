@@ -428,49 +428,52 @@ def test_proposal_sign_bytes_distinct():
     assert v.sign_bytes(CHAIN) != p1.sign_bytes(CHAIN)
 
 
-def test_compact_commit_roundtrip_and_lanes():
-    """Array-native CompactCommit: lossless conversion with the Vote
-    form, and identical verify-lane output from commit_verify_lanes."""
+def test_wire_commit_roundtrip_and_lanes():
+    """A commit decoded from the wire: lossless against the Vote form it
+    was encoded from, and identical verify-lane output from
+    commit_verify_lanes."""
     import numpy as np
-    from chainutil import make_validators, sign_vote, make_commit
-    from tendermint_tpu.types import BlockID, CompactCommit
+    from chainutil import make_validators, make_commit
+    from tendermint_tpu.types import BlockID, Commit
+    from tendermint_tpu.types.codec import Reader
     from tendermint_tpu.types.part_set import PartSetHeader
 
     privs, vs = make_validators(8)
     bid = BlockID(b"\x11" * 32, PartSetHeader(2, b"\x22" * 32))
     commit = make_commit(privs, vs, "cc-chain", 5, bid)
-    cc = CompactCommit.from_commit(commit)
-    assert cc is not None
-    assert (cc.height(), cc.round(), cc.size()) == (5, 0, 8)
-    assert cc.num_sigs() == commit.num_sigs()
+    wc = Commit.decode(Reader(commit.encode()))
+    assert wc.wire_columns() is not None
+    assert (wc.height(), wc.round(), wc.size()) == (5, 0, 8)
+    assert wc.num_sigs() == commit.num_sigs()
 
     # lanes match the object form exactly (templates content included)
     lo = vs.commit_verify_lanes("cc-chain", bid, 5, commit)
-    lc = vs.commit_verify_lanes("cc-chain", bid, 5, cc)
+    lc = vs.commit_verify_lanes("cc-chain", bid, 5, wc)
     assert np.array_equal(lo[0][lo[1]], lc[0][lc[1]])   # per-lane msgs
     assert np.array_equal(lo[2], lc[2])                 # sigs
     assert np.array_equal(lo[3], lc[3])                 # powers
     assert np.array_equal(lo[4], lc[4])                 # idxs
     assert lo[5] == lc[5] == 0                          # foreign power
 
-    # verify_commit accepts the compact form end to end
-    vs.verify_commit("cc-chain", bid, 5, cc)
+    # verify_commit accepts the wire form end to end
+    vs.verify_commit("cc-chain", bid, 5, wc)
 
-    # and the round-trip back to the object form is lossless
-    back = cc.to_commit(vs)
-    assert back.block_id == commit.block_id
-    assert [v and v.signature for v in back.precommits] == \
-        [v and v.signature for v in commit.precommits]
+    # and the votes it makes on demand are the ones that were encoded
+    assert wc.block_id == commit.block_id
+    assert wc.precommits == commit.precommits
+    assert wc.encode() == commit.encode()
 
     # a commit for ANOTHER block id: powers zero, foreign power total
     other = BlockID(b"\x33" * 32, PartSetHeader(2, b"\x44" * 32))
-    lo2 = vs.commit_verify_lanes("cc-chain", other, 5, cc)
+    lo2 = vs.commit_verify_lanes("cc-chain", other, 5, wc)
     assert lo2[3].sum() == 0 and lo2[5] == vs.total_voting_power()
 
-    # sparse commit (missing votes) keeps lane alignment
+    # sparse commit (missing votes) decodes vote by vote and keeps lane
+    # alignment
     commit.precommits[3] = None
-    cc2 = CompactCommit.from_commit(commit)
-    ls = vs.commit_verify_lanes("cc-chain", bid, 5, cc2)
+    sparse = Commit.decode(Reader(commit.encode()))
+    assert sparse.wire_columns() is None
+    ls = vs.commit_verify_lanes("cc-chain", bid, 5, sparse)
     assert list(ls[4]) == [i for i in range(8) if i != 3]
 
 
