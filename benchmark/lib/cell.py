@@ -99,11 +99,12 @@ def chain_blocks(cell: dict, seconds: float) -> int:
     return windows * WINDOW_BLOCKS + 1
 
 
-def boot_node(home: str, gen, addrs: list[str]):
+def boot_node(home: str, gen, addrs: list[str], app: str | None = None):
     """What `cli node --home <home> --crypto-backend tpu --fast-sync`
     constructs (copied from `chip_smoke.boot_node`): genesis and
     priv-validator on disk, sqlite stores, RPC and p2p on loopback, the
-    supervised ladder off."""
+    supervised ladder off; with `--proxy-app <app>` where the
+    configuration's file names its app."""
     from tendermint_tpu.config import Config
     from tendermint_tpu.node.node import Node
     os.makedirs(home, exist_ok=True)
@@ -113,6 +114,8 @@ def boot_node(home: str, gen, addrs: list[str]):
     cfg.base.moniker = os.path.basename(home)
     cfg.base.crypto_backend = "tpu"
     cfg.base.fast_sync = True
+    if app is not None:
+        cfg.base.proxy_app = app
     cfg.crypto.supervised = False
     cfg.rpc.laddr = "tcp://127.0.0.1:0"
     cfg.p2p.laddr = "tcp://127.0.0.1:0"
@@ -122,10 +125,12 @@ def boot_node(home: str, gen, addrs: list[str]):
     return Node(cfg), cfg
 
 
-def stated_as_run(cfg: dict) -> None:
+def stated_as_run(cfg: dict, node_cfg=None) -> None:
     """The deployment's shapes and limits as its file states them are
     the program's own defaults: the harness sets none of them, so a
-    default that moves is a different deployment, and an error here."""
+    default that moves is a different deployment, and an error here.
+    So is a node (`node_cfg`, the booted node's Config) whose app is not
+    the one the file names."""
     from tendermint_tpu.blockchain import pool, reactor
     from tendermint_tpu.config import P2PConfig
     from tendermint_tpu.types.part_set import PART_SIZE
@@ -134,6 +139,8 @@ def stated_as_run(cfg: dict) -> None:
            "max_pending_requests": pool.MAX_PENDING,
            "max_pending_per_peer": pool.MAX_PENDING_PER_PEER,
            "peer_rate_bytes_per_s": min(p2p.send_rate, p2p.recv_rate)}
+    if node_cfg is not None and "app" in cfg:
+        run["app"] = node_cfg.base.proxy_app
     differ = {k: (cfg[k], v) for k, v in run.items() if cfg[k] != v}
     if differ or reactor.DEFAULT_BATCH != WINDOW_BLOCKS:
         raise RuntimeError("the configuration states (file, program): "
@@ -290,6 +297,7 @@ def run_cell(root: str, cell: dict, seed: int, seconds: float, trace: bool,
     MeasuredNothing when the run cannot give a reading."""
     cfg, traffic = cell["config"], cell["traffic"]
     n_vals, n_sources = cfg["validators"], cfg["source_peers"]
+    valset = traffic.get("valset")    # the mix's validator-set plan
     n_blocks = chain_blocks(cell, seconds)
     workdir = tempfile.mkdtemp(prefix="tmbench_")
     kids = children_mod.Children(root)
@@ -306,7 +314,8 @@ def run_cell(root: str, cell: dict, seed: int, seconds: float, trace: bool,
             json.dump({"seed": seed, "chain_id": chain_id, "n_vals": n_vals,
                        "n_blocks": n_blocks, "n_sources": n_sources,
                        "traffic": traffic["block"],
-                       "index_path": index_path}, f)
+                       "index_path": index_path,
+                       **({"valset": valset} if valset else {})}, f)
         source = kids.start("benchmark.lib.source_child", spec_path)
         prober = kids.start("benchmark.lib.prober_child")
         say(f"children: source {source.pid}, prober {prober.pid}")
@@ -335,7 +344,6 @@ def run_cell(root: str, cell: dict, seed: int, seconds: float, trace: bool,
         from tendermint_tpu.utils import tracing
         from tendermint_tpu.utils.metrics import REGISTRY
         from benchmark.lib import chain, control, devtrace, reducers
-        stated_as_run(cfg)
         if fault:
             from benchmark.lib import faults
             faults.install(fault)
@@ -355,7 +363,8 @@ def run_cell(root: str, cell: dict, seed: int, seconds: float, trace: bool,
         spans_before = tracing.RECORDER.total
         t_boot = tracing.now_epoch()
         node, node_cfg = boot_node(os.path.join(workdir, "node"), gen,
-                                   ready["addrs"])
+                                   ready["addrs"], cfg.get("app"))
+        stated_as_run(cfg, node_cfg)
         be = cb.get_backend()
         if type(be).__name__ != "TpuBackend" or be.platform != platform:
             raise RuntimeError(f"node installed backend {be!r} on "
@@ -475,16 +484,29 @@ def run_cell(root: str, cell: dict, seed: int, seconds: float, trace: bool,
         st = rpc.status()
         blk = rpc.block(height=tip)["block"]
         vals = rpc.validators()["validators"]
-        rpc_wrong = sum((
-            st["latest_block_height"] != tip,
-            st["latest_block_hash"] != index["block_hash"][tip - 1],
-            st["validator_count"] != n_vals,
-            blk["block_hash"] != index["block_hash"][tip - 1],
-            blk["header"]["height"] != tip,
-            blk["last_commit"]["precommits"] != (n_vals if tip > 1 else 0),
-            [v["pub_key"] for v in vals] != ready["genesis"]["validators"]))
+        # the set of the height after the tip, as the builder has it (the
+        # genesis set where the mix states no plan): what the node's
+        # state and its RPC have to hold, and what the control signs with
+        val_seeds, vs = chain.valset_at(seed, n_vals, valset, tip + 1)
+        sets = [s["from_height"] for s in index["valsets"]]
+        say(f"validators: the builder's set {sum(h <= tip + 1 for h in sets)}"
+            f" of {len(sets)} holds at height {tip + 1}")
+        rpc_wrong = [name for name, differs in (
+            ("/status height", st["latest_block_height"] != tip),
+            ("/status hash",
+             st["latest_block_hash"] != index["block_hash"][tip - 1]),
+            ("/status validator_count", st["validator_count"] != n_vals),
+            ("/block hash", blk["block_hash"] != index["block_hash"][tip - 1]),
+            ("/block height", blk["header"]["height"] != tip),
+            ("/block precommits", blk["last_commit"]["precommits"] !=
+             (n_vals if tip > 1 else 0)),
+            ("/validators", [v["pub_key"] for v in vals] !=
+             [v.pub_key.bytes_.hex() for v in vs.validators]),
+            ("state validators hash",
+             bc.state.validators.hash() != vs.hash())) if differs]
         checks.at_most("rpc_answers_differ", "/status, /block, /validators "
-                       "answers that differ", rpc_wrong, 0)
+                       "answers and the state's validator set that differ "
+                       f"from the builder's {rpc_wrong}", len(rpc_wrong), 0)
         moved = {k: counters_close[k] - counters_boot[k] for k in
                  ("sigs_verified", "blocks_synced", "crypto_fallback_calls")}
         checks.at_most("fallback_calls", "crypto_fallback_calls moved by",
@@ -507,12 +529,9 @@ def run_cell(root: str, cell: dict, seed: int, seconds: float, trace: bool,
         checks.at_most("probe_errors", "probes answered with an error",
                        probe["errors"], 0)
 
-        # the verdict control: the window's own bucket, after the window
-        val_seeds, vs = chain.make_validators(seed, n_vals)
-        if vs.hash() != bc.state.validators.hash():
-            raise RuntimeError("the harness's validator set is not the "
-                               "node's")
-
+        # the verdict control: the window's own bucket, after the window,
+        # signed by the builder's set and run against the node's, whose
+        # table is resident
         t_control = tracing.now_epoch()
         batch = control.build(seed, val_seeds, WINDOW_BLOCKS)
         got = control.device_verdicts(bc.state.validators, batch)

@@ -11,11 +11,18 @@ The program's own types only give the blocks their wire format.  What the builde
 is what a source peer serves (the encoded block) and what the check
 needs (block hash, app hash after the height, encoded size).
 
+A traffic mix may state a validator-set plan (`valset`, README): then
+the set that signs changes where the plan says, by `val:` txs the blocks
+carry and the reference app returns as `EndBlock` diffs.  `valset_at` is
+the one function that says which set a height has; the builder, the
+source child's index and the harness's check all read it.
+
 Nothing in this module may import jax: it runs in the source child.
 """
 
 from __future__ import annotations
 
+import functools
 import hashlib
 import os
 import subprocess
@@ -30,24 +37,98 @@ POWER = 10
 GENESIS_TIME_NS = 1_000_000_000
 # below this many validators a pipe round trip costs more than signing
 MIN_VALS_FOR_WORKERS = 32
+# upstream persistent_dummy's validator tx: `val:<pubkey hex>/<power>`
+VAL_TX_PREFIX = b"val:"
 
 
 def val_seed(seed: int, i: int) -> bytes:
     return hashlib.sha256(b"tm-bench/%d/val/%d" % (seed, i)).digest()
 
 
-def make_validators(seed: int, n: int):
+def pub_of(key_seed: bytes) -> bytes:
+    return (Ed25519PrivateKey.from_private_bytes(key_seed).public_key()
+            .public_bytes_raw())
+
+
+def _set_of(seeds: list[bytes]):
     """(seeds aligned with the set's validator order, ValidatorSet).
     Public keys are OpenSSL's; the program's ValidatorSet only orders
     them (by address) and hashes the set for the headers."""
     from tendermint_tpu.types import Validator, ValidatorSet
     from tendermint_tpu.types.keys import PubKey
-    seeds = [val_seed(seed, i) for i in range(n)]
-    pubs = {s: Ed25519PrivateKey.from_private_bytes(s).public_key()
-            .public_bytes_raw() for s in seeds}
+    pubs = {s: pub_of(s) for s in seeds}
     vs = ValidatorSet([Validator(PubKey(pubs[s]), POWER) for s in seeds])
     by_addr = {PubKey(pubs[s]).address: s for s in seeds}
     return [by_addr[v.address] for v in vs.validators], vs
+
+
+def make_validators(seed: int, n: int):
+    """The genesis set: keys 0..n-1 of the seed, as `_set_of` gives it."""
+    return _set_of([val_seed(seed, i) for i in range(n)])
+
+
+@functools.lru_cache(maxsize=None)
+def _members(seed: int, n: int, swap: int, epoch: int) -> tuple[int, ...]:
+    """Key indices of the set after `epoch` changes.  At change c the
+    `swap` members whose sha256(seed, c, index) is least leave, and the
+    keys n + (c-1) x swap + j, j < swap, never used before, join."""
+    if epoch == 0:
+        return tuple(range(n))
+    prev = _members(seed, n, swap, epoch - 1)
+    out = set(sorted(prev, key=lambda i: hashlib.sha256(
+        b"tm-bench/%d/out/%d/%d" % (seed, epoch, i)).digest())[:swap])
+    first_new = n + (epoch - 1) * swap
+    return tuple(i for i in prev if i not in out) + tuple(
+        range(first_new, first_new + swap))
+
+
+def valset_members(seed: int, n: int, plan: dict | None,
+                   height: int) -> tuple[int, ...]:
+    """Key indices of the set of `height` (>= 1) under a traffic mix's
+    `valset` plan: the diffs of a height h with h % change_every_blocks
+    == 0 make the set of h + 1.  No plan: the genesis set throughout."""
+    if not plan:
+        return tuple(range(n))
+    every, swap = plan["change_every_blocks"], plan["swap"]
+    if every < 1 or not 1 <= swap <= n:
+        raise ValueError(f"valset plan {plan!r} for {n} validators: needs "
+                         "change_every_blocks >= 1 and 1 <= swap <= n")
+    members = ()
+    # epoch by epoch, so that the cached recursion is one call deep
+    for epoch in range((height - 1) // every + 1):
+        members = _members(seed, n, swap, epoch)
+    return members
+
+
+def valset_at(seed: int, n: int, plan: dict | None, height: int):
+    """(signing seeds in set order, ValidatorSet) of `height`: the set
+    that signs the commit of `height` and whose hash its header holds."""
+    return _set_of([val_seed(seed, i)
+                    for i in valset_members(seed, n, plan, height)])
+
+
+def valset_txs(seed: int, n: int, plan: dict | None, h: int) -> list[bytes]:
+    """The `val:` txs height h carries under the plan: power 0 for each
+    member that leaves (by key index), then POWER for each key that
+    joins; none where the plan changes nothing at h."""
+    if not plan or h % plan["change_every_blocks"]:
+        return []
+    old = valset_members(seed, n, plan, h)
+    new = valset_members(seed, n, plan, h + 1)
+    return [VAL_TX_PREFIX + b"%s/%d" % (
+        pub_of(val_seed(seed, i)).hex().encode(), power)
+        for idxs, power in ((sorted(set(old) - set(new)), 0),
+                            (sorted(set(new) - set(old)), POWER))
+        for i in idxs]
+
+
+def parse_val_tx(tx: bytes) -> tuple[bytes, int]:
+    """(public key, power) of a `val:<pubkey hex>/<power>` tx."""
+    pub, _, power = tx[len(VAL_TX_PREFIX):].partition(b"/")
+    pub = bytes.fromhex(pub.decode())
+    if len(pub) != 32 or not power.isdigit():
+        raise ValueError(f"malformed validator tx {tx[:80]!r}")
+    return pub, int(power)
 
 
 def genesis_dict(chain_id: str, vs) -> dict:
@@ -72,15 +153,22 @@ class RefKVStore:
     sha256 over its sorted length-prefixed pairs, never-written buckets
     are 32 zero bytes; the app hash is the first 20 bytes of sha256 over
     the 256 digests and the height).  It re-hashes a bucket once per
-    commit, not once per write, and shares no code with the program."""
+    commit, not once per write, and shares no code with the program.
+
+    A `val:` tx is stored as today's kvstore stores any tx without `=`
+    (key = value = the tx) and is kept besides as one of the block's
+    validator `diffs`, which `EndBlock` returns and `commit` clears."""
 
     def __init__(self):
         self.height = 0
+        self.diffs: list[tuple[bytes, int]] = []
         self._buckets = [{} for _ in range(256)]
         self._digests = [bytes(32)] * 256
         self._dirty: set[int] = set()
 
     def deliver_tx(self, tx: bytes) -> None:
+        if tx.startswith(VAL_TX_PREFIX):
+            self.diffs.append(parse_val_tx(tx))
         k, _, v = tx.partition(b"=") if b"=" in tx else (tx, b"", tx)
         b = hashlib.sha256(k).digest()[0]
         self._buckets[b][k] = v
@@ -95,13 +183,16 @@ class RefKVStore:
                          len(v).to_bytes(4, "big") + v)
             self._digests[b] = h.digest()
         self._dirty.clear()
+        self.diffs = []
         self.height += 1
         return hashlib.sha256(b"".join(self._digests) +
                               self.height.to_bytes(8, "big")).digest()[:20]
 
 
 class Signers:
-    """Signs one message with every validator key, in set order."""
+    """Signs one message with every key of the set in use, in set order.
+    It keeps every key it was ever given (the union of a churning
+    chain's sets); `use` says which of them are the set from now on."""
 
     def __init__(self, seeds: list[bytes], workers: int | None = None):
         self._n = len(seeds)
@@ -110,33 +201,45 @@ class Signers:
                        if self._n >= MIN_VALS_FOR_WORKERS else 0)
         self._procs: list[subprocess.Popen] = []
         self._keys = None
+        self._key_of: dict[bytes, Ed25519PrivateKey] = {}
         if workers <= 1:
-            self._keys = [Ed25519PrivateKey.from_private_bytes(s)
-                          for s in seeds]
+            self.use(seeds)
             return
         # contiguous slices, so the answers concatenate in set order
-        per = -(-self._n // workers)
+        self._per = -(-self._n // workers)
         root = os.path.dirname(os.path.dirname(os.path.dirname(
             os.path.abspath(__file__))))
         try:
-            for w in range(0, self._n, per):
-                p = subprocess.Popen(
+            for _ in range(0, self._n, self._per):
+                self._procs.append(subprocess.Popen(
                     [sys.executable, os.path.abspath(signer.__file__)],
-                    stdin=subprocess.PIPE, stdout=subprocess.PIPE, cwd=root)
-                self._procs.append(p)
-                signer.write_frame(p.stdin, b"".join(seeds[w:w + per]))
-            for p in self._procs:
-                if signer.read_frame(p.stdout) != b"ok":
-                    raise RuntimeError("a signing worker did not start")
+                    stdin=subprocess.PIPE, stdout=subprocess.PIPE, cwd=root))
+            self.use(seeds)
         except BaseException:
             self.close()
             raise
+
+    def use(self, seeds: list[bytes]) -> None:
+        """The set that signs from now on: as many seeds as before, in
+        set order."""
+        if len(seeds) != self._n:
+            raise ValueError(f"a set of {len(seeds)} keys where the signers "
+                             f"hold {self._n}")
+        if not self._procs:
+            self._keys = signer.keys_for(self._key_of, seeds)
+            return
+        for i, p in enumerate(self._procs):
+            signer.write_frame(p.stdin, signer.KEYS + b"".join(
+                seeds[i * self._per:(i + 1) * self._per]))
+        for p in self._procs:
+            if signer.read_frame(p.stdout) != b"ok":
+                raise RuntimeError("a signing worker did not take its keys")
 
     def sign_all(self, msg: bytes) -> list[bytes]:
         if self._keys is not None:
             return [k.sign(msg) for k in self._keys]
         for p in self._procs:
-            signer.write_frame(p.stdin, msg)
+            signer.write_frame(p.stdin, signer.SIGN + msg)
         out = b"".join(signer.read_frame(p.stdout) or b""
                        for p in self._procs)
         if len(out) != 64 * self._n:
@@ -179,13 +282,35 @@ def block_txs(block: dict, seed: int, h: int) -> list[bytes]:
             for i, x in enumerate(heads)]
 
 
+def _next_set(vs, diffs, seed: int, n: int, plan: dict | None, h: int):
+    """The set of h + 1 as `valset_at` gives it, which has to be the set
+    of h with the app's diffs of h applied, as the program will have it."""
+    seeds, new = valset_at(seed, n, plan, h + 1)
+    pubs = {v.pub_key.bytes_: v.voting_power for v in vs.validators}
+    for pub, power in diffs:
+        if power:
+            pubs[pub] = power
+        elif pubs.pop(pub, None) is None:
+            raise RuntimeError(f"height {h} removes a validator not in "
+                               "the set")
+    if pubs != {v.pub_key.bytes_: v.voting_power for v in new.validators}:
+        raise RuntimeError(f"the diffs of height {h} do not make the set "
+                           f"the plan gives height {h + 1}")
+    return seeds, new
+
+
 def build_chain(chain_id: str, seeds, vs, n_blocks: int, block_spec: dict,
                 seed: int, signers: Signers | None = None,
-                keep_objects: bool = False):
+                keep_objects: bool = False, valset: dict | None = None):
     """Heights 1..n_blocks, each block embedding the +2/3 LastCommit of
-    its predecessor.  Returns a dict of per-height lists (index h-1):
-    `encoded`, `block_hash`, `app_hash` (state after h), and with
-    `keep_objects` also `objects` = (block, part_set, seen_commit)."""
+    its predecessor.  `seeds`, `vs` are the genesis set; under a `valset`
+    plan a height's header holds the hash of ITS set, its commit is
+    signed by that set's members only, and the set moves after a height
+    whose `val:` txs the reference app returned as diffs.  Returns a
+    dict of per-height lists (index h-1): `encoded`, `block_hash`,
+    `app_hash` (state after h), with `keep_objects` also `objects` =
+    (block, part_set, seen_commit); and `valsets`, a (first height,
+    ValidatorSet) for every set that signs."""
     from tendermint_tpu.types import (TYPE_PRECOMMIT, Block, BlockID, Commit,
                                       EMPTY_COMMIT, Vote, ZERO_BLOCK_ID,
                                       canonical)
@@ -195,11 +320,13 @@ def build_chain(chain_id: str, seeds, vs, n_blocks: int, block_spec: dict,
     app = RefKVStore()
     vals_hash = vs.hash()
     addrs = [v.address for v in vs.validators]
-    out = {"encoded": [], "block_hash": [], "app_hash": [], "objects": []}
+    out = {"encoded": [], "block_hash": [], "app_hash": [], "objects": [],
+           "valsets": [(1, vs)]}
     last_commit, last_block_id, app_hash = EMPTY_COMMIT, ZERO_BLOCK_ID, b""
     try:
         for h in range(1, n_blocks + 1):
-            txs = block_txs(block_spec, seed, h)
+            txs = block_txs(block_spec, seed, h) + valset_txs(
+                seed, len(seeds), valset, h)
             block = Block.make(chain_id=chain_id, height=h,
                                time_ns=GENESIS_TIME_NS + h, txs=txs,
                                last_commit=last_commit,
@@ -218,6 +345,7 @@ def build_chain(chain_id: str, seeds, vs, n_blocks: int, block_spec: dict,
                 for i, s in enumerate(sigs)])
             for tx in txs:
                 app.deliver_tx(tx)
+            diffs = app.diffs             # EndBlock's, before the commit
             app_hash = app.commit()
             out["encoded"].append(enc)
             out["block_hash"].append(bid.hash)
@@ -225,6 +353,12 @@ def build_chain(chain_id: str, seeds, vs, n_blocks: int, block_spec: dict,
             if keep_objects:
                 out["objects"].append((block, ps, seen))
             last_commit, last_block_id = seen, bid
+            if diffs:
+                seeds, vs = _next_set(vs, diffs, seed, len(seeds), valset, h)
+                signers.use(seeds)
+                vals_hash = vs.hash()
+                addrs = [v.address for v in vs.validators]
+                out["valsets"].append((h + 1, vs))
     finally:
         if own:
             signers.close()

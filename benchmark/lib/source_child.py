@@ -8,9 +8,12 @@ reports ready), so it cannot touch the chip or the node's GIL.
 
     python -m benchmark.lib.source_child <spec.json>
 
-spec: seed, chain_id, n_vals, n_blocks, n_sources, traffic, index_path.
+spec: seed, chain_id, n_vals, n_blocks, n_sources, traffic, index_path,
+and `valset` where the traffic mix states a validator-set plan.
 When every peer listens it writes the per-height index (block hashes,
-app hashes, encoded sizes) to `index_path`, prints one JSON line
+app hashes, encoded sizes; and `valsets`: for each set that signs, its
+first height, its hash and its public keys in set order) to
+`index_path`, prints one JSON line
 {"ready": ..., "genesis": ..., "addrs": [...]} and serves until its
 stdin closes.
 """
@@ -74,15 +77,21 @@ def main(argv) -> int:
     with open(argv[1]) as f:
         spec = json.load(f)
     from benchmark.lib import chain
-    seeds, vs = chain.make_validators(spec["seed"], spec["n_vals"])
+    plan = spec.get("valset")
+    seeds, vs = chain.valset_at(spec["seed"], spec["n_vals"], plan, 1)
     built = chain.build_chain(spec["chain_id"], seeds, vs, spec["n_blocks"],
-                              spec["traffic"], spec["seed"])
+                              spec["traffic"], spec["seed"], valset=plan)
     t_built = time.monotonic()
     gen = chain.genesis_dict(spec["chain_id"], vs)
     with open(spec["index_path"], "w") as f:
         json.dump({"block_hash": [b.hex() for b in built["block_hash"]],
                    "app_hash": [b.hex() for b in built["app_hash"]],
-                   "size": [len(e) for e in built["encoded"]]}, f)
+                   "size": [len(e) for e in built["encoded"]],
+                   "valsets": [
+                       {"from_height": h, "hash": s.hash().hex(),
+                        "validators": [v.pub_key.bytes_.hex()
+                                       for v in s.validators]}
+                       for h, s in built["valsets"]]}, f)
     store = ServedStore(built["encoded"])
     switches = start_sources(spec["chain_id"], chain.genesis_doc(gen), store,
                              spec["n_sources"])

@@ -13,7 +13,9 @@ if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
 # what run.py does, minus its look for a chip: the real cell's files with
-# the sizes cut to what a test can hold.  `hold_trace_s` makes the
+# the sizes cut to what a test can hold.  `config` and `traffic` are laid
+# over the cell's files, and `prelude` is run before the cell is loaded (a
+# test's own app registers itself there).  `hold_trace_s` makes the
 # profiler's stop_trace() as slow as a real cell's: it returns only when
 # the sync thread has ended (stopped by the harness, or at the served tip)
 # and no sooner than that many seconds
@@ -23,10 +25,13 @@ T = time.monotonic()
 import json, os, sys
 os.environ.setdefault("TM_FLIGHT_RECORDER_CAP", "4194304")
 sys.path.insert(0, {root!r})
+{prelude}
 from benchmark.lib import cell as cm
 cell = cm.load_cell({root!r}, "testnet-4v.empty-blocks")
-cell["config"] = dict(cell["config"], validators=4, source_peers=2)
-cell["traffic"] = dict(cell["traffic"], chain={{"default": {chain!r}}})
+cell["config"] = dict(cell["config"], validators=4, source_peers=2,
+                      **{config!r})
+cell["traffic"] = dict(cell["traffic"], **{traffic!r},
+                       chain={{"default": {chain!r}}})
 cm.TRACE_MAX_S = {trace_max_s}
 if {hold_trace_s}:
     import jax.profiler
@@ -68,14 +73,17 @@ def cpu_env(**extra) -> dict:
 
 def run_rehearsal(seed: int, seconds: float = 6.0, trace: bool = True,
                   fault=None, chain=CHAIN, trace_max_s: float = 20.0,
-                  hold_trace_s: float = 0.0) -> subprocess.CompletedProcess:
+                  hold_trace_s: float = 0.0, config=None, traffic=None,
+                  prelude: str = "",
+                  timeout: float = 900) -> subprocess.CompletedProcess:
     code = REHEARSAL.format(root=REPO, seed=seed, seconds=seconds,
                             trace=trace, fault=fault, chain=chain,
                             trace_max_s=trace_max_s,
-                            hold_trace_s=hold_trace_s)
+                            hold_trace_s=hold_trace_s, config=config or {},
+                            traffic=traffic or {}, prelude=prelude)
     return subprocess.run([sys.executable, "-c", code], cwd=REPO,
                           env=cpu_env(), capture_output=True, text=True,
-                          timeout=900)
+                          timeout=timeout)
 
 
 def rehearse(seed: int, **kw):
