@@ -14,8 +14,9 @@ import json
 import os
 
 from tendermint_tpu.abci.app import Application, register_app
-from tendermint_tpu.abci.types import (OK, ResponseInfo,
-                                       ResponseQuery, Result)
+from tendermint_tpu.abci.types import (ERR_ENCODING, OK, ResponseEndBlock,
+                                       ResponseInfo, ResponseQuery, Result,
+                                       Validator)
 
 
 N_BUCKETS = 256
@@ -74,7 +75,6 @@ class KVStoreApp(Application):
         return Result(OK)
 
     def end_block(self, height: int):
-        from tendermint_tpu.abci.types import ResponseEndBlock
         return ResponseEndBlock()
 
     def commit(self) -> Result:
@@ -175,7 +175,48 @@ class PersistentKVStoreApp(KVStoreApp):
         os.replace(tmp, self.db_path)
 
 
+VAL_TX_PREFIX = b"val:"
+_HEX_DIGITS = frozenset(b"0123456789abcdefABCDEF")
+
+
+class ValsetKVStoreApp(KVStoreApp):
+    """A kvstore whose validator set the chain's own txs change
+    (reference abci `example/dummy/persistent_dummy.go`): a tx
+    `val:<pubkey hex>/<decimal power>` is a validator diff, returned by
+    the `EndBlock` of the block that carries it (power 0 removes).
+
+    The pubkey is the raw 32 bytes of the ed25519 key (the reference's
+    go-wire form carries a type byte more), and the tx is STORED as the
+    kvstore stores any tx without `=`, key = value = the tx, where the
+    reference keeps the validator under `val:` + pubkey and deletes it
+    at power 0: a chain's app hashes are then the plain kvstore's over
+    the same txs.  A malformed `val:` tx is refused with a result code,
+    stores nothing and changes no set."""
+
+    def __init__(self):
+        super().__init__()
+        self._diffs: list[Validator] = []
+
+    def deliver_tx(self, tx: bytes) -> Result:
+        if tx.startswith(VAL_TX_PREFIX):
+            pub, sep, power = tx[len(VAL_TX_PREFIX):].partition(b"/")
+            # 64 hex digits and a plain decimal, nothing else: `int()` and
+            # `fromhex` alone would take signs, spaces and underscores
+            if not (sep and len(pub) == 64 and power.isdigit() and
+                    _HEX_DIGITS.issuperset(pub)):
+                return Result(ERR_ENCODING,
+                              log="expected val:<64 hex>/<decimal power>")
+            self._diffs.append(Validator(bytes.fromhex(pub.decode()),
+                                         int(power)))
+        return super().deliver_tx(tx)
+
+    def end_block(self, height: int) -> ResponseEndBlock:
+        diffs, self._diffs = self._diffs, []
+        return ResponseEndBlock(diffs=diffs)
+
+
 register_app("kvstore", KVStoreApp)
+register_app("valset_kvstore", ValsetKVStoreApp)
 register_app("dummy", KVStoreApp)
 register_app("persistent_kvstore", PersistentKVStoreApp)
 register_app("persistent_dummy", PersistentKVStoreApp)

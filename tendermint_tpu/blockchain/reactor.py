@@ -112,6 +112,10 @@ class BlockchainReactor(Reactor):
         self._stopped = threading.Event()
         self._thread: threading.Thread | None = None
         self._switched = False
+        # True once `on_caught_up` has returned: consensus holds the state
+        # from then on, and until then this reactor's is the applied one
+        # (`Node.state`)
+        self.handed_over = False
         self._lookahead: _Lookahead | None = None
         self.lookahead_hits = 0     # speculative windows actually consumed
 
@@ -237,6 +241,7 @@ class BlockchainReactor(Reactor):
                          height=self.state.last_block_height)
                 if self.on_caught_up is not None:
                     self.on_caught_up(self.state)
+                self.handed_over = True
                 return
             if not progressed:
                 time.sleep(SYNC_TICK)
@@ -267,6 +272,15 @@ class BlockchainReactor(Reactor):
         tick against the updated state (reference verifies per block:
         `blockchain/reactor.go:230-231`).  Returns (window, parts_list,
         items); an empty window means the very next block mismatches.
+
+        A cut window has any size from 1 to 63, wherever the chain puts
+        its change.  It costs one verify call of a full window: the
+        backend pads its lanes into the smallest program it has already
+        compiled (`TpuBackend._warm_shape`), never a compile of the odd
+        size's own bucket.  What a change does cost is the next set's
+        comb table, built in line by the first verify call that meets
+        the set, and the look-ahead of the window after the cut, which
+        was prepared against the old set and is dropped.
         """
         window = blocks[:-1]              # each needs its successor's
         cut = len(window)                 # LastCommit as its +2/3 proof
@@ -274,6 +288,9 @@ class BlockchainReactor(Reactor):
             if b.header.validators_hash != vals_hash:
                 cut = i
                 break
+        if 0 < cut < len(window):
+            tracing.instant("fastsync.valset_cut", height=window[cut].height,
+                            blocks=cut)
         window = window[:cut]
         # full 64KB chunks lockstep on device, tails + trees on host —
         # proving data integrity like the reference's per-block re-hash
@@ -295,10 +312,11 @@ class BlockchainReactor(Reactor):
         the best peer's height ends inside it — the tip of the chain.
         With no peer at all the tip is unknown, so it waits (a starved
         boot evicts every peer for request timeouts at once, and they
-        redial).  Draining whatever happens to have arrived would
-        dispatch a new (lanes, templates) bucket for every odd size, and
-        each bucket is a fresh XLA compile — tens of seconds on the chip
-        — while only the full-window shape is warmed at boot."""
+        redial).  Draining whatever happens to have arrived would cost
+        a full window's verify call for every handful of blocks (an odd
+        size is padded into the warmed full-window program, see
+        `_prepare_window`), and one sqlite-bound apply and one dropped
+        look-ahead a call."""
         if len(blocks) > self.batch_size:
             return True
         best = self.pool.max_peer_height()

@@ -218,6 +218,10 @@ class TpuBackend:
         self._tables: dict[bytes, tuple] = {}
         self._tables_lock = threading.Lock()
         self._builds: dict[bytes, threading.Event] = {}  # in-flight builds
+        # (V bucket, message length) -> the (lanes, templates) buckets of
+        # the templated verify that have run to an end in this process:
+        # the programs a call can be padded into without a compile
+        self._warm_templated: dict[tuple, set] = {}
         # multi-chip: shard verify lanes over every visible device (comb
         # tables replicate; no collectives in the hot loop).  Single-chip
         # hosts skip the sharding machinery entirely.
@@ -375,9 +379,18 @@ class TpuBackend:
             ok = jax.device_put(ok, repl)
             vp_dev = jax.device_put(vp_dev, repl)
         tbl.block_until_ready()
+        dt = time.perf_counter() - t0
+        # one bare record a table, the device build (or the load from the
+        # disk cache) run to its end; bookkeeping (CAT_NONE): it nests
+        # under the verify call that first met the set
+        tracing.RECORDER.record(
+            "tables.build" if built else "tables.load",
+            tracing.perf_to_epoch(t0), dt, {"v": v, "bytes": int(tbl.size)},
+            cat=tracing.CAT_NONE)
         if built:
             # loads are ~100ms and would drag the build histogram down
-            REGISTRY.table_build_seconds.observe(time.perf_counter() - t0)
+            REGISTRY.table_build_seconds.observe(dt)
+            REGISTRY.table_builds.inc()
         if built and path is not None:
             tmp = None
             try:                         # persist for the next restart
@@ -404,8 +417,12 @@ class TpuBackend:
             while self._tables and \
                     resident + new_bytes > self.TABLE_CACHE_BYTES:
                 oldest = next(iter(self._tables))   # FIFO eviction
-                resident -= self._tables.pop(oldest)[0].size
+                gone = int(self._tables.pop(oldest)[0].size)
+                resident -= gone
+                tracing.instant("tables.evict", bytes=gone)
+                REGISTRY.table_evictions.inc()
             self._tables[set_key] = ent
+            REGISTRY.tables_resident_bytes.set(resident + new_bytes)
         return ent
 
     @classmethod
@@ -474,17 +491,49 @@ class TpuBackend:
         t.start()
         return t
 
+    def _warm_shape(self, n_vals: int, msg_len: int, lanes: int,
+                    templates: int) -> tuple[int, int] | None:
+        """The smallest (lanes, templates) bucket of the templated verify
+        that has already run here for this set size and fits the call, or
+        None.  A fast-sync window cut short by a validator-set change has
+        1 to 63 blocks, wherever the chain puts the change: its own
+        bucket would be a program nobody warmed (23 s of compile on the
+        chip at 100 validators, once a bucket), where padding it into
+        the full window's costs one call of that (~50 ms)."""
+        with self._tables_lock:
+            fits = [s for s in self._warm_templated.get(
+                        (_bucket(n_vals), msg_len), ())
+                    if s[0] >= lanes and s[1] >= templates]
+        return min(fits) if fits else None
+
     def verify_grouped_templated(self, set_key, val_pubs, val_idx,
-                                 tmpl_idx, templates, sigs):
+                                 tmpl_idx, templates, sigs,
+                                 exact_bucket: bool = False):
         """Grouped verify shipping only (sig, val_idx, tmpl_idx) lanes
         plus T message templates; messages and pubkeys assemble on
-        device (see ops.ed25519.verify_grouped_templated)."""
+        device (see ops.ed25519.verify_grouped_templated).
+
+        The call is padded, on the host, into the smallest program that
+        has already run and fits (`_warm_shape`), and compiles its own
+        power-of-two bucket only where none does or where the caller
+        asks for that (`exact_bucket`: the warm-up, which is there to
+        compile each bucket).  Padding lanes repeat lane 0 and padding
+        templates are zeros, as within a bucket: a real lane's verdict is
+        the same in every program that holds it."""
         n = len(val_idx)
         if n == 0:
             return np.zeros(0, dtype=bool)
-        warm = self._warm_verify_if_cold(
-            set_key, len(val_pubs), "templated",
-            (_bucket(n), _bucket(len(templates)), templates.shape[1]))
+        b, tb = _bucket(n), _bucket(len(templates))
+        mlen = templates.shape[1]
+        on_mesh = self._mesh_eligible(b)
+        fit = (None if on_mesh or exact_bucket
+               else self._warm_shape(len(val_pubs), mlen, b, tb))
+        if fit is not None:
+            # a program that has run: no compile to hide behind the build
+            (b, tb), warm = fit, None
+        else:
+            warm = self._warm_verify_if_cold(
+                set_key, len(val_pubs), "templated", (b, tb, mlen))
         tbl, pub_ok, v, vp_dev = self._set_tables(set_key, val_pubs)
         if warm is not None:
             warm.join()
@@ -492,8 +541,7 @@ class TpuBackend:
             raise ValueError(
                 f"set_key reused for a different set size ({v} != "
                 f"{len(val_pubs)})")
-        b = _bucket(n)
-        if self._mesh_eligible(b):
+        if on_mesh:
             # mesh path: assemble messages host-side and ride the
             # sharded kernel (templates are tiny; the win is moot there)
             return self.verify_grouped(set_key, val_pubs,
@@ -508,11 +556,9 @@ class TpuBackend:
                                        np.repeat(tmpl_idx[:1], pad)])
             sigs = np.concatenate([sigs, np.repeat(sigs[:1], pad, 0)])
         t = len(templates)
-        tb = _bucket(t)
         if tb > t:
             templates = np.concatenate(
-                [templates, np.zeros((tb - t, templates.shape[1]),
-                                     np.uint8)])
+                [templates, np.zeros((tb - t, mlen), np.uint8)])
         jnp = self._jnp
         _h2d(val_idx, tmpl_idx, templates, sigs)
         cold = _note_dispatch("verify_grouped_templated", tbl, val_idx,
@@ -530,6 +576,9 @@ class TpuBackend:
         with tracing.span("verify.collect", lanes=n, bucket=b):
             out = np.asarray(dev_out)
         _d2h(out)
+        with self._tables_lock:       # it has run to its end: warm
+            self._warm_templated.setdefault(
+                (tbl.shape[2], mlen), set()).add((b, tb))
         now = time.perf_counter()
         REGISTRY.device_step_seconds.observe(now - t1)
         REGISTRY.device_dispatch_seconds.observe(now - t0)
@@ -606,7 +655,8 @@ class TpuBackend:
             self.verify_grouped_templated(
                 set_key, val_pubs, idx,
                 (np.arange(n) % t).astype(np.int32),
-                np.zeros((t, msg_len), dtype=np.uint8), sigs)
+                np.zeros((t, msg_len), dtype=np.uint8), sigs,
+                exact_bucket=True)
 
     # below this many lanes per device the sharded dispatch overhead
     # beats the parallelism (single gossiped votes stay single-device)

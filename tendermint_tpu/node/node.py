@@ -147,9 +147,21 @@ class Node:
 
     @property
     def state(self):
-        """The LIVE state: consensus swaps in a fresh State copy on every
-        commit, so RPC must read through it rather than hold the boot-time
-        object."""
+        """The state the node has APPLIED, for the RPC and `status()`.
+        While the node fast-syncs that is the blockchain reactor's: it
+        advances a copy of its own (`node/p2p_setup.py`), which consensus
+        gets only at the hand-over, so `consensus.state` stands at the
+        boot height (and the boot validator set) all through a catch-up.
+        After the hand-over consensus swaps in a fresh State copy on
+        every commit, so readers go through here each time and hold no
+        State object.  The reactor's state is advanced IN PLACE by its
+        apply thread: a reader takes one reference of a field (`vs =
+        state.validators` is one whole set, never mutated once it is
+        installed) and does not read the field twice for one answer."""
+        bc = (self.switch.reactor("blockchain")
+              if self.switch is not None else None)
+        if bc is not None and bc.fast_sync and not bc.handed_over:
+            return bc.state
         return self.consensus.state
 
     def _maybe_precompile(self) -> None:
@@ -258,18 +270,19 @@ class Node:
         latest_height = self.block_store.height
         meta = self.block_store.load_block_meta(latest_height) \
             if latest_height else None
+        state = self.state
         return {
             "node_info": {
                 "moniker": self.config.base.moniker,
-                "network": self.state.chain_id,
+                "network": state.chain_id,
                 "version": "0.1.0",
             },
             "pub_key": (self.priv_validator.pub_key.hex()
                         if self.priv_validator else None),
             "latest_block_height": latest_height,
             "latest_block_hash": (meta.block_id.hash.hex() if meta else ""),
-            "latest_app_hash": self.state.app_hash.hex(),
-            "validator_count": self.state.validators.size(),
+            "latest_app_hash": state.app_hash.hex(),
+            "validator_count": state.validators.size(),
             "consensus": self.consensus.get_round_state_summary(),
             "metrics": metrics.snapshot(),
         } | self._crypto_status()

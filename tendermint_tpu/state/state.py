@@ -14,6 +14,7 @@ from dataclasses import dataclass, field
 from tendermint_tpu.types import BlockID, GenesisDoc, ValidatorSet, ZERO_BLOCK_ID
 from tendermint_tpu.types.codec import Reader, i64, lp_bytes, u32, u64
 from tendermint_tpu.abci.types import Result
+from tendermint_tpu.utils import tracing
 
 _STATE_KEY = b"stateKey"
 
@@ -136,6 +137,11 @@ class State:
         next_vals = self.validators.copy()
         if diffs:
             next_vals.apply_updates(diffs)
+            was, now = prev_vals._by_addr.keys(), next_vals._by_addr.keys()
+            joined, left = len(now - was), len(was - now)
+            if joined or left:        # not a change of powers alone
+                tracing.instant("state.valset_change", height=header.height,
+                                joined=joined, left=left)
         next_vals.increment_accum(1)
         self.last_block_height = header.height
         self.last_block_id = block_id

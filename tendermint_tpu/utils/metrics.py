@@ -272,6 +272,12 @@ class Registry:
         self.device_dispatch_seconds = Summary()  # dispatch->result wall
         #   (includes overlapped host work in pipelined callers)
         self.table_build_seconds = Summary()  # comb-table builds (per set)
+        # comb tables, one a validator set: built on the device (not
+        # loaded from disk), dropped by the byte-bounded FIFO, and the
+        # bytes the device holds for them now
+        self.table_builds = Counter()
+        self.table_evictions = Counter()
+        self.tables_resident_bytes = Gauge()
         # tail-aware distributions (the Summary twins above keep the
         # steering heuristics; these feed the /metrics scrape + p99s)
         self.device_step_hist = Histogram(Histogram.LATENCY_BOUNDS)

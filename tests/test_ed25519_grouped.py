@@ -10,13 +10,17 @@ lane on valid AND adversarial inputs, exactly like the generic kernel
 """
 
 import secrets
+import threading
 
 import numpy as np
 import jax.numpy as jnp
 import pytest
 
+from tendermint_tpu.crypto import backend as cb
 from tendermint_tpu.crypto import pure_ed25519 as ref
 from tendermint_tpu.ops import ed25519 as dev
+from tendermint_tpu.utils import tracing
+from tendermint_tpu.utils.metrics import REGISTRY
 
 MSG_LEN = 96
 V = 4
@@ -318,3 +322,146 @@ def test_table_disk_cache_roundtrip(tmp_path, monkeypatch):
     files[0].write_bytes(b"garbage")
     be3 = TpuBackend()
     assert be3.verify_grouped(b"disk-set", pubs, idx, msgs, sigs).all()
+
+
+# -- which program a call is padded into; what a table build records ---------
+# (the device programs stubbed: `host_kernels`)
+
+FULL = (256, 64)                 # a 4-validator window's (lanes, templates)
+
+
+def _since(t0: float, name: str) -> list[dict]:
+    return [s for s in tracing.RECORDER.since(t0)
+            if s["name"] == name and s["ts"] >= t0]
+
+
+def _signed_window(tag: int, blocks: int):
+    """`blocks` templates, each signed by 4 keys, block-major as a window
+    lays its commits out; the last lane forged."""
+    from tendermint_tpu.crypto import native
+    sign = native.sign_one if native.AVAILABLE else ref.sign
+    seeds = [bytes([tag, i + 1]) + b"\x00" * 30 for i in range(V)]
+    pubs = np.frombuffer(b"".join(ref.pubkey_from_seed(s) for s in seeds),
+                         np.uint8).reshape(V, 32)
+    templates = np.frombuffer(
+        b"".join(bytes([tag, b]) * (MSG_LEN // 2) for b in range(blocks)),
+        np.uint8).reshape(blocks, MSG_LEN)
+    idx = np.tile(np.arange(V, dtype=np.int32), blocks)
+    tmpl_idx = np.repeat(np.arange(blocks, dtype=np.int32), V)
+    sigs = [sign(seeds[v], templates[t].tobytes())
+            for v, t in zip(idx, tmpl_idx)]
+    sigs[-1] = sigs[-1][:63] + bytes([sigs[-1][63] ^ 0x01])
+    return pubs, idx, tmpl_idx, templates, np.frombuffer(
+        b"".join(sigs), np.uint8).reshape(len(sigs), 64)
+
+
+@pytest.mark.parametrize("blocks", [1, 2, 7, 8, 9, 31, 33, 63])
+def test_a_window_of_any_size_is_padded_into_the_warm_full_one(
+        host_kernels, blocks):
+    """What the boot does, then a cut window: the warm-up compiles the
+    full window's own bucket; a window of 1 to 63 blocks after it is
+    handed to the device in that shape and no other, its verdicts come
+    back trimmed and in order, and the counters move by its real lanes."""
+    be = cb.TpuBackend()
+    pubs, idx, tmpl_idx, templates, sigs = _signed_window(blocks, blocks)
+    be.precompile(b"pad-%d" % blocks, pubs, [("templated",) + FULL],
+                  MSG_LEN)
+    assert host_kernels == [(FULL[0], FULL[0], FULL[0], FULL[1])]
+    del host_kernels[:]
+    counters = (REGISTRY.sigs_requested, REGISTRY.sigs_verified,
+                REGISTRY.verify_batches)
+    before = [c.value for c in counters]
+    t0 = tracing.now_epoch()
+    out = be.verify_grouped_templated(b"pad-%d" % blocks, pubs, idx,
+                                      tmpl_idx, templates, sigs)
+    n = blocks * V
+    assert host_kernels == [(FULL[0], FULL[0], FULL[0], FULL[1])]
+    assert out.dtype == bool and out.tolist() == [True] * (n - 1) + [False]
+    assert [c.value - v for c, v in zip(counters, before)] == [n, n - 1, 1]
+    mine = threading.current_thread().ident
+    assert [(s["name"], s["args"]) for s in tracing.RECORDER.since(t0)
+            if s["ts"] >= t0 and s["tid"] == mine and
+            s["name"].startswith("verify.")] == [
+        ("verify.dispatch", {"lanes": n, "bucket": FULL[0]}),
+        ("verify.collect", {"lanes": n, "bucket": FULL[0]})]
+
+
+def test_a_call_compiles_its_own_bucket_only_where_no_warm_one_fits(
+        host_kernels):
+    be = cb.TpuBackend()
+    pubs, idx, tmpl_idx, templates, sigs = _signed_window(101, 8)
+    key = b"pad-own"
+    # nothing warm: the call's own (32, 16)
+    assert be.verify_grouped_templated(key, pubs, idx, tmpl_idx, templates,
+                                       sigs).tolist() == [True] * 31 + [False]
+    # a smaller call fits it; a wider one (17 templates) does not
+    be.verify_grouped_templated(key, pubs, idx[:4], tmpl_idx[:4],
+                                templates[:1], sigs[:4])
+    pubs2, idx2, tmpl2, templates2, sigs2 = _signed_window(102, 17)
+    be.verify_grouped_templated(key, pubs, idx2[:32], tmpl2[:32] * 2,
+                                templates2, sigs2[:32])
+    # the warm-up asks for each bucket's own program, whatever is warm
+    be.verify_grouped_templated(key, pubs, idx[:4], tmpl_idx[:4],
+                                templates[:1], sigs[:4], exact_bucket=True)
+    # and the smallest warm fit is taken, not the first or the largest
+    be.verify_grouped_templated(key, pubs, idx[:4], tmpl_idx[:4],
+                                templates[:1], sigs[:4])
+    assert host_kernels == [(32, 32, 32, 16), (32, 32, 32, 16),
+                            (32, 32, 32, 32), (16, 16, 16, 16),
+                            (16, 16, 16, 16)]
+    # another set size or message length shares no program with these
+    assert be._warm_shape(V, MSG_LEN + 1, 16, 16) is None
+    assert be._warm_shape(17, MSG_LEN, 16, 16) is None
+    assert be._warm_shape(V, MSG_LEN, 16, 16) == (16, 16)
+    assert be._warm_shape(V, MSG_LEN, 32, 17) == (32, 32)
+    assert be._warm_shape(V, MSG_LEN, 33, 16) is None
+
+
+def test_tables_are_built_once_a_set_and_the_fifo_drops_the_oldest(
+        host_kernels, monkeypatch):
+    """(d) `tables.build` once a set reached, `tables.evict` and the
+    resident bytes with the cache bounded at two tables; a table the
+    disk cache holds is `tables.load`, and counts as no build."""
+    one = 26 * 1024 * 16 * 96            # V bucket 16, uint8
+    monkeypatch.setattr(cb.TpuBackend, "TABLE_CACHE_BYTES", 2 * one)
+    be = cb.TpuBackend()
+    builds0, evicted0 = (REGISTRY.table_builds.value,
+                         REGISTRY.table_evictions.value)
+    t0 = tracing.now_epoch()
+    sets = [_signed_window(110 + i, 1) for i in range(3)]
+
+    def call(i):
+        pubs, idx, tmpl_idx, templates, sigs = sets[i]
+        be.verify_grouped_templated(b"set-%d" % i, pubs, idx, tmpl_idx,
+                                    templates, sigs)
+
+    call(0)
+    call(0)                               # resident: no second build
+    assert len(_since(t0, "tables.build")) == 1
+    assert REGISTRY.tables_resident_bytes.value == one
+    call(1)
+    assert REGISTRY.tables_resident_bytes.value == 2 * one
+    assert not _since(t0, "tables.evict")
+    call(2)                               # the third drops the first
+    builds = _since(t0, "tables.build")
+    assert [b["args"] for b in builds] == [{"v": V, "bytes": one}] * 3
+    assert all(b["dur"] > 0 and "cat" not in b for b in builds)
+    assert [e["args"] for e in _since(t0, "tables.evict")] == [
+        {"bytes": one}]
+    assert REGISTRY.tables_resident_bytes.value == 2 * one
+    assert list(be._tables) == [b"set-1", b"set-2"]
+    assert REGISTRY.table_builds.value - builds0 == 3
+    assert REGISTRY.table_evictions.value - evicted0 == 1
+    # the dropped set comes back from the disk cache the first build
+    # wrote (conftest gives every test a directory of its own)
+    call(0)
+    assert len(_since(t0, "tables.build")) == 3
+    assert [b["args"] for b in _since(t0, "tables.load")] == [
+        {"v": V, "bytes": one}]
+    assert REGISTRY.table_builds.value - builds0 == 3
+    assert REGISTRY.table_evictions.value - evicted0 == 2
+    from tendermint_tpu.utils import metrics
+    text = metrics.prometheus_text()
+    assert f"tendermint_tables_resident_bytes {2 * one}" in text
+    assert "# TYPE tendermint_table_builds counter" in text
+    assert "# TYPE tendermint_table_evictions counter" in text

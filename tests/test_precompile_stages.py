@@ -84,19 +84,23 @@ def test_precompile_calls_each_programs_own_entry_point():
     fake = types.SimpleNamespace(
         verify_grouped=lambda key, pubs, idx, msgs, sigs: calls.append(
             ("plain", len(idx), msgs.shape)),
-        verify_grouped_templated=lambda key, pubs, idx, tidx, tmpl, sigs:
-        calls.append(("templated", len(idx), tmpl.shape, int(tidx.max()))))
+        verify_grouped_templated=lambda key, pubs, idx, tidx, tmpl, sigs,
+        exact_bucket=False: calls.append(
+            ("templated", len(idx), tmpl.shape, int(tidx.max()),
+             exact_bucket)))
     cb.TpuBackend.precompile(
         fake, b"k", np.zeros((4, 32), np.uint8),
         [("templated", 256, 64), ("plain", 16, 1)], 110)
-    assert calls == [("templated", 256, (64, 110), 63),
+    # a warm-up compiles each program's OWN bucket: a call that may be
+    # padded into a bigger warm one would compile nothing
+    assert calls == [("templated", 256, (64, 110), 63, True),
                      ("plain", 16, (16, 110))]
 
 
 def test_a_stopped_warm_up_ends_before_its_next_program():
     stop, calls = threading.Event(), []
 
-    def first_then_stop(*args):
+    def first_then_stop(*args, **_kw):
         calls.append(len(args[2]))
         stop.set()
 
