@@ -123,6 +123,27 @@ def _d2h(out) -> None:
         REGISTRY.d2h_bytes.inc(int(nb))
 
 
+def _loaded(fn, *args):
+    """`fn`'s executable for these arguments (arrays, or their
+    `ShapeDtypeStruct`s), traced and compiled or read from the compile
+    cache WITHOUT a run; a later `fn(...)` of the same shapes finds
+    trace, lowering and executable in jit's caches and only dispatches.
+    A stand-in without `lower` (the tests' stubs) is returned as it is."""
+    lower = getattr(fn, "lower", None)
+    return fn if lower is None else lower(*args).compile()
+
+
+def _count_call(n: int, b: int, out) -> None:
+    """One device verify: `n` lanes asked for, in the program of `b`
+    lanes it rode (its own bucket, or the warm one it was padded into)."""
+    REGISTRY.sigs_requested.inc(n)
+    REGISTRY.sigs_verified.inc(int(out[:n].sum()))
+    REGISTRY.verify_batches.inc()
+    REGISTRY.verify_lanes_padded.inc(b)
+    REGISTRY.batch_occupancy.observe(n / b)
+    REGISTRY.batch_occupancy_hist.observe(n / b)
+
+
 class Backend(Protocol):
     name: str
 
@@ -280,11 +301,7 @@ class TpuBackend:
         REGISTRY.device_step_seconds.observe(dt)
         REGISTRY.device_dispatch_seconds.observe(dt)
         REGISTRY.device_step_hist.observe(dt)
-        REGISTRY.sigs_requested.inc(n)
-        REGISTRY.sigs_verified.inc(int(out[:n].sum()))
-        REGISTRY.verify_batches.inc()
-        REGISTRY.batch_occupancy.observe(n / b)
-        REGISTRY.batch_occupancy_hist.observe(n / b)
+        _count_call(n, b, out)
         return out[:n]
 
     def _set_tables(self, set_key: bytes, val_pubs: np.ndarray) -> tuple:
@@ -364,10 +381,20 @@ class TpuBackend:
             except Exception:
                 tbl = ok = None          # corrupt cache file: rebuild
         vp_dev = self._jnp.asarray(val_pubs)   # one upload serves both the
-        built = tbl is None
+        built = tbl is None                    # build + lane pubkey gathers
         if built:
-            tbl, ok = self._dev.build_neg_comb_jit(vp_dev)  # build + lane
-        if self._mesh is not None:             # pubkey gathers
+            # the program first (traced, then compiled or read from the
+            # compile cache: seconds the first time a process meets a V
+            # bucket, nothing after), under a record of its own, so that
+            # `tables.build` times the device's work alone
+            build = _loaded(self._dev.build_neg_comb_jit, vp_dev)
+            t1 = time.perf_counter()
+            tracing.RECORDER.record(
+                "tables.build.load", tracing.perf_to_epoch(t0), t1 - t0,
+                {"v": v}, cat=tracing.CAT_NONE)
+            t0 = t1
+            tbl, ok = build(vp_dev)
+        if self._mesh is not None:
             # commit the tables replicated across the mesh at build time:
             # the sharded verify takes them as arguments (one jitted fn
             # per SHAPE, not per set), so evicting the table entry also
@@ -450,44 +477,40 @@ class TpuBackend:
     def _warm_verify_if_cold(self, set_key: bytes, n_vals: int,
                              kind: str, shape: tuple):
         """Overlap the verify executable's XLA compile with the comb-table
-        build on a COLD set: the compile needs only shapes, so a dummy
-        call with zero tables runs on a thread of THIS process (one
-        process owns the chip) while `_set_tables` pays the build's
-        compile and run.  Returns the thread (caller joins after tables
-        are ready), or None when the set is already cached."""
+        build on a COLD set: the compile needs only shapes, so a thread
+        of THIS process (one process owns the chip) traces the program
+        and compiles it, or reads it from the compile cache, while
+        `_set_tables` pays the build's compile and run.  Nothing runs and
+        nothing is allocated on the device (a dummy run would need a
+        table of zeros beside the one being built, 1.25 GiB at V bucket
+        512): the call that follows finds the executable in jit's own
+        cache.  Returns the thread (caller joins after tables are
+        ready), or None when the set is already cached."""
         if self._mesh is not None:
             return None     # mesh path compiles per-shape sharded fns
         with self._tables_lock:
             if set_key in self._tables:
                 return None
-        jnp = self._jnp
-        vb = _bucket(n_vals)
+        import jax
         from tendermint_tpu.ops.curve import COMB_DIGITS, COMB_WINDOWS
-
-        def warm():
-            ztbl = jnp.zeros((COMB_WINDOWS, COMB_DIGITS, vb, 3, 32),
-                             jnp.uint8)
-            zok = jnp.zeros((vb,), bool)
-            if kind == "templated":
-                b, tb, mlen = shape
-                out = self._dev.verify_grouped_templated_jit(
-                    ztbl, zok, jnp.zeros((vb, 32), jnp.uint8),
-                    jnp.zeros((b,), jnp.int32),
-                    jnp.zeros((b,), jnp.int32),
-                    jnp.zeros((tb, mlen), jnp.uint8),
-                    jnp.zeros((b, 64), jnp.uint8), self._base_tbl)
-            else:
-                b, mlen = shape
-                # pubkeys here are PER-LANE (challenge-hash input),
-                # so the warm shape is the lane bucket, not vb
-                out = self._dev.verify_grouped_jit(
-                    ztbl, zok, jnp.zeros((b,), jnp.int32),
-                    jnp.zeros((b, 32), jnp.uint8),
-                    jnp.zeros((b, mlen), jnp.uint8),
-                    jnp.zeros((b, 64), jnp.uint8), self._base_tbl)
-            out.block_until_ready()
-
-        t = threading.Thread(target=warm, daemon=True)
+        u8, i32, S = np.uint8, np.int32, jax.ShapeDtypeStruct
+        vb = _bucket(n_vals)
+        tables = (S((COMB_WINDOWS, COMB_DIGITS, vb, 3, 32), u8),
+                  S((vb,), np.bool_))
+        base = S(self._base_tbl.shape, self._base_tbl.dtype)
+        if kind == "templated":
+            b, tb, mlen = shape
+            fn = self._dev.verify_grouped_templated_jit
+            specs = tables + (S((vb, 32), u8), S((b,), i32), S((b,), i32),
+                              S((tb, mlen), u8), S((b, 64), u8), base)
+        else:
+            b, mlen = shape
+            # pubkeys here are PER-LANE (challenge-hash input),
+            # so the warm shape is the lane bucket, not vb
+            fn = self._dev.verify_grouped_jit
+            specs = tables + (S((b,), i32), S((b, 32), u8),
+                              S((b, mlen), u8), S((b, 64), u8), base)
+        t = threading.Thread(target=_loaded, args=(fn,) + specs, daemon=True)
         t.start()
         return t
 
@@ -583,11 +606,7 @@ class TpuBackend:
         REGISTRY.device_step_seconds.observe(now - t1)
         REGISTRY.device_dispatch_seconds.observe(now - t0)
         REGISTRY.device_step_hist.observe(now - t1)
-        REGISTRY.sigs_requested.inc(n)
-        REGISTRY.sigs_verified.inc(int(out[:n].sum()))
-        REGISTRY.verify_batches.inc()
-        REGISTRY.batch_occupancy.observe(n / b)
-        REGISTRY.batch_occupancy_hist.observe(n / b)
+        _count_call(n, b, out)
         return out[:n]
 
     def precompile_for_validators(self, vals, stage: str = "all",
@@ -730,11 +749,7 @@ class TpuBackend:
         REGISTRY.device_step_seconds.observe(dt)      # sync: step ==
         REGISTRY.device_dispatch_seconds.observe(dt)  # dispatch interval
         REGISTRY.device_step_hist.observe(dt)
-        REGISTRY.sigs_requested.inc(n)
-        REGISTRY.sigs_verified.inc(int(out[:n].sum()))
-        REGISTRY.verify_batches.inc()
-        REGISTRY.batch_occupancy.observe(n / b)
-        REGISTRY.batch_occupancy_hist.observe(n / b)
+        _count_call(n, b, out)
         return out[:n]
 
 

@@ -216,8 +216,8 @@ COMB_WINDOWS = -(-256 // COMB_WBITS)  # 26 windows cover 256 bits
 COMB_DIGITS = 1 << COMB_WBITS
 
 
-def _comb_row0(Q) -> tuple:
-    """Window-0 digit rows j*Q for j in [0, 1024): a 256-step add scan
+def _comb_row(Q) -> tuple:
+    """One window's digit rows j*Q for j in [0, 1024): a 256-step add scan
     builds digits < 256, then three WIDE adds of 256Q/512Q/768Q extend to
     1024 (not a 1024-step scan).  Coords [1024, ..., V, 32] per coord."""
     def add_step(acc, _):
@@ -256,19 +256,27 @@ def build_affine_comb(Q) -> tuple:
     only the uint8 output and one extended row ever live on device — a
     two-phase build materializes all 26 windows in int32 extended
     coordinates (~1.7 GB at V=128) plus inversion temporaries, which
-    OOMs a 16 GB chip.  Sequential depth ~530 point ops; fast-sync then
-    amortizes the build over thousands of commits against the same set.
+    OOMs a 16 GB chip.
+
+    What a window carries to the next is its BASE POINT 2^(10w) * Q_v
+    (V points), not its row: each row is built from its base by adds
+    (`_comb_row`: one add an entry), where shifting a whole row up a
+    window is ten doublings an entry and most of a build (on a v5e
+    5.53 s at V bucket 128 and 30.6 at 512, against 1.46 and 5.75;
+    PERF.md §6, PR 39).  Canonical affine bytes do not depend on which
+    projective form reached them, so either way gives the same table
+    byte for byte.  Fast-sync then amortizes the build over thousands
+    of commits against the same set.
     """
-    def window_step(row, _):
-        packed, ok = _affine_pack(row)
-        # x1024 = shift one window up; fori keeps ONE doubling body in
-        # the graph (10 inline copies of the 12-mul dbl are ten times
+    def window_step(base, _):
+        packed, ok = _affine_pack(_comb_row(base))
+        # x1024 = the next window's base; fori keeps ONE doubling body
+        # in the graph (10 inline copies of the 12-mul dbl are ten times
         # its share of the build's XLA compile)
-        nxt = lax.fori_loop(0, COMB_WBITS, lambda _, p: pt_dbl(p), row)
+        nxt = lax.fori_loop(0, COMB_WBITS, lambda _, p: pt_dbl(p), base)
         return nxt, (packed, ok)
 
-    _, (tbl, oks) = lax.scan(window_step, _comb_row0(Q), None,
-                             length=COMB_WINDOWS)
+    _, (tbl, oks) = lax.scan(window_step, Q, None, length=COMB_WINDOWS)
     return tbl, jnp.all(oks, axis=(0, 1))
 
 

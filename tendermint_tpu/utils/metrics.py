@@ -278,6 +278,10 @@ class Registry:
         self.table_builds = Counter()
         self.table_evictions = Counter()
         self.tables_resident_bytes = Gauge()
+        # lanes of the programs the device verifies rode (a call's
+        # power-of-two bucket, or the warm one it was padded into); less
+        # `sigs_requested` it is what the bucketing costs the device
+        self.verify_lanes_padded = Counter()
         # tail-aware distributions (the Summary twins above keep the
         # steering heuristics; these feed the /metrics scrape + p99s)
         self.device_step_hist = Histogram(Histogram.LATENCY_BOUNDS)

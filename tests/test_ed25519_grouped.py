@@ -86,6 +86,27 @@ def test_adversarial_lanes_match_golden(valset):
                             False, False, True, True]
 
 
+@pytest.mark.parametrize("w", [0, 1, 2, 13, 25])
+def test_table_entries_are_the_references_multiples(valset, w):
+    """Entry [w, j, v] is (y+x, y-x, 2d*x*y) of j * 2^(10w) * (-A_v) in
+    canonical bytes, computed here by the bigint reference: the digits a
+    row's add chain builds (0, 1, 2, 255), the first of each wide add
+    (256, 512, 768) and its last (511, 1023), in the first windows, a
+    middle one and the top one, whose base is 250 doublings from A."""
+    _, pubs, _, tbl, _ = valset
+    for v in (0, V - 1):
+        neg_a = ref.pt_neg(ref.pt_decode(pubs[v]))
+        for j in (0, 1, 2, 255, 256, 511, 512, 768, 1023):
+            x, y, z, _ = ref.pt_mul(j << (10 * w), neg_a)
+            zi = pow(z, ref.P - 2, ref.P)
+            x, y = x * zi % ref.P, y * zi % ref.P
+            want = [(y + x) % ref.P, (y - x) % ref.P,
+                    2 * ref.D * x * y % ref.P]
+            got = [int.from_bytes(bytes(np.asarray(tbl[w, j, v, k])),
+                                  "little") for k in range(3)]
+            assert got == want, (w, j, v)
+
+
 def test_invalid_pubkey_in_set():
     """A non-decodable key in the set poisons only its own lanes."""
     seeds = [secrets.token_bytes(32) for _ in range(V)]
