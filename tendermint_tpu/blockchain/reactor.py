@@ -21,6 +21,7 @@ import time
 
 from tendermint_tpu.blockchain import messages as BM
 from tendermint_tpu.blockchain.pool import BlockPool
+from tendermint_tpu.crypto import backend as crypto_backend
 from tendermint_tpu.p2p.peer import Peer, Reactor
 from tendermint_tpu.p2p.types import ChannelDescriptor
 from tendermint_tpu.state import execution
@@ -291,6 +292,9 @@ class BlockchainReactor(Reactor):
         if 0 < cut < len(window):
             tracing.instant("fastsync.valset_cut", height=window[cut].height,
                             blocks=cut)
+            # the next set's table will be derived from this one's: the
+            # programs for that load while this window is applied
+            crypto_backend.valset_change_ahead(blocks[cut].last_commit.size())
         window = window[:cut]
         # full 64KB chunks lockstep on device, tails + trees on host —
         # proving data integrity like the reference's per-block re-hash

@@ -235,10 +235,16 @@ class TpuBackend:
         # 8.6 MB literal adds ~5s of XLA compile per executable
         from tendermint_tpu.ops import curve as _curve
         self._base_tbl = jnp.asarray(_curve._base_table())
-        # set_key -> (tbl, ok, V, staged key matrix)
+        # set_key -> (tbl, ok, V, staged key matrix, its padded host copy)
         self._tables: dict[bytes, tuple] = {}
         self._tables_lock = threading.Lock()
         self._builds: dict[bytes, threading.Event] = {}  # in-flight builds
+        # (V bucket, joined bucket) -> the two executables that derive a
+        # table from a resident one (`_derive_tables`), loaded under the
+        # lock; None while `warm_derive`'s thread is on its way to them.
+        # Empty, and nothing loaded, until a set changes
+        self._derive_programs: dict[tuple, tuple | None] = {}
+        self._derive_lock = threading.Lock()
         # (V bucket, message length) -> the (lanes, templates) buckets of
         # the templated verify that have run to an end in this process:
         # the programs a call can be padded into without a compile
@@ -382,7 +388,10 @@ class TpuBackend:
                 tbl = ok = None          # corrupt cache file: rebuild
         vp_dev = self._jnp.asarray(val_pubs)   # one upload serves both the
         built = tbl is None                    # build + lane pubkey gathers
-        if built:
+        near = self._predecessor(val_pubs) if built else None
+        if near is not None:
+            t0, tbl, ok = self._derive_tables(v, *near)
+        elif built:
             # the program first (traced, then compiled or read from the
             # compile cache: seconds the first time a process meets a V
             # bucket, nothing after), under a record of its own, so that
@@ -407,9 +416,17 @@ class TpuBackend:
             vp_dev = jax.device_put(vp_dev, repl)
         tbl.block_until_ready()
         dt = time.perf_counter() - t0
-        # one bare record a table, the device build (or the load from the
-        # disk cache) run to its end; bookkeeping (CAT_NONE): it nests
-        # under the verify call that first met the set
+        if near is not None:
+            # one bare record a derived table, inside its set's
+            # `tables.build`, which stays the cost of a set
+            tracing.RECORDER.record(
+                "tables.derive", tracing.perf_to_epoch(t0), dt,
+                {"v": v, "joined": len(near[1]), "bytes": int(tbl.size)},
+                cat=tracing.CAT_NONE)
+            REGISTRY.table_derives.inc()
+        # one bare record a table, the device build (whole or derived; or
+        # the load from the disk cache) run to its end; bookkeeping
+        # (CAT_NONE): it nests under the verify call that first met the set
         tracing.RECORDER.record(
             "tables.build" if built else "tables.load",
             tracing.perf_to_epoch(t0), dt, {"v": v, "bytes": int(tbl.size)},
@@ -437,7 +454,10 @@ class TpuBackend:
                         os.unlink(tmp)   # the pruner's *.npz scope
                     except OSError:      # forever
                         pass
-        ent = (tbl, ok, v, vp_dev)
+        # the host copy is what a later set's keys are matched against
+        # (`_predecessor`): its own, so that no caller's array is trusted
+        # to stay as it was
+        ent = (tbl, ok, v, vp_dev, np.array(val_pubs))
         with self._tables_lock:
             new_bytes = tbl.size                    # uint8: size == bytes
             resident = sum(e[0].size for e in self._tables.values())
@@ -451,6 +471,119 @@ class TpuBackend:
             self._tables[set_key] = ent
             REGISTRY.tables_resident_bytes.set(resident + new_bytes)
         return ent
+
+    def _predecessor(self, val_pubs: np.ndarray) -> tuple | None:
+        """The resident table that the table of `val_pubs` (a set's key
+        matrix, padded to its V bucket) can be assembled from: of the
+        same V bucket, the one that holds the most of these keys, where
+        the keys it lacks fit one small build.  Returns (its entry, the
+        keys that joined [k, 32], for each column of the new table the
+        column of [that table | the joined keys' table] it is), or None:
+        the whole build.
+
+        Keys are matched by their bytes, never by position: a set is
+        ordered by address, so one swap moves every column between the
+        old and the new position by one, and the padding columns repeat
+        the first member, so they change when it does.  What decides is
+        what the call was given (these keys, the tables resident now);
+        `k` = every key is the whole build."""
+        if self._mesh is not None:
+            return None     # the replicated table's build is the whole one
+        want = [row.tobytes() for row in val_pubs]
+        with self._tables_lock:
+            resident = [e for e in self._tables.values()
+                        if len(e[4]) == len(want)]
+        held, best = 0, None
+        for ent in resident:
+            at = {}
+            for j, row in enumerate(ent[4]):
+                at.setdefault(row.tobytes(), j)
+            n = sum(key in at for key in want)
+            if n and n >= held:                  # of equals, the newest
+                held, best = n, (ent, at)
+        if best is None:
+            return None
+        ent, at = best
+        joined = list(dict.fromkeys(key for key in want if key not in at))
+        if len(joined) > MIN_BUCKET:
+            return None
+        for i, key in enumerate(joined):
+            at[key] = len(want) + i
+        return (ent,
+                np.frombuffer(b"".join(joined), np.uint8).reshape(-1, 32),
+                np.array([at[key] for key in want], np.int32))
+
+    def _derive_programs_for(self, vb: int, kb: int, v: int) -> tuple:
+        """The build of `kb` columns (`build_neg_comb_jit`, the program a
+        set of up to 16 is built by; None where no key joined) and the
+        gather over [a table of `vb` columns | those], as executables:
+        traced and compiled, or read from the compile cache, by shapes
+        (`_loaded`: nothing runs, nothing is allocated), once a process
+        and under a record of its own, by whichever comes first: the
+        thread `warm_derive` started, or the derive that needs them."""
+        with self._derive_lock:
+            programs = self._derive_programs.get((vb, kb))
+            if programs is not None:
+                return programs
+            import jax
+            from tendermint_tpu.ops.curve import COMB_DIGITS, COMB_WINDOWS
+            u8, S = np.uint8, jax.ShapeDtypeStruct
+            t0 = time.perf_counter()
+            widths = (vb, kb) if kb else (vb,)
+            with _cold_section():
+                build = (_loaded(self._dev.build_neg_comb_jit,
+                                 S((kb, 32), u8)) if kb else None)
+                gather = _loaded(
+                    self._dev.comb_columns_jit,
+                    tuple(S((COMB_WINDOWS, COMB_DIGITS, w, 3, 32), u8)
+                          for w in widths),
+                    tuple(S((w,), np.bool_) for w in widths),
+                    S((vb,), np.int32))
+            tracing.RECORDER.record(
+                "tables.derive.load", tracing.perf_to_epoch(t0),
+                time.perf_counter() - t0, {"v": v, "joined": kb},
+                cat=tracing.CAT_NONE)
+            programs = self._derive_programs[(vb, kb)] = (build, gather)
+        return programs
+
+    def warm_derive(self, n_vals: int) -> None:
+        """A change of the validator set has been SIGHTED ahead of the
+        height the node has applied (the fast-sync window cut at it,
+        `BlockchainReactor._prepare_window`): load the derive's two
+        programs now, on a thread named `crypto-precompile` as the
+        node's own warm-ups are, so that the set's first verify call
+        finds them, and whoever waits for the warm-ups by that name
+        (`chip_smoke.py`, the benchmark before it opens its window)
+        waits for this one too.  Once a V bucket; before a node's first
+        set change nothing of the derive exists, this thread included."""
+        key = (_bucket(n_vals), MIN_BUCKET)
+        with self._tables_lock:
+            if self._mesh is not None or key in self._derive_programs:
+                return
+            self._derive_programs[key] = None
+        threading.Thread(target=self._derive_programs_for,
+                         args=key + (n_vals,), daemon=True,
+                         name="crypto-precompile").start()
+
+    def _derive_tables(self, v: int, near: tuple, joined: np.ndarray,
+                       src: np.ndarray) -> tuple:
+        """The table of a set from the resident table `near` and the
+        `joined` keys' columns, built by the program a 16-key set is
+        built by: the whole build's table byte for byte, `ok` included,
+        since a column depends on its own key alone.  `near`'s arrays
+        are arguments that are not donated: it stays resident as it is,
+        for the windows in flight and for the FIFO.  Returns (when the
+        programs were dispatched, tbl, ok)."""
+        jnp, k = self._jnp, len(joined)
+        kb = _bucket(k) if k else 0
+        build, gather = self._derive_programs_for(near[0].shape[2], kb, v)
+        t0 = time.perf_counter()
+        tbls, oks = near[:1], near[1:2]
+        if k:
+            fresh = build(jnp.asarray(np.concatenate(
+                [joined, np.repeat(joined[:1], kb - k, 0)])))
+            tbls, oks = tbls + fresh[:1], oks + fresh[1:]
+        return (t0,) + tuple(gather(tbls, oks, jnp.asarray(src)))
 
     @classmethod
     def _prune_table_cache(cls, d: str) -> None:
@@ -557,7 +690,7 @@ class TpuBackend:
         else:
             warm = self._warm_verify_if_cold(
                 set_key, len(val_pubs), "templated", (b, tb, mlen))
-        tbl, pub_ok, v, vp_dev = self._set_tables(set_key, val_pubs)
+        tbl, pub_ok, v, vp_dev, _ = self._set_tables(set_key, val_pubs)
         if warm is not None:
             warm.join()
         if v != len(val_pubs):
@@ -707,7 +840,7 @@ class TpuBackend:
             return np.zeros(0, dtype=bool)
         warm = self._warm_verify_if_cold(
             set_key, len(val_pubs), "plain", (_bucket(n), msgs.shape[-1]))
-        tbl, pub_ok, v, _ = self._set_tables(set_key, val_pubs)
+        tbl, pub_ok, v = self._set_tables(set_key, val_pubs)[:3]
         if warm is not None:
             warm.join()
         if v != len(val_pubs):       # stale key reuse would verify against
@@ -888,6 +1021,16 @@ def active_backend_name() -> str:
     be = get_backend()
     active = getattr(be, "active_rung_name", None)
     return (active() or "") if active is not None else be.name
+
+
+def valset_change_ahead(n_vals: int) -> None:
+    """A node that catches up has sighted a validator-set change ahead of
+    the height it has applied, in a set of `n_vals`: a backend that
+    derives the next set's table (`TpuBackend.warm_derive`) loads the
+    programs for it meanwhile; to any other this is nothing."""
+    fn = getattr(get_backend(), "warm_derive", None)
+    if fn is not None:
+        fn(n_vals)
 
 
 def verify_batch(pubkeys, msgs, sigs) -> np.ndarray:

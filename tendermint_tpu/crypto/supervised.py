@@ -392,6 +392,15 @@ class SupervisedBackend:
                               rung=rung.name)
             return
 
+    def warm_derive(self, n_vals: int) -> None:
+        """A sighted set change (`backend.valset_change_ahead`), handed
+        to the first rung that derives tables."""
+        for rung in self._rungs:
+            fn = getattr(rung.backend, "warm_derive", None)
+            if fn is not None:
+                fn(n_vals)
+                return
+
     # -- introspection --------------------------------------------------
     def supervisor_status(self) -> dict:
         """Breaker/ladder state for the RPC status endpoint and tests."""

@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from tendermint_tpu.ops import curve
 from tendermint_tpu.ops import scalar as sc
@@ -73,6 +74,32 @@ def build_neg_comb(pubkeys: jnp.ndarray) -> tuple:
 
 
 build_neg_comb_jit = jax.jit(build_neg_comb)
+
+
+def comb_columns(tbls: tuple, oks: tuple, src: jnp.ndarray) -> tuple:
+    """A comb table assembled from the columns of others: with the
+    tables of `tbls` (each uint8[26, 1024, V_i, 3, 32], `oks` their
+    bool[V_i]) laid side by side along the column axis, column j of the
+    result is column src[j].  A column is a function of its own public
+    key and of nothing else (`build_neg_comb`), so a set that shares
+    keys with a resident table takes those columns from it and builds
+    only the keys that joined (`crypto.backend.TpuBackend`).  The result
+    is a new array; no argument is donated.
+
+    One window at a time (`lax.map` over the 26): the columns laid side
+    by side are then one window's (14 MB at 144 columns) and never the
+    whole table's, which compiled whole is 471 MiB of temporaries at 128
+    columns and 1,958 at 512, and is none this way.
+    """
+    def window(rows):
+        row = rows[0] if len(rows) == 1 else jnp.concatenate(rows, axis=1)
+        return jnp.take(row, src, axis=1, mode="clip")
+
+    ok = oks[0] if len(oks) == 1 else jnp.concatenate(oks)
+    return lax.map(window, tbls), jnp.take(ok, src, mode="clip")
+
+
+comb_columns_jit = jax.jit(comb_columns)
 
 
 def verify_grouped(tables: jnp.ndarray, pub_ok: jnp.ndarray,

@@ -273,9 +273,11 @@ class Registry:
         #   (includes overlapped host work in pipelined callers)
         self.table_build_seconds = Summary()  # comb-table builds (per set)
         # comb tables, one a validator set: built on the device (not
-        # loaded from disk), dropped by the byte-bounded FIFO, and the
-        # bytes the device holds for them now
+        # loaded from disk), those of them derived from a resident table
+        # (only the keys that joined were built), dropped by the
+        # byte-bounded FIFO, and the bytes the device holds for them now
         self.table_builds = Counter()
+        self.table_derives = Counter()
         self.table_evictions = Counter()
         self.tables_resident_bytes = Gauge()
         # lanes of the programs the device verifies rode (a call's

@@ -89,9 +89,15 @@ def synced(tmp_path_factory):
     polling = threading.Event()
     try:
         t0 = tracing.now_epoch()
-        node, _cfg = cell.boot_node(
-            str(tmp_path_factory.mktemp("churn-node")), gen,
-            [str(sw._listener.addr) for sw in sources], "valset_kvstore")
+        with pytest.MonkeyPatch.context() as mp:
+            # one device, as on one chip: conftest gives the CPU eight,
+            # and on a mesh a set's table is replicated and built whole
+            import jax
+            devices = jax.devices
+            mp.setattr(jax, "devices", lambda *a, **kw: devices(*a, **kw)[:1])
+            node, _cfg = cell.boot_node(
+                str(tmp_path_factory.mktemp("churn-node")), gen,
+                [str(sw._listener.addr) for sw in sources], "valset_kvstore")
         routes = Routes(node)
 
         def poll():
@@ -178,6 +184,21 @@ def test_the_spans_of_a_set_change_are_one_a_change(synced):
     assert all(b["args"]["v"] == N_VALS and b["args"]["bytes"] > 0 and
                b["dur"] > 0 for b in builds)
     assert not _since(t0, "tables.evict")
+    # every set after the genesis set is derived from its predecessor
+    # (one key joined, 15 columns kept), each inside its set's build; the
+    # two programs for that were loaded once, on the warm-up thread that
+    # the first window cut at a change started
+    derives = _since(t0, "tables.derive")
+    assert [d["args"] for d in derives] == [
+        {"v": N_VALS, "joined": 1, "bytes": builds[0]["args"]["bytes"]}] * 2
+    assert len(derives) == len(changes)
+    for d, b in zip(derives, builds[1:]):
+        assert b["ts"] <= d["ts"] and \
+            d["ts"] + d["dur"] <= b["ts"] + b["dur"] + 1e-6
+    loads = _since(t0, "tables.derive.load")
+    assert [(ld["args"], ld["lane"]) for ld in loads] == [
+        ({"v": N_VALS, "joined": 16}, "crypto-precompile")]
+    assert loads[0]["ts"] + loads[0]["dur"] <= derives[0]["ts"]
 
 
 def test_a_cut_window_runs_in_the_program_the_boot_warmed(synced):
