@@ -35,6 +35,7 @@ from tendermint_tpu.utils import attribution, tracing
 from tendermint_tpu.utils.chaos import DeviceFault
 from tendermint_tpu.utils.log import get_logger
 from tendermint_tpu.utils.metrics import REGISTRY
+from tendermint_tpu.utils.threadledger import ThreadLedger
 
 log = get_logger("blockchain")
 
@@ -52,7 +53,7 @@ class _Lookahead:
     and next height still match; verification errors are recorded, not
     acted on — the synchronous path re-verifies and owns the blame logic."""
 
-    def __init__(self, vals, chain_id: str, blocks):
+    def __init__(self, vals, chain_id: str, blocks, ledger: ThreadLedger):
         self.vals_hash = vals.hash()
         self.first_height = blocks[0].height
         self.window = None
@@ -62,6 +63,7 @@ class _Lookahead:
         self._vals = vals
         self._chain_id = chain_id
         self._blocks = blocks
+        self._ledger = ledger
         self.thread = threading.Thread(target=self._run, daemon=True,
                                        name="fastsync-lookahead")
         self.thread.start()
@@ -70,21 +72,23 @@ class _Lookahead:
         try:
             with tracing.span("fastsync.lookahead",
                               first_height=self.first_height,
-                              blocks=len(self._blocks)) as args:
-                cpu0 = time.thread_time()
+                              blocks=len(self._blocks)):
                 window, parts_list, items = \
                     BlockchainReactor._prepare_window(self._blocks,
                                                       self.vals_hash)
                 if window:
                     verify_commits_batched(self._vals, self._chain_id,
                                            items)
-                # this thread's own CPU: wall - cpu_s is what it waited,
-                # for the GIL (apply runs meanwhile) or for the device
-                args["cpu_s"] = time.thread_time() - cpu0
             self.window, self.parts_list, self.items = (window, parts_list,
                                                         items)
         except BaseException as e:
             self.error = e
+        finally:
+            # this thread lives for one window, so it says what CPU it
+            # used itself (`cpu.lookahead`): the span's wall less that is
+            # what it waited, for the GIL (apply runs meanwhile) or for
+            # the device
+            self._ledger.thread_exiting()
 
 
 class BlockchainReactor(Reactor):
@@ -119,6 +123,9 @@ class BlockchainReactor(Reactor):
         self.handed_over = False
         self._lookahead: _Lookahead | None = None
         self.lookahead_hits = 0     # speculative windows actually consumed
+        # what the threads used and waited, a window; its probe of the
+        # GIL lives as long as the fast-sync thread
+        self._ledger = ThreadLedger()
 
     def get_channels(self):
         return [ChannelDescriptor(id=BLOCKCHAIN_CHANNEL, priority=5,
@@ -129,10 +136,12 @@ class BlockchainReactor(Reactor):
         if self.fast_sync:
             self._thread = threading.Thread(target=self._pool_routine,
                                             daemon=True, name="fast-sync")
+            self._ledger.probe.start()
             self._thread.start()
 
     def stop(self) -> None:
         self._stopped.set()
+        self._ledger.probe.stop()
         la = self._lookahead
         if la is not None:
             la.thread.join(timeout=5)
@@ -213,6 +222,14 @@ class BlockchainReactor(Reactor):
 
     # -- the sync loop ---------------------------------------------------
     def _pool_routine(self) -> None:
+        """The fast-sync thread: it ends at the hand-over to consensus
+        or at `stop()`, and the GIL probe with it."""
+        try:
+            self._sync_until_caught_up()
+        finally:
+            self._ledger.probe.stop()
+
+    def _sync_until_caught_up(self) -> None:
         """Reference `poolRoutine` :169-257."""
         last_status = 0.0
         while not self._stopped.is_set():
@@ -420,7 +437,7 @@ class BlockchainReactor(Reactor):
         if len(nxt) >= 2 and self._window_ready(nxt) and \
                 not self._stopped.is_set():
             self._lookahead = _Lookahead(
-                self.state.validators.copy(), chain_id, nxt)
+                self.state.validators.copy(), chain_id, nxt, self._ledger)
         commit_by_height = {h: c for _bid, h, c in items}
         parts_by_height = {b.height: p for b, p in zip(window, parts_list)}
 
@@ -448,23 +465,25 @@ class BlockchainReactor(Reactor):
             return moved
 
         with tracing.span("fastsync.apply", first_height=window[0].height,
-                          blocks=len(window)) as args:
+                          blocks=len(window)):
             # the window-batched apply: per-block validate/exec/save
             # discipline identical to apply_block (one state save a
             # block: a durable node must keep store <= state+1 for the
             # handshake), but the app conn's lock is held once for the
             # whole window instead of ~4 acquisitions per block
-            cpu0 = time.thread_time()
+            p0, cpu0 = time.perf_counter(), time.thread_time()
             applied = execution.apply_window(
                 self.state, None, self.proxy,
                 [(b, p.header) for b, p in zip(window, parts_list)],
                 execution.MockMempool(), check_last_commit=False,
                 before_block=_save_to_store, on_applied=_advance,
                 stop_when=_valset_moved)
-            # this thread's own CPU: wall - cpu_s is what apply waited,
-            # for the GIL (look-ahead and p2p decode run meanwhile) or
-            # for sqlite's I/O
-            args["cpu_s"] = time.thread_time() - cpu0
+            # wall less this thread's own CPU is what apply waited, for
+            # the GIL (look-ahead and p2p decode run meanwhile) or for
+            # sqlite's I/O: `offcpu.apply`, and its writes' share of it
+            p1 = time.perf_counter()
+            self._ledger.apply_ended(p1 - p0, time.thread_time() - cpu0,
+                                     tracing.perf_to_epoch(p1))
         # the window-boundary span: covers verify (or lookahead reuse)
         # through apply under one window=<first_height> key, which is
         # what the attribution profiler groups by
@@ -473,6 +492,7 @@ class BlockchainReactor(Reactor):
         tracing.RECORDER.record(
             "fastsync.window", lo, hi - lo,
             {"window": window[0].height, "blocks": applied})
+        self._ledger.window_ended(hi)
         try:
             # per-window pipeline health -> Prometheus histograms, from
             # this window's own categorized records: the read costs what

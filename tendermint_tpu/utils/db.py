@@ -14,6 +14,7 @@ import threading
 import time
 from itertools import chain
 
+from tendermint_tpu.utils.threadledger import tally_write, write_begins
 from tendermint_tpu.utils.tracing import CAT_NONE, RECORDER, perf_to_epoch
 
 
@@ -107,9 +108,9 @@ class SQLiteDB:
 
     def set(self, key: bytes, value: bytes) -> None:
         conn = self._conn()
-        t0 = time.perf_counter()
+        t0, cpu0 = time.perf_counter(), write_begins()
         conn.execute(_insert_sql(1), (key, value))
-        _wrote(t0)
+        _wrote(t0, cpu0)
 
     def set_batch(self, kvs: list[tuple[bytes, bytes]]) -> None:
         """All of `kvs` or none of it, in order (the last write of a
@@ -119,7 +120,7 @@ class SQLiteDB:
         if not kvs:
             return
         conn = self._conn()
-        t0 = time.perf_counter()
+        t0, cpu0 = time.perf_counter(), write_begins()
         if len(kvs) <= _ROWS_A_STATEMENT:
             _insert(conn, kvs)
         else:
@@ -132,13 +133,13 @@ class SQLiteDB:
                 if conn.in_transaction:   # some errors roll back themselves
                     conn.execute("ROLLBACK")
                 raise
-        _wrote(t0)
+        _wrote(t0, cpu0)
 
     def delete(self, key: bytes) -> None:
         conn = self._conn()
-        t0 = time.perf_counter()
+        t0, cpu0 = time.perf_counter(), write_begins()
         conn.execute("DELETE FROM kv WHERE k=?", (key,))
-        _wrote(t0)
+        _wrote(t0, cpu0)
 
     def iterate_prefix(self, prefix: bytes):
         hi = _prefix_upper_bound(prefix)
@@ -157,14 +158,19 @@ class SQLiteDB:
             self._local.conn = None
 
 
-def _wrote(t0: float) -> None:
+def _wrote(t0: float, cpu0: float | None) -> None:
     """One `db.write` flight-recorder record around a transaction (the
     one statement, or BEGIN .. COMMIT of a chunked batch): what a
     store's caller spent in sqlite, so that its own encoding and hashing
     is the rest of its span.  Bookkeeping, so outside the attribution
-    partition (CAT_NONE); MemDB has none."""
-    RECORDER.record("db.write", perf_to_epoch(t0), time.perf_counter() - t0,
-                    None, cat=CAT_NONE)
+    partition (CAT_NONE); MemDB has none.  The same seconds go to the
+    thread ledger's tally, which splits them by the thread's CPU clock
+    (`cpu0`, read at the start of the transactions it samples): on the
+    CPU (sqlite and the kernel) and off it (the WAL's sync, and the wait
+    to get the GIL back)."""
+    wall = time.perf_counter() - t0
+    RECORDER.record("db.write", perf_to_epoch(t0), wall, None, cat=CAT_NONE)
+    tally_write(wall, cpu0)
 
 
 def _prefix_upper_bound(prefix: bytes) -> bytes | None:

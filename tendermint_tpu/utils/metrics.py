@@ -355,6 +355,12 @@ class Registry:
         self.window_device_idle_frac_hist = Histogram(
             Histogram.RATIO_BOUNDS)
         self.window_scalar_seconds = Histogram(Histogram.DURATION_BOUNDS)
+        # thread ledger (utils/threadledger.py), while a node fast-syncs:
+        # CPU seconds by kind of thread (`process` is the whole process,
+        # the other roles included), and how long a thread that wants
+        # the GIL and nothing else waits for it
+        self.thread_cpu_seconds = CounterVec("role")
+        self.gil_lag_seconds = Histogram(Histogram.LATENCY_BOUNDS)
         # bench regression ledger (utils/ledger.py): worst per-config
         # delta_frac of the latest run vs best prior (negative = slower);
         # alert on < -threshold

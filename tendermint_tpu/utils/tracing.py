@@ -5,8 +5,9 @@ did the DEVICE do" at kernel granularity, but only while an operator has
 a capture running.  The flight recorder is the complement: an
 always-on, bounded record of what the HOST planes did — consensus step
 transitions, device batch dispatch/collect, WAL writes, fast-sync pool
-events — cheap enough to leave recording in production (one lock + one
-list store per span) and dumpable after the fact, like an aircraft FDR.
+events — cheap enough to leave recording in production (a record is one
+tuple, one lock and one list store: ~1.2 us) and dumpable after the
+fact, like an aircraft FDR.
 
 Spans are written with the context manager::
 
@@ -15,9 +16,22 @@ Spans are written with the context manager::
 
 or, for point events with no duration, ``instant("pool.evict", ...)``.
 
+A third kind of record holds a QUANTITY, not an interval
+(``RECORDER.quantity("cpu.recv", seconds, window_end)``): its `dur` is
+an amount of seconds (CPU a kind of thread used, time a thread spent
+off the CPU) that belongs to the interval ENDING at the given instant,
+and its `ts` is only where it has to start to end there.  It can be
+longer than the interval it belongs to (16 threads use more CPU than a
+window is long).  `utils/threadledger.py` writes them; a reader that
+sums durations by name reads them like spans, and one that asks which
+record COVERS an instant has to pass over `PH_COUNTER`.
+
 The buffer is a fixed-capacity ring (TM_FLIGHT_RECORDER_CAP, default
-16384 spans): old spans are overwritten, never reallocated, so the
-recorder's footprint is constant no matter how long the node runs.
+16384 records): old records are overwritten, never reallocated, so the
+recorder's footprint is constant no matter how long the node runs.  A
+catch-up writes 13 records a height and more, so the default ring holds
+its last seconds (~20 windows of 64 blocks), not minutes; the benchmark
+raises the capacity to keep a whole run.
 `to_chrome_trace()` renders the Chrome trace-event JSON format that
 Perfetto / chrome://tracing / TensorBoard all load, so a flight-recorder
 dump and an XPlane capture can be eyeballed side by side.
@@ -41,6 +55,7 @@ _EPOCH_T0 = time.time() - time.perf_counter()
 
 PH_SPAN = "X"        # Chrome "complete" event (ts + dur)
 PH_INSTANT = "i"     # Chrome "instant" event
+PH_COUNTER = "C"     # Chrome "counter" event: `dur` holds a quantity
 
 
 def perf_to_epoch(p: float) -> float:
@@ -162,7 +177,7 @@ class FlightRecorder:
         case), with error=<type> appended to its args.  `cat` and `lane`
         are reserved keywords feeding the attribution profiler; every
         other keyword lands in the span's args.  Yields the args dict:
-        what the block adds to it (a `cpu_s` read at its end) is
+        what the block adds to it (a count known only at its end) is
         recorded with the span."""
         p0 = time.perf_counter()
         try:
@@ -177,6 +192,17 @@ class FlightRecorder:
     def instant(self, name: str, **args) -> None:
         self.record(name, _EPOCH_T0 + time.perf_counter(), 0.0, args,
                     ph=PH_INSTANT)
+
+    def quantity(self, name: str, value_s: float, end_epoch: float) -> None:
+        """`value_s` seconds of something (CPU, time off the CPU) that
+        belong to the interval ending at `end_epoch`, in the `dur` slot
+        of a record that ENDS one microsecond before that instant: a
+        reader that keeps what ended inside an interval keeps the
+        quantity exactly when it keeps the span that ended at
+        `end_epoch`, whatever the quantity's size.  Outside the
+        attribution partition (CAT_NONE)."""
+        self.record(name, end_epoch - 1e-6 - value_s, value_s,
+                    ph=PH_COUNTER, cat=CAT_NONE)
 
     # -- reading ---------------------------------------------------------
     def snapshot(self) -> list[dict]:
@@ -229,8 +255,10 @@ class FlightRecorder:
     def to_chrome_trace(self) -> dict:
         """Chrome trace-event JSON (the format Perfetto, chrome://tracing
         and TensorBoard's trace viewer load): one "X" complete event per
-        span (ts/dur in MICROseconds), "i" instants, plus one "M"
-        thread_name metadata event per thread seen."""
+        span (ts/dur in MICROseconds), "i" instants, a quantity as a "C"
+        counter event at the instant it ends with its value under
+        `args.seconds`, plus one "M" thread_name metadata event per
+        thread seen."""
         pid = os.getpid()
         events = []
         threads: dict[int, str] = {}
@@ -243,6 +271,9 @@ class FlightRecorder:
                 ev["cat"] = rec["cat"]
             if rec["ph"] == PH_SPAN:
                 ev["dur"] = rec["dur"] * 1e6
+            elif rec["ph"] == PH_COUNTER:
+                ev["ts"] = (rec["ts"] + rec["dur"]) * 1e6
+                ev["args"] = {"seconds": rec["dur"]}
             else:
                 ev["s"] = "t"            # instant scope: thread
             if "args" in rec:

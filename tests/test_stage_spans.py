@@ -63,13 +63,27 @@ def test_stage_records_nest_in_time_under_the_apply_span(spans):
     assert n_stage == 8 * sum(a["args"]["blocks"] for a in applies)
 
 
-def test_apply_and_lookahead_carry_their_threads_cpu_seconds(spans):
-    for name in ("fastsync.apply", "fastsync.lookahead"):
-        found = _named(spans, name)
-        assert found, name
-        for s in found:
-            # its own thread's CPU cannot pass its wall (clock grain aside)
-            assert 0.0 <= s["args"]["cpu_s"] <= s["dur"] + 0.02, s
+def test_apply_and_lookahead_leave_their_threads_cpu_in_the_ledger(spans):
+    applies = _named(spans, "fastsync.apply")
+    waits = _named(spans, "offcpu.apply")
+    assert applies and len(waits) == len(applies)
+    for a, q in zip(applies, waits):
+        # what apply was off the CPU for ends with its span (the record
+        # is written inside it) and cannot pass its wall
+        assert q["ph"] == tracing.PH_COUNTER and q["tid"] == a["tid"]
+        assert a["ts"] <= q["ts"] + q["dur"] <= a["ts"] + a["dur"] + CLOCK
+        assert 0.0 <= q["dur"] <= a["dur"], (q, a)
+    # the look-aheads' own CPU, as each said it at its exit, cannot pass
+    # their wall (clock grain aside); the first window's reading is the
+    # baseline, so what ended before it is in no record
+    aheads = _named(spans, "fastsync.lookahead")
+    cpu = _named(spans, "cpu.lookahead")
+    assert aheads and cpu
+    assert len(cpu) == len(_named(spans, "fastsync.window")) - 1
+    assert 0.0 < sum(q["dur"] for q in cpu) <= \
+        sum(s["dur"] + 0.02 for s in aheads)
+    assert not any("args" in s for s in applies + aheads
+                   if "cpu_s" in s.get("args", {}))
 
 
 def test_prepare_and_commit_phases_nest_under_their_window_phase(spans):
