@@ -17,14 +17,17 @@ flags bit0 = EOF (last packet of the message).
 
 from __future__ import annotations
 
+import os
 import struct
 import threading
 import time
 from collections import deque
 
 from tendermint_tpu.p2p.types import ChannelDescriptor
+from tendermint_tpu.utils import tracing
 from tendermint_tpu.utils.log import get_logger
 from tendermint_tpu.utils.metrics import REGISTRY
+from tendermint_tpu.utils.nativelib import LinkReceiver
 
 log = get_logger("p2p")
 
@@ -115,20 +118,41 @@ class MConnection:
         self._err_lock = threading.Lock()
         self._last_decay = time.monotonic()
         self._threads: list[threading.Thread] = []
+        # the native receive loop's state, where start() finds the link
+        # made for it; None: the Python loop below
+        self._rx: LinkReceiver | None = None
         from tendermint_tpu.utils.flowrate import Meter
         self.send_monitor = Meter()
         self.recv_monitor = Meter()
 
     # -- lifecycle ------------------------------------------------------
     def start(self) -> None:
+        # decided once, from what the link is made of: a secret link
+        # straight over a socket has its packets read natively, a
+        # message a call; any other (fuzzed, not a socket, no toolchain)
+        # runs the Python loop, which is also the reference the tests
+        # hold the native one equal to
+        hand_over = getattr(self.conn, "native_receiver", None)
+        if hand_over is not None:       # a SecretConnection: it decides
+            lim = self._recv_limiter
+            self._rx = hand_over(
+                lim.rate, lim.burst,
+                {ch.desc.id: ch.desc.recv_message_capacity
+                 for ch in self._channels.values()})
+        recv = (self._recv_routine if self._rx is None
+                else self._recv_routine_native)
         for target, name in ((self._send_routine, "mconn-send"),
-                             (self._recv_routine, "mconn-recv")):
+                             (recv, "mconn-recv")):
             t = threading.Thread(target=target, daemon=True, name=name)
             t.start()
             self._threads.append(t)
 
     def stop(self) -> None:
         self._stopped.set()
+        if self._rx is not None:
+            # ends a sleep in the native limiter; closing the conn shuts
+            # the socket down, which ends a native recv
+            self._rx.stop()
         with self._send_cv:
             self._send_cv.notify()
         self.conn.close()
@@ -277,14 +301,70 @@ class MConnection:
                     msg = bytes(ch.recving)
                     ch.recving.clear()
                     REGISTRY.msgs_received.inc()
+                    REGISTRY.link_msgs_python.inc()
+                    tracing.instant("link.recv.python", ch=ch_id,
+                                    bytes=len(msg))
                     self.on_receive(ch_id, msg)
         except Exception as e:
             self._die(e)
+
+    def _recv_routine_native(self) -> None:
+        """The loop above with its per-packet body in one GIL-free call
+        (native/tmlink.cpp): Python runs once a message, a PING, or
+        64 KiB / 100 ms of a long message, and raises what the loop
+        above raises."""
+        rx = self._rx
+        try:
+            while not self._stopped.is_set():
+                ev = rx.recv()
+                if rx.bytes:
+                    self.recv_monitor.update(rx.bytes)
+                if ev == rx.MSG:
+                    msg = rx.message()
+                    REGISTRY.msgs_received.inc()
+                    REGISTRY.link_msgs_native.inc()
+                    tracing.instant("link.recv.native", ch=rx.ch,
+                                    bytes=len(msg))
+                    self.on_receive(rx.ch, msg)
+                elif ev == rx.PING:
+                    with self._send_cv:
+                        self._pong_pending += 1
+                        self._send_cv.notify()
+                elif ev == rx.STOPPED:
+                    break
+                elif ev != rx.PROGRESS:
+                    raise self._native_error(ev, rx.ch, rx.arg)
+        except Exception as e:
+            self._die(e)
+        finally:
+            rx.close()
+
+    @staticmethod
+    def _native_error(ev: int, ch_id: int, arg: int) -> Exception:
+        """The exception the Python loop raises where the native one
+        returned `ev` (secret.py, transport.py and _recv_routine have
+        the originals; tests/test_link_native.py holds them equal)."""
+        if ev == LinkReceiver.CLOSED:
+            return ConnectionError("connection closed")
+        if ev == LinkReceiver.OS_ERROR:
+            return OSError(arg, os.strerror(arg))
+        return ValueError({
+            LinkReceiver.BAD_MAC: "secret connection: bad frame MAC",
+            LinkReceiver.BAD_FRAME_LEN:
+                f"secret connection: bad frame length {arg}",
+            LinkReceiver.BAD_PACKET_TYPE: f"unknown packet type {arg}",
+            LinkReceiver.UNKNOWN_CHANNEL:
+                f"packet for unknown channel {ch_id}",
+            LinkReceiver.OVER_CAPACITY:
+                f"message on channel {ch_id} exceeds {arg} bytes",
+        }[ev])
 
     def receiving(self, ch_id: int) -> int:
         """Bytes that have arrived of the message now being received on
         `ch_id` (0 between messages): a reactor whose messages take
         seconds to cross the link reads its peer's progress here."""
+        if self._rx is not None:
+            return self._rx.receiving(ch_id)
         ch = self._channels.get(ch_id)
         return len(ch.recving) if ch is not None else 0
 

@@ -25,8 +25,9 @@ import hmac
 import os
 import struct
 
-from tendermint_tpu.p2p.transport import ReadBuffer
+from tendermint_tpu.p2p.transport import ReadBuffer, StreamConn
 from tendermint_tpu.types.keys import PrivKey, PubKey
+from tendermint_tpu.utils import nativelib
 
 # ---------------------------------------------------------------------------
 # X25519 (RFC 7748) — handshake only
@@ -219,6 +220,30 @@ class SecretConnection:
         if not 16 <= n <= self.MAX_FRAME:
             raise ValueError(f"secret connection: bad frame length {n}")
         return self._recv.open(self._conn.read_exact(n))
+
+    def native_receiver(self, rate: float, burst: float,
+                        channels: dict[int, int]):
+        """Hand the receive side to the native loop: a
+        `nativelib.LinkReceiver` that owns the socket's read side, the
+        receive direction's `seq` and what both read buffers held, and
+        turns frames into MConnection messages off the GIL; `read_exact`
+        may not be called again.  None, and nothing changed, unless the
+        link is made of exactly what that loop knows: this class (a
+        subclass may open frames its own way) straight over a
+        `StreamConn` on a live socket, and the library built."""
+        if type(self) is not SecretConnection \
+                or type(self._conn) is not StreamConn:
+            return None
+        fd = self._conn.socket_fd()
+        if fd is None:
+            return None
+        d = self._recv
+        rx = nativelib.LinkReceiver.open(fd, d.key, d.mac_key, d.seq,
+                                         rate, burst, channels)
+        if rx is None:
+            return None
+        rx.feed(self._conn.take_buffered(), self._reader.take())
+        return rx
 
     # -- StreamConn API -------------------------------------------------
     def write(self, data: bytes) -> None:

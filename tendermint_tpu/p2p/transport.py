@@ -49,6 +49,13 @@ class ReadBuffer:
         self._pos = end
         return buf[pos:end]
 
+    def take(self) -> bytes:
+        """What is held and not yet read, handed over: the buffer is
+        empty afterwards (another reader goes on from here)."""
+        rest = self._buf[self._pos:]
+        self._buf, self._pos = b"", 0
+        return rest
+
 
 class StreamConn:
     """Blocking duplex byte stream over a socket with exact-read semantics."""
@@ -71,6 +78,19 @@ class StreamConn:
 
     def read_exact(self, n: int) -> bytes:
         return self._reader.read_exact(n)
+
+    def socket_fd(self) -> int | None:
+        """The descriptor of the live, plain socket under this conn, for
+        a reader that takes the read side over from `read_exact` (the
+        native receive loop, utils/nativelib.LinkReceiver); else None."""
+        if self.closed or type(self._sock) is not socket.socket:
+            return None
+        return self._sock.fileno()
+
+    def take_buffered(self) -> bytes:
+        """The bytes read off the socket that `read_exact` has not given
+        out: that reader has them before it reads the socket."""
+        return self._reader.take()
 
     def write(self, data: bytes) -> None:
         with self._wlock:

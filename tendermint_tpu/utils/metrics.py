@@ -324,6 +324,13 @@ class Registry:
         self.peers = Gauge()
         self.msgs_sent = Counter()
         self.msgs_received = Counter()
+        # complete messages by the receive loop that assembled them
+        # (p2p/connection.py): the native one, a message a GIL-free
+        # call, or the Python one, a packet a pass.  python > 0 where
+        # every link is a secret link over a socket says the native
+        # library did not build
+        self.link_msgs_native = Counter()
+        self.link_msgs_python = Counter()
         # p2p self-healing plane (p2p/switch.py): reconnect attempts are
         # the graceful-degradation signal under partitions (a heal storm
         # shows as a burst, a dead peer as a bounded trickle); evictions
@@ -448,6 +455,8 @@ class Registry:
             "peers": self.peers.value,
             "p2p_msgs_sent": self.msgs_sent.value,
             "p2p_msgs_received": self.msgs_received.value,
+            "link_msgs_native": self.link_msgs_native.value,
+            "link_msgs_python": self.link_msgs_python.value,
             "switch_reconnect_attempts":
                 self.switch_reconnect_attempts.value,
             "switch_peers_evicted": self.switch_peers_evicted.value,

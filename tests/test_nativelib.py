@@ -1,5 +1,9 @@
 """Native C++ merkle engine vs the host reference implementation."""
 
+import os
+import subprocess
+import sys
+
 import numpy as np
 import pytest
 
@@ -47,3 +51,40 @@ def test_reuse_is_decided_by_source_hash_not_mtime(tmp_path, monkeypatch):
     so.unlink()
     assert not nativelib._up_to_date(h)          # record, no binary
     assert nativelib.build_status in ("built", "reused")
+
+
+_BUILD_IN = """
+import sys
+from tendermint_tpu.utils import nativelib as n
+n._SO = sys.argv[1]
+n._SO_SRC_HASH = n._SO + ".src.sha256"
+lib = n.get()
+import numpy as np
+got = n.leaf_hashes(np.zeros((1, 8), dtype=np.uint8))
+print(n.build_status, lib is not None and hasattr(lib, "tm_link_recv"),
+      bytes(got[0]).hex())
+"""
+
+
+def test_two_processes_building_at_once_both_load_the_library(tmp_path):
+    """The node and its source child may both find a fresh checkout: each
+    builds under a name of its own and renames, so neither loads, or
+    records as built, a file the other is still writing."""
+    so = str(tmp_path / "libtmhash.so")
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, PYTHONPATH=repo)
+    procs = [subprocess.Popen([sys.executable, "-c", _BUILD_IN, so],
+                              env=env, stdout=subprocess.PIPE, text=True)
+             for _ in range(2)]
+    outs = [p.communicate(timeout=240)[0].split() for p in procs]
+    assert [p.returncode for p in procs] == [0, 0]
+    want = host.leaf_hash(bytes(8)).hex()
+    for status, loaded, digest in outs:
+        assert status in ("built", "reused") and loaded == "True"
+        assert digest == want
+    assert "built" in [o[0] for o in outs]
+    assert sorted(os.listdir(tmp_path)) == ["libtmhash.so",
+                                            "libtmhash.so.src.sha256"]
+    third = subprocess.run([sys.executable, "-c", _BUILD_IN, so], env=env,
+                           capture_output=True, text=True, timeout=240)
+    assert third.stdout.split()[:2] == ["reused", "True"]
