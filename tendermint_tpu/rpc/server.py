@@ -9,12 +9,13 @@ from __future__ import annotations
 
 import json
 import threading
+import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from urllib.parse import parse_qsl, urlparse
 
 from tendermint_tpu.rpc.routes import Routes
 from tendermint_tpu.rpc import websocket as ws
-from tendermint_tpu.utils import metrics
+from tendermint_tpu.utils import metrics, tracing
 
 
 class RPCServer:
@@ -85,6 +86,10 @@ class RPCServer:
                         "error": {"code": -32601,
                                   "message": f"unknown method {method!r}"}})
                     return
+                # one bare record a request a route handled, from the
+                # parsed request to the written answer: the service time,
+                # without the wait for a handler thread or for the socket
+                t0 = time.perf_counter()
                 try:
                     result = fn(params)
                     self._respond(200, {"jsonrpc": "2.0", "id": rid,
@@ -93,6 +98,11 @@ class RPCServer:
                     self._respond(500, {"jsonrpc": "2.0", "id": rid,
                                         "error": {"code": -32603,
                                                   "message": str(e)}})
+                finally:
+                    tracing.RECORDER.record(
+                        "rpc.request", tracing.perf_to_epoch(t0),
+                        time.perf_counter() - t0, {"method": method},
+                        cat=tracing.CAT_NONE)
 
             def _upgrade_websocket(self):
                 key = self.headers.get("Sec-WebSocket-Key")

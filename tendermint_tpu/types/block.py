@@ -214,6 +214,10 @@ class Commit:
         cols = self._cols.unpack(self._wire)
         return (b"".join(cols[0::2]), b"".join(cols[1::2])) + self._hrt
 
+    def wire_backed(self) -> bool:
+        """Whether the commit is still the bytes it was decoded from."""
+        return self._wire is not None
+
     def __eq__(self, other):
         if not isinstance(other, Commit):
             return NotImplemented
@@ -330,10 +334,23 @@ class Commit:
             REGISTRY.commits_decoded_wire.inc()
             return commit
         votes: list[Vote | None] = []
+        t0 = time.perf_counter()
         for _ in range(n):
             votes.append(Vote.decode(r) if r.u8() else None)
+        # one bare record a commit decoded vote by vote (as
+        # `block.txs_hash` below: a `perf_counter` pair and a ring write)
+        tracing.RECORDER.record("commit.decode.votes",
+                                tracing.perf_to_epoch(t0),
+                                time.perf_counter() - t0, None,
+                                cat=tracing.CAT_NONE)
         commit = cls(block_id=block_id, precommits=votes)
         REGISTRY.commits_decoded_objects.inc()
+        absent = n - commit.num_sigs()
+        if absent:
+            # upstream's nil entries: `_irregular` trips over the body's
+            # length first, which says nothing of why it is short
+            REGISTRY.commit_precommits_absent.inc(absent)
+            reason = "absent"
         tracing.instant("commit.object_form", height=commit.height(),
                         reason=reason)
         return commit

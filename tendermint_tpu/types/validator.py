@@ -19,6 +19,7 @@ from tendermint_tpu.types import canonical, merkle
 from tendermint_tpu.types.codec import Reader, i64, lp_bytes, u32
 from tendermint_tpu.types.keys import PubKey
 from tendermint_tpu.utils import tracing
+from tendermint_tpu.utils.metrics import REGISTRY
 
 
 class CommitSignatureError(ValueError):
@@ -483,8 +484,9 @@ class ValidatorSet:
 
     def _window_wire_columns(self, items: list[tuple]) -> list | None:
         """Every commit's `wire_columns()` when the whole window may take
-        the vectorized pass, else None (nothing recorded: the per-block
-        path the window then takes records what it refuses)."""
+        the vectorized pass, else None (no reason recorded: the
+        per-block path the window then takes records what it refuses,
+        and a fast-sync window which path it took)."""
         cols = []
         for _bid, h, c in items:
             col = c.wire_columns()
@@ -566,8 +568,24 @@ def merge_commit_lanes(arrays: list[tuple]) -> tuple:
             np.concatenate([a[4] for a in arrays]))
 
 
+def _record_lane_builder(items: list[tuple], vectorised: bool) -> None:
+    """One instant and one count a fast-sync window: which builder
+    `window_commit_lanes` gave it, and on the per-block path how many of
+    its commits were not wire-backed (decoded vote by vote, or built
+    from votes); the others were refused by a check."""
+    if vectorised:
+        REGISTRY.lane_windows_vectorised.inc()
+        tracing.instant("fastsync.lanes.vectorised", blocks=len(items),
+                        object_commits=0)
+    else:
+        REGISTRY.lane_windows_per_block.inc()
+        tracing.instant(
+            "fastsync.lanes.per_block", blocks=len(items),
+            object_commits=sum(not c.wire_backed() for _b, _h, c in items))
+
+
 def window_commit_lanes(val_set: ValidatorSet, chain_id: str,
-                        items: list[tuple]) -> tuple:
+                        items: list[tuple], record: bool = False) -> tuple:
     """Window-level lane builder: the vectorized fusion of per-block
     `commit_verify_lanes` + `merge_commit_lanes` over a whole fast-sync
     window (`items` = [(block_id, height, commit)]).
@@ -589,6 +607,8 @@ def window_commit_lanes(val_set: ValidatorSet, chain_id: str,
     block, and foreign (other non-nil block) power — everything the
     post-verify tally needs, with no per-block arrays retained.
     Structural errors raise `CommitFormatError` naming the height.
+    With `record` (the fast-sync producer's windows) the window says
+    which of the two builders it took (`_record_lane_builder`).
     """
     if not items:
         z = np.zeros(0, dtype=np.int64)
@@ -597,6 +617,8 @@ def window_commit_lanes(val_set: ValidatorSet, chain_id: str,
                 np.zeros((0, 64), dtype=np.uint8),
                 np.zeros(0, dtype=np.int32), z, z.copy(), z.copy())
     cols = val_set._window_wire_columns(items)
+    if record:
+        _record_lane_builder(items, cols is not None)
     if cols is None:
         arrays = []
         for bid, h, c in items:
@@ -686,7 +708,8 @@ def verify_commits_batched(val_set: ValidatorSet, chain_id: str,
                 if producer == "fastsync" else nullcontext())
     with phase("fastsync.commit.lanes"):
         templates, tmpl_idx, sigs, idxs, counts, tallied, foreign = \
-            window_commit_lanes(val_set, chain_id, items)
+            window_commit_lanes(val_set, chain_id, items,
+                                record=producer == "fastsync")
     ok = batchplane.verify_grouped_templated(
         val_set.set_key(), val_set.pubs_matrix(), idxs,
         tmpl_idx, templates, sigs, producer=producer,

@@ -310,6 +310,14 @@ class Registry:
         # by vote (an absent, nil or foreign vote, any irregular record)
         self.commits_decoded_wire = Counter()
         self.commits_decoded_objects = Counter()
+        # upstream's nil entries (a precommit that missed the commit)
+        # among the votes decoded one by one
+        self.commit_precommits_absent = Counter()
+        # fast-sync windows by the lane builder they took
+        # (types/validator.py::window_commit_lanes): every commit in its
+        # wire bytes and one vectorised pass, or a pass a block
+        self.lane_windows_vectorised = Counter()
+        self.lane_windows_per_block = Counter()
         # state-sync / snapshot plane (statesync/): chunks_verified vs
         # chunks_rejected is the no-silent-acceptance ledger — every
         # fetched chunk lands in exactly one of the two, and a rejected
@@ -444,6 +452,9 @@ class Registry:
             "blocks_synced": self.blocks_synced.value,
             "commits_decoded_wire": self.commits_decoded_wire.value,
             "commits_decoded_objects": self.commits_decoded_objects.value,
+            "commit_precommits_absent": self.commit_precommits_absent.value,
+            "lane_windows_vectorised": self.lane_windows_vectorised.value,
+            "lane_windows_per_block": self.lane_windows_per_block.value,
             "snapshots_created": self.snapshots_created.value,
             "snapshot_create_seconds_mean":
                 round(self.snapshot_create_seconds.mean, 6),

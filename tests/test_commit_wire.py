@@ -236,6 +236,13 @@ def test_the_counter_pair_and_the_instant_say_which_path_a_decode_took():
     pruned = Commit(block_id=bid, precommits=votes)
     stale = Commit(block_id=bid,
                    precommits=votes_for(rng, vs, bid, height=HEIGHT - 3))
+    # irregular WITHOUT a nil entry: the word is what `_irregular` found
+    votes = list(regular.precommits)
+    votes[2] = Vote(**{**votes[2].__dict__, "round": 2})
+    stray = Commit(block_id=bid, precommits=votes)
+    votes = list(regular.precommits)
+    votes[2] = Vote(**{**votes[2].__dict__, "block_id": ZERO_BLOCK_ID})
+    for_nil = Commit(block_id=bid, precommits=votes)
 
     def moved(f):
         t0 = tracing.now_epoch()
@@ -254,6 +261,10 @@ def test_the_counter_pair_and_the_instant_say_which_path_a_decode_took():
         lambda: vs.commit_verify_lanes(CHAIN, bid, HEIGHT, dec))
     assert counts == (0, 0) and seen == []
     _, counts, seen = moved(lambda: Commit.decode(Reader(pruned.encode())))
+    assert counts == (0, 1) and seen == [(HEIGHT, "absent")]
+    _, counts, seen = moved(lambda: Commit.decode(Reader(stray.encode())))
+    assert counts == (0, 1) and seen == [(HEIGHT, "votes")]
+    _, counts, seen = moved(lambda: Commit.decode(Reader(for_nil.encode())))
     assert counts == (0, 1) and seen == [(HEIGHT, "length")]
     _, counts, seen = moved(
         lambda: Commit.decode(Reader(Commit(ZERO_BLOCK_ID, []).encode())))

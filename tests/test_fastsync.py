@@ -695,7 +695,7 @@ def test_a_regular_window_records_nothing_and_a_pruned_commit_one_instant():
     assert REGISTRY.commits_decoded_wire.value - wire0 == 63
     assert REGISTRY.commits_decoded_objects.value - objects0 == 1
     assert since(t0, "commit.object_form") == [
-        {"height": 39, "reason": "length"}]
+        {"height": 39, "reason": "absent"}]
     assert bc._sync_step() is False
     assert bc.state.last_block_height == 0
     assert [a["height"] for a in since(t0, "pool.redo")] == [40]
