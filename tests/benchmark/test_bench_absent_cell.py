@@ -233,10 +233,25 @@ def test_the_mean_service_time_of_a_request_follows_from_the_pair():
 
 def test_a_traced_rehearsal_over_an_absent_chain_reads_all_six():
     """`run_cell` at 4 validators with one down and some late (every
-    commit holds a nil entry): each new metric is in the line, the
-    absent count is the interval's heights, every window took the
-    per-block builder and none the vectorised pass, and the prober's
-    requests were handled in less than they waited."""
+    commit holds a nil entry): the run is `correct`, each new metric is
+    in the line and finite, and the prober's requests were handled in
+    less than they waited.
+
+    Which decoder and which lane builder the program took is NOT held
+    here: a live run's path is the program's to change (a wire form that
+    survives a nil entry reads `reactor.commit_absent_form` 0,
+    `reactor.vote_decode_ms` 0.0 and every window vectorised, where PR
+    44's program reads the interval's heights, a time, and every window
+    per block), and an accepted benchmark file that pinned it could be
+    lifted by no PR that changes the program.  Two relations hold
+    whatever the path: this chain has no irregularity but its nil
+    entries, so every object-form commit is an absent-form one, at most
+    one a height; and every window is counted once by one of the two
+    builders, twice where a look-ahead was dropped.  That the four
+    reducers read the right records is pinned by the synthetic ring
+    above (`WANT`)."""
+    import math
+
     import benchutil
     result, out = benchutil.rehearse(
         seed=SEED + 4, trace=True, timeout=600,
@@ -245,15 +260,19 @@ def test_a_traced_rehearsal_over_an_absent_chain_reads_all_six():
     assert result["correct"] is True and result["failed"] == 0, out[-3000:]
     m = {k: v["value"] for k, v in result["metrics"].items()}
     assert set(NEW_METRICS) <= set(m)
+    assert all(math.isfinite(m[name]) for name in NEW_METRICS)
     heights = result["attempted"]
     windows = heights // 64
-    assert abs(m["reactor.commit_absent_form"] - heights) <= 64
-    assert m["reactor.commit_absent_form"] == m["reactor.commit_object_form"]
-    assert m["reactor.vote_decode_ms"] > 0.0
-    assert m["reactor.lane_windows_vectorised"] == 0.0
+    absent = m["reactor.commit_absent_form"]
+    assert m["reactor.commit_object_form"] == absent <= heights + 64
     # a window the look-ahead verified and the main loop verified again
     # (a dropped look-ahead) counts twice
-    assert windows - 1 <= m["reactor.lane_windows_per_block"] <= 2 * windows
+    assert windows - 1 <= (m["reactor.lane_windows_per_block"]
+                           + m["reactor.lane_windows_vectorised"]) \
+        <= 2 * windows
+    # the per-vote loop took time exactly where a commit went through it
+    assert m["reactor.vote_decode_ms"] >= 0.0
+    assert (m["reactor.vote_decode_ms"] > 0.0) == (absent > 0)
     assert 10 <= m["rpc.requests"] <= 70          # 10 a second for 6 s
     mean_ms = m["rpc.handle_ms"] * windows / m["rpc.requests"]
     assert 0.0 < mean_ms < 1e3

@@ -20,7 +20,7 @@ NAMES = ["refused", "wrong_hash", "tip_hash_differs", "app_hash_differs",
          "probe_errors", "control_lanes_differ", "control_programs"]
 # beside the chain's own limits the run prints what `precommit_limits`
 # gives for an index that says every commit was full (the parent's limits),
-# and what the program counted of commits decoded vote by vote
+# and what the program counted of commits decoded, by either decoder
 BESIDE = """
 from benchmark.lib import cell as _cell
 from tendermint_tpu.utils.metrics import REGISTRY as _registry
@@ -63,11 +63,12 @@ def test_absent_rehearsal_is_correct_by_the_chains_own_count():
         f"the interval signed (75.000 %); {heights} of {heights} commits and "
         f"{heights // 64} of {heights // 64} 64-block windows hold an absent "
         "precommit")
-    # the program decoded every served commit vote by vote, none by wire
+    # the program decoded every served commit, by one of its two
+    # decoders (which one a commit with a nil entry takes is not held)
     beside = _line(out, "[test] limits:").split()
     held, at_tip, full_held, full_at_tip, by_vote, by_wire = (
         int(beside[i]) for i in (4, 5, 9, 10, 14, 17))
-    assert by_vote >= held // 3 > 0 and by_wire == 0
+    assert by_vote + by_wire >= held // 3 > 0
     # the floor is the chain's own count, three a height, and the node,
     # look-ahead and all, verified at least that and not four a height
     verified = checks["sigs_verified"]
