@@ -46,6 +46,17 @@ def fixed(b: bytes, n: int) -> bytes:
     return b
 
 
+def without(data: bytes, cuts, cut_len: int) -> bytes:
+    """`data` less the `cut_len` bytes at each offset of `cuts`
+    (ascending): the (cuts + 1) runs between them, joined."""
+    parts, start = [], 0
+    for at in cuts:
+        parts.append(data[start:at])
+        start = at + cut_len
+    parts.append(data[start:])
+    return b"".join(parts)
+
+
 class Reader:
     """Sequential decoder over one buffer; raises on truncation."""
 

@@ -16,7 +16,7 @@ from dataclasses import dataclass
 import numpy as np
 
 from tendermint_tpu.types import canonical, merkle
-from tendermint_tpu.types.codec import Reader, i64, lp_bytes, u32
+from tendermint_tpu.types.codec import Reader, i64, lp_bytes, u32, without
 from tendermint_tpu.types.keys import PubKey
 from tendermint_tpu.utils import tracing
 from tendermint_tpu.utils.metrics import REGISTRY
@@ -451,10 +451,12 @@ class ValidatorSet:
         """Why the vectorized lane builders may not take a wire-backed
         commit's columns, in a word; None when they may: every check the
         per-vote loop of `commit_verify_lanes` makes then holds by
-        inspection (what `Commit.decode` pinned, plus the set's size and
-        addresses, the expected height, the type byte and 32-byte
-        hashes), so the two cannot diverge."""
-        addrs, _sigs, c_height, _round, type_ = cols
+        inspection (what `Commit.decode` pinned, each present record's
+        index to its position among the entries above all, plus the
+        set's size, the expected height, the type byte, 32-byte hashes
+        and the set's addresses AT THE POSITIONS THAT ARE PRESENT), so
+        the two cannot diverge."""
+        addrs, _sigs, c_height, _round, type_, absent = cols
         bid = commit.block_id
         if commit.size() != self.size():
             return "size"
@@ -464,7 +466,10 @@ class ValidatorSet:
             return "type"
         if len(bid.hash) != 32 or len(bid.parts.hash) != 32:
             return "block id"
-        if addrs != self._addrs_bytes():
+        members = self._addrs_bytes()
+        if absent:
+            members = without(members, [20 * p for p in absent], 20)
+        if addrs != members:
             return "address"
         return None
 
@@ -484,9 +489,10 @@ class ValidatorSet:
 
     def _window_wire_columns(self, items: list[tuple]) -> list | None:
         """Every commit's `wire_columns()` when the whole window may take
-        the vectorized pass, else None (no reason recorded: the
-        per-block path the window then takes records what it refuses,
-        and a fast-sync window which path it took)."""
+        the vectorized pass (each wire-backed, with or without nil
+        entries, and passing `_wire_refusal`), else None (no reason
+        recorded: the per-block path the window then takes records what
+        it refuses, and a fast-sync window which path it took)."""
         cols = []
         for _bid, h, c in items:
             col = c.wire_columns()
@@ -500,21 +506,30 @@ class ValidatorSet:
         """`commit_verify_lanes` without the per-vote loop, for columns
         `_wire_columns` passed: every lane shares the commit's (height,
         round, block_id), so there is ONE template, the signature column
-        is the lanes' sigs and the powers are the set's power array."""
+        is the lanes' sigs, the lanes' members are the positions that
+        are not nil and the powers are the set's power array there.  A
+        nil entry is no lane: neither verified nor tallied."""
         bid = commit.block_id
         tmpl = _commit_template(chain_id, commit)
-        n = self.size()
+        absent = cols[5]
+        idxs = np.arange(self.size(), dtype=np.int32)
+        powers, signed = self._powers_arr(), self._total
+        if absent:
+            idxs = np.delete(idxs, absent)
+            powers = powers[idxs]
+            signed = int(powers.sum())
+        n = len(idxs)
         if bid.key() == block_id.key():
-            powers = self._powers_arr().copy()
+            powers = powers.copy()
             foreign_power = 0
         else:   # the whole commit endorses another block (32-byte hash)
             powers = np.zeros(n, dtype=np.int64)
-            foreign_power = self._total
+            foreign_power = signed
         return (np.frombuffer(tmpl, np.uint8).reshape(
                     1, canonical.SIGN_BYTES_LEN),
                 np.zeros(n, dtype=np.int32),
                 np.frombuffer(cols[1], np.uint8).reshape(n, 64),
-                powers, np.arange(n, dtype=np.int32), foreign_power)
+                powers, idxs, foreign_power)
 
     def verify_commit(self, chain_id: str, block_id, height: int,
                       commit, producer: str = "fastsync",
@@ -594,11 +609,17 @@ def window_commit_lanes(val_set: ValidatorSet, chain_id: str,
     `Vote` objects a block, B rounds of sign-bytes assembly and a B-way
     concatenate, all holding the GIL beside the apply.  When every
     commit is wire-backed and passes `_window_wire_columns` (what
-    fast-sync decodes from an honest peer), the whole window collapses
-    to one template a block and one gather of the signature columns:
-    byte-identical to the loop (property-tested).  Any commit built
-    from votes, or a check that fails, routes the window to the
-    per-block path so results and errors match exactly.
+    fast-sync decodes from an honest peer, nil entries or none), the
+    whole window collapses to one template a block and one gather of
+    the signature columns, which hold the present records only; where
+    a commit has nil entries the lanes' members, the counts and the
+    tallied power are read off the window's (B x V) presence, a
+    handful of numpy calls a window and none a commit: byte-identical
+    to the loop (property-tested).  A nil entry is no lane, neither
+    verified nor tallied, and +2/3 of the WHOLE set's power is still
+    owed (`window_tally_check`).  Any commit built from votes, or a
+    check that fails, routes the window to the per-block path so
+    results and errors match exactly.
 
     Returns (templates[T,128], tmpl_idx[N], sigs[N,64], idxs[N],
     counts[B], tallied[B], foreign[B]): the first four are the merged
@@ -641,13 +662,26 @@ def window_commit_lanes(val_set: ValidatorSet, chain_id: str,
     templates = np.frombuffer(b"".join(
         _commit_template(chain_id, c) for _bid, _h, c in items),
         np.uint8).reshape(b, canonical.SIGN_BYTES_LEN)
-    # every vote present: block-major lanes, already in merge order
-    idxs = np.tile(np.arange(v, dtype=np.int32), b)
-    tmpl_idx = np.repeat(np.arange(b, dtype=np.int32), v)
+    nil = [i * v + p for i, col in enumerate(cols) for p in col[5]]
+    if nil:
+        # the (B x V) presence: block-major lanes where an entry is
+        # present, already in merge order; a nil entry is no lane, and
+        # a commit's power is its present members'
+        present = np.ones((b, v), dtype=bool)
+        present.reshape(-1)[nil] = False
+        blocks, members = np.nonzero(present)
+        idxs, tmpl_idx = members.astype(np.int32), blocks.astype(np.int32)
+        counts = present.sum(axis=1, dtype=np.int64)
+        row_power = present @ val_set._powers_arr()
+    else:
+        # every vote present: block-major lanes, already in merge order
+        idxs = np.tile(np.arange(v, dtype=np.int32), b)
+        tmpl_idx = np.repeat(np.arange(b, dtype=np.int32), v)
+        counts = np.full(b, v, dtype=np.int64)
+        row_power = np.full(b, val_set.total_voting_power(),
+                            dtype=np.int64)
     sigs = np.frombuffer(b"".join(col[1] for col in cols),
-                         np.uint8).reshape(b * v, 64)
-    counts = np.full(b, v, dtype=np.int64)
-    row_power = np.full(b, val_set.total_voting_power(), dtype=np.int64)
+                         np.uint8).reshape(len(idxs), 64)
     same = np.fromiter(
         (c.block_id.key() == bid.key() for bid, _, c in items), bool, b)
     # a 32-byte hash is no nil block id, so every commit that does not

@@ -306,12 +306,14 @@ class Registry:
         self.vote_microbatch_lanes = Counter()
         # sync plane
         self.blocks_synced = Counter()
-        # commits `Commit.decode` left in their wire bytes / decoded vote
-        # by vote (an absent, nil or foreign vote, any irregular record)
+        # commits `Commit.decode` left in their wire bytes (of them, those
+        # that hold nil entries) / decoded vote by vote (a nil or foreign
+        # vote, any irregular record)
         self.commits_decoded_wire = Counter()
+        self.commits_decoded_wire_absent = Counter()
         self.commits_decoded_objects = Counter()
         # upstream's nil entries (a precommit that missed the commit)
-        # among the votes decoded one by one
+        # in the commits decoded, on either path
         self.commit_precommits_absent = Counter()
         # fast-sync windows by the lane builder they took
         # (types/validator.py::window_commit_lanes): every commit in its
@@ -451,6 +453,8 @@ class Registry:
             "vote_microbatch_lanes": self.vote_microbatch_lanes.value,
             "blocks_synced": self.blocks_synced.value,
             "commits_decoded_wire": self.commits_decoded_wire.value,
+            "commits_decoded_wire_absent":
+                self.commits_decoded_wire_absent.value,
             "commits_decoded_objects": self.commits_decoded_objects.value,
             "commit_precommits_absent": self.commit_precommits_absent.value,
             "lane_windows_vectorised": self.lane_windows_vectorised.value,

@@ -1,13 +1,16 @@
 """A commit decoded from the wire stays in its bytes; nobody can tell.
 
-`Commit.decode` leaves a regular body (every vote present, one width,
-one (height, round, type, block id), indices in order) as a view of the
+`Commit.decode` leaves a body whose present records are regular (one
+width, one (height, round, type, block id), each index its position
+among the entries; nil entries between them or none) as a view of the
 bytes it was read from, and decodes any other body vote by vote.  For
 every shape a peer can send, the decoded commit must be what the
-vote-by-vote decoder alone (the parent's `Commit.decode`, copied below)
-gives: the same lanes for the batch plane or the same error with the same
-message and height, the same bytes back, the same answers to every
-accessor.  Signatures are random bytes: nothing here verifies one.
+vote-by-vote decoder alone (`object_decode` below) gives: the same lanes
+for the batch plane or the same error with the same message and height,
+the same bytes back, the same answers to every accessor.  An entry's
+marker byte is 0 or 1 for both.  Signatures are random bytes: nothing
+here verifies one.  The presence patterns (first entry nil, last, runs,
+one present, none) are `tests/test_commit_wire_absent.py`'s.
 """
 
 import numpy as np
@@ -27,13 +30,13 @@ SIZES = (1, 4, 100, 128)
 # shape -> is the decoded commit wire-backed?
 SHAPES = {
     "all_present": True,
-    "some_absent": False,
+    "some_absent": True,                 # but a set of one: none is left
     "nil_vote": False,
     "foreign_vote": False,
     "foreign_commit_block_id": True,
     "wrong_index": False,
     "wrong_address": True,
-    "marker_two": False,
+    "marker_two": None,                  # does not decode at all
     "short_signature": False,
     "truncated_last_record": None,       # does not decode at all
     "wrong_height": True,
@@ -47,10 +50,16 @@ WINDOW_NAMES = ("templates", "tmpl_idx", "sigs", "idxs", "counts",
 
 
 def object_decode(wire: bytes) -> Commit:
-    """The parent's `Commit.decode`: vote by vote, no other path."""
+    """`Commit.decode` vote by vote, no other path: an entry's marker
+    byte is 0 (nil) or 1 (a vote follows), as go-wire's pointer byte."""
     r = Reader(wire)
     block_id = BlockID.decode(r)
-    votes = [Vote.decode(r) if r.u8() else None for _ in range(r.u32())]
+    votes = []
+    for i in range(r.u32()):
+        marker = r.u8()
+        if marker > 1:
+            raise ValueError(f"commit entry {i}: marker byte {marker}")
+        votes.append(Vote.decode(r) if marker else None)
     r.expect_done()
     return Commit(block_id=block_id, precommits=votes)
 
@@ -153,10 +162,13 @@ def test_decoded_commit_is_the_object_form_in_every_way(sets, n_vals, shape):
         assert got == want and got[0] == "raised"
         return
     dec, ref = got[1], want[1]
-    # (a set of one has no other vote for its one vote to differ from)
-    wire_backed = SHAPES[shape] or (shape == "wrong_round_in_one_vote"
-                                    and n_vals == 1)
+    # (a set of one has no other vote for its one vote to differ from,
+    # and with its one vote absent no record to stay in)
+    wire_backed = SHAPES[shape]
+    if n_vals == 1 and shape in ("wrong_round_in_one_vote", "some_absent"):
+        wire_backed = not wire_backed
     assert (dec.wire_columns() is not None) == wire_backed
+    assert dec.wire_backed() == wire_backed
     assert ref.wire_columns() is None
 
     # the bytes back: the input's own where it stayed in them
@@ -205,26 +217,34 @@ def test_decode_advances_the_reader_past_the_commit_only():
     regular = Commit(block_id=bid, precommits=votes_for(rng, vs, bid))
     votes = list(regular.precommits)
     votes[2] = None
-    for commit, wire_backed in ((regular, True),
-                                (Commit(block_id=bid, precommits=votes),
-                                 False)):
+    absent = Commit(block_id=bid, precommits=votes)
+    # a nil VOTE (a precommit for no block) is a record of another width
+    votes = list(regular.precommits)
+    votes[2] = Vote(**{**votes[2].__dict__, "block_id": ZERO_BLOCK_ID})
+    for_nil = Commit(block_id=bid, precommits=votes)
+    for commit, wire_backed in ((regular, True), (absent, True),
+                                (for_nil, False)):
         wire = commit.encode()
-        r = Reader(b"\xaa\xbb" + wire + b"tail")
-        assert r.fixed(2) == b"\xaa\xbb"
-        dec = Commit.decode(r)
-        assert r.fixed(4) == b"tail" and r.done()
-        assert (dec.wire_columns() is not None) == wire_backed
-        assert dec == commit and dec.encode() == wire
+        for tail in (b"tail", b"\x00\x01\x00", b"\x01" * 200, b""):
+            r = Reader(b"\xaa\xbb" + wire + tail)
+            assert r.fixed(2) == b"\xaa\xbb"
+            dec = Commit.decode(r)
+            assert r.fixed(len(tail)) == tail and r.done()
+            assert (dec.wire_columns() is not None) == wire_backed
+            assert dec == commit and dec.encode() == wire
     empty = Commit.decode(Reader(Commit(ZERO_BLOCK_ID, []).encode()))
     assert empty.size() == 0 and not empty.is_commit()
     assert empty.wire_columns() is None
 
 
 def test_the_counter_pair_and_the_instant_say_which_path_a_decode_took():
-    """No record on the regular path; one instant, with the commit's
-    height and the reason, when a decoded commit takes the object path
-    at the decoder or at the lane builder.  An empty commit (height 1's)
-    has no votes to take either path."""
+    """No record on the regular path of a full commit; one
+    `commit.wire_absent` instant, with the commit's height and its nil
+    entries, where a commit stays in its bytes through them; one
+    `commit.object_form` instant, with the height and the reason, when
+    a decoded commit takes the object path at the decoder or at the
+    lane builder.  An empty commit (height 1's) has no votes to take
+    either path."""
     from tendermint_tpu.utils import tracing
     from tendermint_tpu.utils.metrics import REGISTRY
     _, vs = make_validators(4)
@@ -249,9 +269,11 @@ def test_the_counter_pair_and_the_instant_say_which_path_a_decode_took():
         before = (REGISTRY.commits_decoded_wire.value,
                   REGISTRY.commits_decoded_objects.value)
         out = f()
-        seen = [(s["args"]["height"], s["args"]["reason"])
+        seen = [(s["args"]["height"], s["args"].get("reason",
+                                                    s["args"].get("absent")))
                 for s in tracing.RECORDER.since(t0)
-                if s["name"] == "commit.object_form" and s["ts"] >= t0]
+                if s["name"] in ("commit.object_form", "commit.wire_absent")
+                and s["ts"] >= t0]
         return out, (REGISTRY.commits_decoded_wire.value - before[0],
                      REGISTRY.commits_decoded_objects.value - before[1]), seen
 
@@ -260,8 +282,19 @@ def test_the_counter_pair_and_the_instant_say_which_path_a_decode_took():
     _, counts, seen = moved(
         lambda: vs.commit_verify_lanes(CHAIN, bid, HEIGHT, dec))
     assert counts == (0, 0) and seen == []
-    _, counts, seen = moved(lambda: Commit.decode(Reader(pruned.encode())))
-    assert counts == (0, 1) and seen == [(HEIGHT, "absent")]
+    # a nil entry: still the bytes, and the instant says how many
+    wire_absent0 = REGISTRY.commits_decoded_wire_absent.value
+    kept, counts, seen = moved(
+        lambda: Commit.decode(Reader(pruned.encode())))
+    assert counts == (1, 0) and seen == [(HEIGHT, 1)]
+    assert REGISTRY.commits_decoded_wire_absent.value - wire_absent0 == 1
+    _, counts, seen = moved(
+        lambda: vs.commit_verify_lanes(CHAIN, bid, HEIGHT, kept))
+    assert counts == (0, 0) and seen == []
+    # every entry nil: no record to stay in
+    none = Commit(block_id=bid, precommits=[None] * 4)
+    _, counts, seen = moved(lambda: Commit.decode(Reader(none.encode())))
+    assert counts == (0, 1) and seen == [(0, "absent")]
     _, counts, seen = moved(lambda: Commit.decode(Reader(stray.encode())))
     assert counts == (0, 1) and seen == [(HEIGHT, "votes")]
     _, counts, seen = moved(lambda: Commit.decode(Reader(for_nil.encode())))
@@ -271,6 +304,7 @@ def test_the_counter_pair_and_the_instant_say_which_path_a_decode_took():
     assert counts == (0, 0) and seen == []
     old, counts, seen = moved(lambda: Commit.decode(Reader(stale.encode())))
     assert counts == (1, 0) and seen == []
+    assert REGISTRY.commits_decoded_wire_absent.value - wire_absent0 == 1
     with pytest.raises(ValueError, match="commit height 4 != 7"):
         _, counts, seen = moved(
             lambda: vs.commit_verify_lanes(CHAIN, bid, HEIGHT, old))

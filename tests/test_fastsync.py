@@ -579,14 +579,17 @@ def test_fast_sync_of_100_validators_stores_the_bytes_object_commits_would(
     from tendermint_tpu.utils.metrics import REGISTRY
     n, cid = 21, "wire-commit-chain"
     before = (REGISTRY.commits_decoded_wire.value,
-              REGISTRY.commits_decoded_objects.value)
+              REGISTRY.commits_decoded_objects.value,
+              REGISTRY.commits_decoded_wire_absent.value)
     bc = fast_sync_in_process(cid, n, 8, sqlite_dir=str(tmp_path),
                               n_vals=100)
     # the syncer decoded blocks 2..n from the wire; nothing took the
     # object path (the source serves stored bytes and decodes nothing
-    # but what it loads to serve, which is wire-backed too)
+    # but what it loads to serve, which is wire-backed too), and no
+    # commit of this chain holds a nil entry
     assert REGISTRY.commits_decoded_wire.value - before[0] >= n - 2
     assert REGISTRY.commits_decoded_objects.value == before[1]
+    assert REGISTRY.commits_decoded_wire_absent.value == before[2]
     top = bc.store.height
     assert top >= n - 1
 
@@ -651,11 +654,12 @@ def _window_through_receive(blocks_encoded, batch_size):
 
 def test_a_regular_window_records_nothing_and_a_pruned_commit_one_instant():
     """What the flight recorder and the counter pair say of a 64-block
-    window: nothing of commits that stayed in their bytes (6,400 lanes
-    must not buy 64 ring writes on the receive threads); of one pruned
-    commit one `commit.object_form` instant with its height, and the
-    blame the parent gives (`pool.redo` of the successor, which carried
-    it)."""
+    window: nothing of full commits that stayed in their bytes (6,400
+    lanes must not buy 64 ring writes on the receive threads); of one
+    pruned commit, which stays in its bytes as well, one
+    `commit.wire_absent` instant with its height and its nil entries,
+    and the blame it always got (`pool.redo` of the successor, which
+    carried it)."""
     from tendermint_tpu.types import Block, Commit
     from tendermint_tpu.utils import tracing
     from tendermint_tpu.utils.metrics import REGISTRY
@@ -692,11 +696,13 @@ def test_a_regular_window_records_nothing_and_a_pruned_commit_one_instant():
     wire0 = REGISTRY.commits_decoded_wire.value
     bc = _window_through_receive(
         encoded[:39] + [evil.encode()] + encoded[40:], 64)
-    assert REGISTRY.commits_decoded_wire.value - wire0 == 63
-    assert REGISTRY.commits_decoded_objects.value - objects0 == 1
-    assert since(t0, "commit.object_form") == [
-        {"height": 39, "reason": "absent"}]
+    # one vote of four left: its record is regular, so are the bytes
+    assert REGISTRY.commits_decoded_wire.value - wire0 == 64
+    assert REGISTRY.commits_decoded_objects.value == objects0
+    assert since(t0, "commit.object_form") == []
+    assert since(t0, "commit.wire_absent") == [{"height": 39, "absent": 3}]
     assert bc._sync_step() is False
     assert bc.state.last_block_height == 0
     assert [a["height"] for a in since(t0, "pool.redo")] == [40]
-    assert len(since(t0, "commit.object_form")) == 1
+    assert since(t0, "commit.object_form") == []
+    assert len(since(t0, "commit.wire_absent")) == 1

@@ -468,13 +468,17 @@ def test_wire_commit_roundtrip_and_lanes():
     lo2 = vs.commit_verify_lanes("cc-chain", other, 5, wc)
     assert lo2[3].sum() == 0 and lo2[5] == vs.total_voting_power()
 
-    # sparse commit (missing votes) decodes vote by vote and keeps lane
-    # alignment
+    # sparse commit (missing votes) stays in its bytes too and keeps
+    # lane alignment: a present vote's member is its POSITION
     commit.precommits[3] = None
     sparse = Commit.decode(Reader(commit.encode()))
-    assert sparse.wire_columns() is None
+    assert sparse.wire_columns()[5] == (3,) and sparse.num_sigs() == 7
+    assert sparse == commit and sparse.encode() == commit.encode()
     ls = vs.commit_verify_lanes("cc-chain", bid, 5, sparse)
     assert list(ls[4]) == [i for i in range(8) if i != 3]
+    lo3 = vs.commit_verify_lanes("cc-chain", bid, 5, commit)
+    assert all(np.array_equal(a, b) for a, b in zip(ls, lo3))
+    vs.verify_commit("cc-chain", bid, 5, sparse)
 
 
 def test_accum_array_rotation_equivalence():

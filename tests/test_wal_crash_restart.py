@@ -166,7 +166,10 @@ def test_crashed_node_catches_up_over_fastsync(tmp_path):
         try:
             connect_switches(sync_sw, src_sw)
             deadline = time.time() + 60
-            while (store.height < N_CATCHUP_BLOCKS - 1
+            # the store moves a block before the state does: wait for
+            # both, or the app hash below is read between the two
+            while ((store.height < N_CATCHUP_BLOCKS - 1
+                    or bc.state.last_block_height < N_CATCHUP_BLOCKS - 1)
                    and time.time() < deadline):
                 time.sleep(0.02)
             assert store.height >= N_CATCHUP_BLOCKS - 1, \
