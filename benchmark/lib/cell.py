@@ -177,6 +177,32 @@ def absent_report(index: dict, heights: list[int], n_vals: int) -> str:
             "hold an absent precommit")
 
 
+def set_answers_differ(answered: list[dict], state_validators, vs) -> list:
+    """The two terms of `rpc_answers_differ` that hold a node to the
+    builder's set `vs` of the height after its tip, by name where they
+    differ: `/validators` answers that set's public keys each with its
+    voting power, in set order, and the state's set hashes to its hash
+    (which covers the powers too)."""
+    return [name for name, differs in (
+        ("/validators",
+         [(v["pub_key"], v["voting_power"]) for v in answered] !=
+         [(v.pub_key.bytes_.hex(), v.voting_power) for v in vs.validators]),
+        ("state validators hash", state_validators.hash() != vs.hash()))
+        if differs]
+
+
+def powers_report(seed: int, n_vals: int, valset: dict | None,
+                  powers: dict | None, heights: list[int]) -> str:
+    """What traffic the interval was, for the run's log: how many of
+    its heights carry a diff that moves a voting power, so that the
+    header of the height after holds another `validators_hash`; by
+    `chain.power_txs`, which the builder made the blocks from."""
+    moved = sum(bool(chain.power_txs(seed, n_vals, valset, powers, h))
+                for h in heights)
+    return (f"powers: {moved} of {len(heights)} heights of the interval "
+            f"change a voting power (plan {powers})")
+
+
 def precompile_running() -> bool:
     return any(t.name == "crypto-precompile" and t.is_alive()
                for t in threading.enumerate())
@@ -328,8 +354,10 @@ def run_cell(root: str, cell: dict, seed: int, seconds: float, trace: bool,
     cfg, traffic = cell["config"], cell["traffic"]
     n_vals, n_sources = cfg["validators"], cfg["source_peers"]
     valset = traffic.get("valset")    # the mix's validator-set plan
-    absent = traffic.get("absent")    # and its plan of absent precommits
-    chain.absent_at(seed, n_vals, valset, absent, 1)   # malformed: here
+    absent = traffic.get("absent")    # its plan of absent precommits
+    powers = traffic.get("powers")    # and its plan of voting powers
+    # a malformed plan, or two that do not go together: here
+    chain.check_plans(seed, n_vals, valset, absent, powers)
     n_blocks = chain_blocks(cell, seconds)
     workdir = tempfile.mkdtemp(prefix="tmbench_")
     kids = children_mod.Children(root)
@@ -348,7 +376,8 @@ def run_cell(root: str, cell: dict, seed: int, seconds: float, trace: bool,
                        "traffic": traffic["block"],
                        "index_path": index_path,
                        **({"valset": valset} if valset else {}),
-                       **({"absent": absent} if absent else {})}, f)
+                       **({"absent": absent} if absent else {}),
+                       **({"powers": powers} if powers else {})}, f)
         source = kids.start("benchmark.lib.source_child", spec_path)
         prober = kids.start("benchmark.lib.prober_child")
         say(f"children: source {source.pid}, prober {prober.pid}")
@@ -521,10 +550,12 @@ def run_cell(root: str, cell: dict, seed: int, seconds: float, trace: bool,
         st = rpc.status()
         blk = rpc.block(height=tip)["block"]
         vals = rpc.validators()["validators"]
-        # the set of the height after the tip, as the builder has it (the
-        # genesis set where the mix states no plan): what the node's
-        # state and its RPC have to hold, and what the control signs with
-        val_seeds, vs = chain.valset_at(seed, n_vals, valset, tip + 1)
+        # the set of the height after the tip, members and powers, as
+        # the builder has it (the genesis set where the mix states no
+        # plan): what the node's state and its RPC have to hold, and what
+        # the control signs with
+        val_seeds, vs = chain.valset_at(seed, n_vals, valset, tip + 1,
+                                        powers)
         sets = [s["from_height"] for s in index["valsets"]]
         say(f"validators: the builder's set {sum(h <= tip + 1 for h in sets)}"
             f" of {len(sets)} holds at height {tip + 1}")
@@ -536,11 +567,8 @@ def run_cell(root: str, cell: dict, seed: int, seconds: float, trace: bool,
             ("/block hash", blk["block_hash"] != index["block_hash"][tip - 1]),
             ("/block height", blk["header"]["height"] != tip),
             ("/block precommits",
-             blk["last_commit"]["precommits"] != held_at_tip),
-            ("/validators", [v["pub_key"] for v in vals] !=
-             [v.pub_key.bytes_.hex() for v in vs.validators]),
-            ("state validators hash",
-             bc.state.validators.hash() != vs.hash())) if differs]
+             blk["last_commit"]["precommits"] != held_at_tip))
+            if differs] + set_answers_differ(vals, bc.state.validators, vs)
         checks.at_most("rpc_answers_differ", "/status, /block, /validators "
                        "answers and the state's validator set that differ "
                        f"from the builder's {rpc_wrong}", len(rpc_wrong), 0)
@@ -616,6 +644,7 @@ def run_cell(root: str, cell: dict, seed: int, seconds: float, trace: bool,
             f"{headroom * height_close / (n_blocks - 1):.2f} of the "
             f"parent's expected, the tip at {headroom:.2f}")
         say(absent_report(index, acct["heights"], n_vals))
+        say(powers_report(seed, n_vals, valset, powers, acct["heights"]))
         reduced = None
         if trace and platform == "tpu":
             reduced = reduce_device_trace(devtrace, trace_dir, spans,

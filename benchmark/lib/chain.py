@@ -24,6 +24,13 @@ function that says who is silent at a height: the builder reads it, the
 source child's index holds its count a height (`signed`), and the
 harness's check sums that.
 
+A traffic mix may state a plan of voting powers (`powers`, README): then
+members' powers move where the plan says, by `val:` txs as the `valset`
+plan's, and every header holds the hash of its own height's powers.
+`powers_at` is the one function that says which power each member of a
+height has; `valset_at` hands it on, so the builder, the index and the
+check read it too.
+
 Nothing in this module may import jax: it runs in the source child.
 """
 
@@ -52,20 +59,29 @@ def val_seed(seed: int, i: int) -> bytes:
     return hashlib.sha256(b"tm-bench/%d/val/%d" % (seed, i)).digest()
 
 
+@functools.lru_cache(maxsize=4096)
 def pub_of(key_seed: bytes) -> bytes:
     return (Ed25519PrivateKey.from_private_bytes(key_seed).public_key()
             .public_bytes_raw())
 
 
-def _set_of(seeds: list[bytes]):
-    """(seeds aligned with the set's validator order, ValidatorSet).
+@functools.lru_cache(maxsize=4096)
+def _pub_key_of(key_seed: bytes):
+    """The program's `PubKey` of a seed: immutable, and it keeps its
+    address, so a chain that makes a set a height hashes each key once."""
+    from tendermint_tpu.types.keys import PubKey
+    return PubKey(pub_of(key_seed))
+
+
+def _set_of(seeds: list[bytes], powers=None):
+    """(seeds aligned with the set's validator order, ValidatorSet);
+    `powers` is aligned with `seeds`, POWER each where none are given.
     Public keys are OpenSSL's; the program's ValidatorSet only orders
     them (by address) and hashes the set for the headers."""
     from tendermint_tpu.types import Validator, ValidatorSet
-    from tendermint_tpu.types.keys import PubKey
-    pubs = {s: pub_of(s) for s in seeds}
-    vs = ValidatorSet([Validator(PubKey(pubs[s]), POWER) for s in seeds])
-    by_addr = {PubKey(pubs[s]).address: s for s in seeds}
+    vs = ValidatorSet([Validator(_pub_key_of(s), p) for s, p in zip(
+        seeds, powers or [POWER] * len(seeds), strict=True)])
+    by_addr = {_pub_key_of(s).address: s for s in seeds}
     return [by_addr[v.address] for v in vs.validators], vs
 
 
@@ -107,6 +123,73 @@ def valset_members(seed: int, n: int, plan: dict | None,
     return members
 
 
+POWERS_KEYS = ("change_every_blocks", "members", "min", "max")
+
+
+def _powers_numbers(plan: dict, n: int) -> tuple[int, int, int, int]:
+    """(change_every_blocks, members, min, max) of a `powers` plan for
+    sets of n members, or a ValueError that names the plan."""
+    numbers = tuple(plan.get(k) for k in POWERS_KEYS)
+    if (set(plan) != set(POWERS_KEYS)
+            or not all(type(x) is int for x in numbers)
+            or numbers[0] < 1 or not 1 <= numbers[1] <= n
+            or not 1 <= numbers[2] <= numbers[3] < 2**32):
+        raise ValueError(
+            f"powers plan {plan!r} for {n} validators: needs "
+            "change_every_blocks >= 1, 1 <= members <= n and 1 <= min <= "
+            "max < 2**32 (a power is drawn from four bytes of a hash), "
+            f"whole numbers all, and the keys {POWERS_KEYS}, no other")
+    return numbers
+
+
+def _frozen(plan: dict | None) -> tuple | None:
+    return tuple(sorted(plan.items())) if plan else None
+
+
+@functools.lru_cache(maxsize=8)
+def _power_epochs(seed: int, n: int, valset: tuple | None,
+                  powers: tuple) -> list[dict[int, int]]:
+    """A chain's memo of `powers_at`: entry c is key index -> power for
+    the set of height c x change_every_blocks + 1, the set that the c-th
+    change made.  `powers_at` grows it a change at a time and never
+    edits an entry; the genesis set, entry 0, is POWER throughout."""
+    return [dict.fromkeys(range(n), POWER)]
+
+
+def powers_at(seed: int, n: int, valset_plan: dict | None,
+              powers_plan: dict | None, height: int) -> tuple[int, ...]:
+    """The voting powers of the set of `height` (>= 1), aligned with
+    `valset_members(seed, n, valset_plan, height)`, under a traffic mix's
+    `powers` plan.  The genesis set is POWER throughout.  The diffs of a
+    height h with h % change_every_blocks == 0 make the powers of h + 1:
+    the `members` members of the set of h whose
+    sha256(seed, "power-pick", h, index) is least are redrawn to min +
+    sha256(seed, "power", h, index)[:4] % (max - min + 1) (one that the
+    `valset` plan takes out at h leaves all the same), every other
+    member keeps what it had, and a key that joins joins at POWER.  No
+    plan: POWER for every member at every height."""
+    members = valset_members(seed, n, valset_plan, height)
+    if not powers_plan:
+        return (POWER,) * len(members)
+    every, redrawn, lo, hi = _powers_numbers(powers_plan, n)
+    epochs = _power_epochs(seed, n, _frozen(valset_plan),
+                           _frozen(powers_plan))
+    for c in range(len(epochs), (height - 1) // every + 1):
+        h = c * every
+        now = valset_members(seed, n, valset_plan, h)
+        picked = sorted(now, key=lambda i: hashlib.sha256(
+            b"tm-bench/%d/power-pick/%d/%d" % (seed, h, i)).digest())[:redrawn]
+        after = dict(epochs[-1])
+        for i in picked:
+            after[i] = lo + int.from_bytes(hashlib.sha256(
+                b"tm-bench/%d/power/%d/%d" % (seed, h, i)).digest()[:4],
+                "big") % (hi - lo + 1)
+        epochs.append({i: after.get(i, POWER) for i in valset_members(
+            seed, n, valset_plan, h + 1)})
+    held = epochs[(height - 1) // every]
+    return tuple(held.get(i, POWER) for i in members)
+
+
 ABSENT_KEYS = ("late_per_1000", "down", "down_for_blocks")
 
 
@@ -135,7 +218,8 @@ def _down(seed: int, members: tuple[int, ...], k: int,
 
 
 def absent_at(seed: int, n: int, valset_plan: dict | None,
-              absent_plan: dict | None, height: int) -> tuple[int, ...]:
+              absent_plan: dict | None, height: int,
+              powers_plan: dict | None = None) -> tuple[int, ...]:
     """Key indices, in rising order, of the members of the set of
     `height` whose precommit the commit of `height` does not hold, under
     a traffic mix's `absent` plan: a subset of `valset_members(seed, n,
@@ -143,10 +227,16 @@ def absent_at(seed: int, n: int, valset_plan: dict | None,
     `(height - 1) // down_for_blocks` come first, then the late ones
     (sha256(seed, height, index)[:4] % 1000 < late_per_1000) in the order
     of their hash, and the list ends at the largest count that leaves
-    MORE than 2/3 of the set's power signed (every power is POWER).  No
-    plan: nobody."""
+    MORE than 2/3 of the set's power signed (every power is POWER: the
+    cut counts heads, so a mix that states a `powers` plan beside the
+    `absent` plan is refused).  No plan: nobody."""
     if not absent_plan:
         return ()
+    if powers_plan:
+        raise ValueError(
+            f"absent plan {absent_plan!r} together with powers plan "
+            f"{powers_plan!r}: the +2/3 cut of the absent plan counts heads "
+            "of equal power, so a traffic mix may state one of the two")
     late, down, every = _absent_numbers(absent_plan)
     members = valset_members(seed, n, valset_plan, height)
     # 3 x (len - silent) > 2 x len, in units of POWER
@@ -165,26 +255,60 @@ def absent_at(seed: int, n: int, valset_plan: dict | None,
     return tuple(sorted(silent[:most]))
 
 
-def valset_at(seed: int, n: int, plan: dict | None, height: int):
+def check_plans(seed: int, n: int, valset_plan: dict | None,
+                absent_plan: dict | None, powers_plan: dict | None) -> None:
+    """A ValueError that names the plan where a traffic mix states one
+    that cannot be run, or two that do not go together: what the builder
+    and `run_cell` call before anything is started."""
+    absent_at(seed, n, valset_plan, absent_plan, 1, powers_plan)
+    powers_at(seed, n, valset_plan, powers_plan, 1)
+
+
+def valset_at(seed: int, n: int, plan: dict | None, height: int,
+              powers_plan: dict | None = None):
     """(signing seeds in set order, ValidatorSet) of `height`: the set
-    that signs the commit of `height` and whose hash its header holds."""
-    return _set_of([val_seed(seed, i)
-                    for i in valset_members(seed, n, plan, height)])
+    that signs the commit of `height` and whose hash its header holds,
+    with the powers `powers_at` gives its members."""
+    return _set_of(
+        [val_seed(seed, i) for i in valset_members(seed, n, plan, height)],
+        powers_at(seed, n, plan, powers_plan, height))
 
 
-def valset_txs(seed: int, n: int, plan: dict | None, h: int) -> list[bytes]:
-    """The `val:` txs height h carries under the plan: power 0 for each
-    member that leaves (by key index), then POWER for each key that
-    joins; none where the plan changes nothing at h."""
-    if not plan or h % plan["change_every_blocks"]:
-        return []
-    old = valset_members(seed, n, plan, h)
-    new = valset_members(seed, n, plan, h + 1)
-    return [VAL_TX_PREFIX + b"%s/%d" % (
+def _val_tx(seed: int, i: int, power: int) -> bytes:
+    return VAL_TX_PREFIX + b"%s/%d" % (
         pub_of(val_seed(seed, i)).hex().encode(), power)
-        for idxs, power in ((sorted(set(old) - set(new)), 0),
-                            (sorted(set(new) - set(old)), POWER))
-        for i in idxs]
+
+
+def power_txs(seed: int, n: int, plan: dict | None,
+              powers_plan: dict | None, h: int) -> list[bytes]:
+    """The `val:` txs of height h that move a power: its new power for
+    each member of the sets of both h and h + 1 whose power `powers_at`
+    changes between them, by key index; none where it changes none."""
+    if not powers_plan or h % powers_plan["change_every_blocks"]:
+        return []
+    was = dict(zip(valset_members(seed, n, plan, h),
+                   powers_at(seed, n, plan, powers_plan, h)))
+    return [_val_tx(seed, i, power) for i, power in sorted(zip(
+        valset_members(seed, n, plan, h + 1),
+        powers_at(seed, n, plan, powers_plan, h + 1)))
+        if was.get(i, power) != power]
+
+
+def valset_txs(seed: int, n: int, plan: dict | None, h: int,
+               powers_plan: dict | None = None) -> list[bytes]:
+    """The `val:` txs height h carries under the two plans: power 0 for
+    each member that leaves (by key index), then POWER for each key that
+    joins, then `power_txs`; none where neither plan changes anything at
+    h."""
+    txs = []
+    if plan and not h % plan["change_every_blocks"]:
+        old = valset_members(seed, n, plan, h)
+        new = valset_members(seed, n, plan, h + 1)
+        txs = [_val_tx(seed, i, power)
+               for idxs, power in ((sorted(set(old) - set(new)), 0),
+                                   (sorted(set(new) - set(old)), POWER))
+               for i in idxs]
+    return txs + power_txs(seed, n, plan, powers_plan, h)
 
 
 def parse_val_tx(tx: bytes) -> tuple[bytes, int]:
@@ -347,10 +471,11 @@ def block_txs(block: dict, seed: int, h: int) -> list[bytes]:
             for i, x in enumerate(heads)]
 
 
-def _next_set(vs, diffs, seed: int, n: int, plan: dict | None, h: int):
+def _next_set(vs, diffs, seed: int, n: int, plan: dict | None, h: int,
+              powers_plan: dict | None = None):
     """The set of h + 1 as `valset_at` gives it, which has to be the set
     of h with the app's diffs of h applied, as the program will have it."""
-    seeds, new = valset_at(seed, n, plan, h + 1)
+    seeds, new = valset_at(seed, n, plan, h + 1, powers_plan)
     pubs = {v.pub_key.bytes_: v.voting_power for v in vs.validators}
     for pub, power in diffs:
         if power:
@@ -375,7 +500,7 @@ def _silent_positions(seed: int, n: int, valset: dict | None,
 def build_chain(chain_id: str, seeds, vs, n_blocks: int, block_spec: dict,
                 seed: int, signers: Signers | None = None,
                 keep_objects: bool = False, valset: dict | None = None,
-                absent: dict | None = None):
+                absent: dict | None = None, powers: dict | None = None):
     """Heights 1..n_blocks, each block embedding the +2/3 LastCommit of
     its predecessor.  `seeds`, `vs` are the genesis set; under a `valset`
     plan a height's header holds the hash of ITS set, its commit is
@@ -384,17 +509,22 @@ def build_chain(chain_id: str, seeds, vs, n_blocks: int, block_spec: dict,
     `absent` plan the commit of h holds None (upstream's nil entry) at
     the positions of the members `absent_at` names: every member signs,
     as without the plan, and the silent ones' signatures are dropped.
+    Under a `powers` plan the set of a height holds the powers
+    `powers_at` gives it: the genesis set (`vs`) is POWER throughout, and
+    a height whose `val:` txs move a power is followed by a set of the
+    same members, and signing keys, with another hash.
     Returns a dict of per-height lists (index h-1): `encoded`,
     `block_hash`, `app_hash` (state after h), `signed` (the precommits
     the commit of h holds), with `keep_objects` also `objects` = (block,
     part_set, seen_commit); and `valsets`, a (first height,
-    ValidatorSet) for every set that signs."""
+    ValidatorSet as it stands at that height) for every set of MEMBERS
+    that signs: a change of powers alone adds none."""
     from tendermint_tpu.types import (TYPE_PRECOMMIT, Block, BlockID, Commit,
                                       EMPTY_COMMIT, Vote, ZERO_BLOCK_ID,
                                       canonical)
     from tendermint_tpu.types.part_set import PartSet
     n = len(seeds)
-    absent_at(seed, n, valset, absent, 1)     # a malformed plan: here
+    check_plans(seed, n, valset, absent, powers)    # a malformed plan: here
     own = signers is None
     signers = signers or Signers(seeds)
     app = RefKVStore()
@@ -406,7 +536,7 @@ def build_chain(chain_id: str, seeds, vs, n_blocks: int, block_spec: dict,
     try:
         for h in range(1, n_blocks + 1):
             txs = block_txs(block_spec, seed, h) + valset_txs(
-                seed, n, valset, h)
+                seed, n, valset, h, powers)
             block = Block.make(chain_id=chain_id, height=h,
                                time_ns=GENESIS_TIME_NS + h, txs=txs,
                                last_commit=last_commit,
@@ -437,11 +567,13 @@ def build_chain(chain_id: str, seeds, vs, n_blocks: int, block_spec: dict,
                 out["objects"].append((block, ps, seen))
             last_commit, last_block_id = seen, bid
             if diffs:
-                seeds, vs = _next_set(vs, diffs, seed, n, valset, h)
-                signers.use(seeds)
+                was = seeds
+                seeds, vs = _next_set(vs, diffs, seed, n, valset, h, powers)
                 vals_hash = vs.hash()
-                addrs = [v.address for v in vs.validators]
-                out["valsets"].append((h + 1, vs))
+                if seeds != was:          # other members, not powers alone
+                    signers.use(seeds)
+                    addrs = [v.address for v in vs.validators]
+                    out["valsets"].append((h + 1, vs))
     finally:
         if own:
             signers.close()

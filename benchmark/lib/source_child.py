@@ -9,12 +9,15 @@ reports ready), so it cannot touch the chip or the node's GIL.
     python -m benchmark.lib.source_child <spec.json>
 
 spec: seed, chain_id, n_vals, n_blocks, n_sources, traffic, index_path,
-`valset` where the traffic mix states a validator-set plan and `absent`
-where it states a plan of absent precommits.
+`valset` where the traffic mix states a validator-set plan, `absent`
+where it states a plan of absent precommits and `powers` where it states
+a plan of voting powers.
 When every peer listens it writes the per-height index (block hashes,
 app hashes, encoded sizes, and `signed`: the precommits the commit of
-the height holds, index h - 1; and `valsets`: for each set that signs,
-its first height, its hash and its public keys in set order) to
+the height holds, index h - 1; and `valsets`: for each set of members
+that signs, its first height, its hash at that height and its public
+keys in set order; a `powers` plan adds no entry and no key: the check
+asks `chain.valset_at` for the powers of a height) to
 `index_path`, prints one JSON line
 {"ready": ..., "genesis": ..., "addrs": [...]} and serves until its
 stdin closes.
@@ -79,11 +82,12 @@ def main(argv) -> int:
     with open(argv[1]) as f:
         spec = json.load(f)
     from benchmark.lib import chain
-    plan = spec.get("valset")
-    seeds, vs = chain.valset_at(spec["seed"], spec["n_vals"], plan, 1)
+    plan, powers = spec.get("valset"), spec.get("powers")
+    seeds, vs = chain.valset_at(spec["seed"], spec["n_vals"], plan, 1,
+                                powers)
     built = chain.build_chain(spec["chain_id"], seeds, vs, spec["n_blocks"],
                               spec["traffic"], spec["seed"], valset=plan,
-                              absent=spec.get("absent"))
+                              absent=spec.get("absent"), powers=powers)
     t_built = time.monotonic()
     gen = chain.genesis_dict(spec["chain_id"], vs)
     with open(spec["index_path"], "w") as f:

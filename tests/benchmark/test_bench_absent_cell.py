@@ -85,27 +85,109 @@ def test_the_mix_is_empty_blocks_block_under_the_plan_pr_43_measured():
     assert MIX["chain"]["catchup-absent-100v"] == want
 
 
+def lists_hold(root: str, bench: dict) -> None:
+    """What this file holds of `bench`'s three lists, read with the
+    files under `root`: the cell is in `workloads` once and its
+    configuration in `configs` once, wherever; PR 44's six metrics are in
+    `per_layer` once each and in PR 44's order RELATIVE TO EACH OTHER;
+    and a cell's metrics come in the list's order.  Nothing here holds
+    the END of a list: a later PR appends its cell, its configuration and
+    its metrics after these, and a pin of the tail would close the list
+    to it (as `per_layer[-6:]` closed it to PR 46's metric)."""
+    cell = cell_mod.load_cell(root, CELL)
+    names = [m["name"] for m in cell["per_layer"]]
+    assert names == [m["name"] for m in bench["per_layer"]
+                     if "workloads" not in m]
+    assert set(NEW_METRICS) <= set(names)
+    assert not any(CELL in m.get("workloads", ())
+                   for m in bench["per_layer"] + bench["end_to_end"])
+    assert [w["name"] for w in bench["workloads"]].count(CELL) == 1
+    assert [c["name"] for c in bench["configs"]].count(
+        "catchup-absent-100v") == 1
+    listed = [m["name"] for m in bench["per_layer"]]
+    assert all(listed.count(n) == 1 for n in NEW_METRICS)
+    at = [listed.index(n) for n in NEW_METRICS]
+    assert at == sorted(at)
+
+
 def test_the_cell_loads_with_its_traffic_and_every_unlisted_metric():
     cell = cell_mod.load_cell(REPO, CELL)
     assert cell["chips"] == 1 and cell["config"] == OURS
     assert cell["traffic"] == MIX and cell["traffic_name"] == MIX["name"]
     assert {m["name"] for m in cell["end_to_end"]} == {
         "sync_blocks_per_s", "boot_to_first_window_s", "setup_s"}
-    names = [m["name"] for m in cell["per_layer"]]
-    assert names == [m["name"] for m in BENCH["per_layer"]
-                     if "workloads" not in m]
-    assert set(NEW_METRICS) <= set(names)
-    assert not any(CELL in m.get("workloads", ())
-                   for m in BENCH["per_layer"] + BENCH["end_to_end"])
     # the accepted kernel's metrics read this cell as they read cell 1
     assert {"kernel.verify_ms", "verify_grouped_templated_roofline",
             "device.idle_pct", "device.hbm_peak_MiB",
-            "rpc.status_p95_ms"} <= set(names)
-    # the cell is the one entry, its configuration the one entry
-    assert [w["name"] for w in BENCH["workloads"]].count(CELL) == 1
-    assert BENCH["workloads"][-1]["name"] == CELL
-    assert BENCH["configs"][-1]["name"] == "catchup-absent-100v"
-    assert [m["name"] for m in BENCH["per_layer"][-6:]] == list(NEW_METRICS)
+            "rpc.status_p95_ms"} <= {m["name"] for m in cell["per_layer"]}
+    lists_hold(REPO, BENCH)
+
+
+def _write(path: str, obj: dict) -> None:
+    with open(path, "w") as f:
+        json.dump(obj, f)
+
+
+def test_a_seventh_cell_configuration_and_metric_appended_break_no_order(
+        tmp_path):
+    """A copy of `BENCHMARK.json` with a made-up cell, configuration and
+    per-layer metric appended, each with its files, as a `model_config`
+    PR would bring them: every assertion under `tests/benchmark/` on the
+    ORDER of the three lists still holds of the copy (this file's, above;
+    and that a cell's metrics come in the list's order, which
+    `test_bench_wide_set.py` holds too), all seven cells load, and the
+    six accepted cells read the new unlisted metric after all they had."""
+    import copy
+    import shutil
+    root = str(tmp_path)
+    for part in ("configs", "traffic", "layers"):
+        shutil.copytree(os.path.join(REPO, "benchmark", part),
+                        os.path.join(root, "benchmark", part))
+    _write(os.path.join(root, "benchmark", "configs", "made-up-7v.json"),
+           dict(PLAIN, name="made-up-7v", validators=7))
+    _write(os.path.join(root, "benchmark", "traffic", "made-up-mix.json"),
+           dict(_json("benchmark", "traffic", "empty-blocks.json"),
+                name="made-up-mix"))
+    _write(os.path.join(root, "benchmark", "layers", "reactor.made_up.json"),
+           dict(reducers.load_layer(REPO, "reactor.commit_absent_form"),
+                name="reactor.made_up"))
+    bench = copy.deepcopy(BENCH)
+    bench["configs"].append({
+        "name": "made-up-7v", "source": "none: a test's",
+        "file": "benchmark/configs/made-up-7v.json", "reduced": [],
+        "why": "a seventh configuration, appended"})
+    bench["workloads"].append({
+        "name": "made-up-7v.made-up-mix", "config": "made-up-7v",
+        "traffic": "made-up-mix", "chips": 1,
+        "why": "a seventh cell, appended"})
+    bench["per_layer"].append(dict(
+        next(m for m in BENCH["per_layer"]
+             if m["name"] == "reactor.commit_absent_form"),
+        name="reactor.made_up"))
+    _write(os.path.join(root, "BENCHMARK.json"), bench)
+    assert bench["workloads"][-1]["name"] != CELL      # the tails moved
+    assert bench["configs"][-1]["name"] != "catchup-absent-100v"
+    assert bench["per_layer"][-1]["name"] not in NEW_METRICS
+    lists_hold(root, bench)
+    cells = [w["name"] for w in bench["workloads"]]
+    assert len(cells) == len(BENCH["workloads"]) + 1 == len(set(cells))
+    for name in cells:
+        got = cell_mod.load_cell(root, name)
+        assert [m["name"] for m in got["per_layer"]] == [
+            m["name"] for m in bench["per_layer"]
+            if "workloads" not in m or name in m["workloads"]]
+        assert got["per_layer"][-1]["name"] == "reactor.made_up"
+        if name != cells[-1]:
+            before = cell_mod.load_cell(REPO, name)
+            assert got["per_layer"][:-1] == before["per_layer"]
+            assert got["config"] == before["config"]
+            assert got["traffic"] == before["traffic"]
+    made_up = cell_mod.load_cell(root, cells[-1])
+    assert made_up["config"]["validators"] == 7
+    assert made_up["traffic"]["name"] == "made-up-mix"
+    # and of the tree as it stands, which this PR appended one metric to
+    lists_hold(REPO, BENCH)
+    assert BENCH["per_layer"][-1]["name"] not in NEW_METRICS
 
 
 @pytest.mark.parametrize("seconds,blocks", [(5, 12033), (45, 29761),
@@ -265,6 +347,13 @@ def test_a_traced_rehearsal_over_an_absent_chain_reads_all_six():
     windows = heights // 64
     absent = m["reactor.commit_absent_form"]
     assert m["reactor.commit_object_form"] == absent <= heights + 64
+    # a commit of this chain that did not go vote by vote stayed in its
+    # wire bytes with its nil entry: each decoded commit is one of the
+    # two (counted where it is DECODED, up to the pool's 300 requests
+    # ahead of the height applied)
+    wire = m["reactor.commit_wire_absent"]
+    assert wire >= 0.0 and abs(wire + absent - heights) <= 400
+    assert wire + absent > 0
     # a window the look-ahead verified and the main loop verified again
     # (a dropped look-ahead) counts twice
     assert windows - 1 <= (m["reactor.lane_windows_per_block"]

@@ -80,7 +80,7 @@ def test_a_check_handed_a_plan_the_chain_was_not_built_to_says_no():
     wrong = """
 from benchmark.lib import chain as _chain
 _real = _chain.valset_at
-_chain.valset_at = lambda seed, n, plan, h: _real(
+_chain.valset_at = lambda seed, n, plan, h, powers=None: _real(
     seed, n, {"change_every_blocks": 1, "swap": 1}, h)
 """
     result, out = benchutil.rehearse(seed=2**31 + 42, trace=False,
