@@ -147,6 +147,51 @@ def stated_as_run(cfg: dict, node_cfg=None) -> None:
                            f"{differ}; window {reactor.DEFAULT_BATCH}")
 
 
+def returns_val_diffs(app) -> bool:
+    """Whether `app`, a fresh in-process app, returns a well-formed
+    `val:<pubkey>/<power>` tx (the builder's own, `chain._val_tx`) as the
+    `EndBlock` diff of the block that carries it: asked of the app, never
+    looked up by its name."""
+    tx = chain._val_tx(0, 0, chain.POWER + 1)
+    app.deliver_tx(tx)
+    return [(d.pub_key, d.power) for d in app.end_block(1).diffs] == [
+        chain.parse_val_tx(tx)]
+
+
+def app_fits_plans(cfg: dict, traffic: dict) -> None:
+    """A ValueError that names the configuration, the mix, the plan and
+    the app unless the app the configuration states (the program's
+    default where its file names none) is in the program's registry of
+    in-process apps and returns `val:` txs as `EndBlock` diffs IF AND
+    ONLY IF the mix states a `valset` or a `powers` plan.  Under such a
+    plan an app that returns none stores the txs, no set moves and the
+    second header names a set the node does not hold; without one, an app
+    that does is another deployment than the one the file describes."""
+    from tendermint_tpu.abci.app import create_app
+    from tendermint_tpu.config import Config
+    app = cfg.get("app", Config().base.proxy_app)
+    plans = [p for p in ("valset", "powers") if traffic.get(p)]
+    stated = "a " + " and a ".join(plans) if plans else "no valset or powers"
+    what = (f"configuration {cfg.get('name')!r} states the app {app!r} "
+            f"under the mix {traffic.get('name')!r}, which states {stated} "
+            "plan")
+    try:
+        made = create_app(app)
+    except ValueError as e:
+        raise ValueError(f"{what}: {e}") from e
+    diffs = returns_val_diffs(made)
+    if plans and not diffs:
+        raise ValueError(
+            f"{what}: the plan needs an app that returns its "
+            f"`val:<pubkey>/<power>` txs as EndBlock diffs, and {app!r} "
+            "returns none")
+    if diffs and not plans:
+        raise ValueError(
+            f"{what}: {app!r} returns `val:` txs as EndBlock diffs, which "
+            "no block of this mix carries; another deployment than the "
+            "file's")
+
+
 def precommit_limits(index: dict, synced: int, tip: int) -> tuple[int, int]:
     """What the served chain holds, by the index's `signed` (`validators`
     at every height where the mix states no `absent` plan): (the
@@ -358,6 +403,8 @@ def run_cell(root: str, cell: dict, seed: int, seconds: float, trace: bool,
     powers = traffic.get("powers")    # and its plan of voting powers
     # a malformed plan, or two that do not go together: here
     chain.check_plans(seed, n_vals, valset, absent, powers)
+    # an app that does not go with them: here too, not at height 2
+    app_fits_plans(cfg, traffic)
     n_blocks = chain_blocks(cell, seconds)
     workdir = tempfile.mkdtemp(prefix="tmbench_")
     kids = children_mod.Children(root)

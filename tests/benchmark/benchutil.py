@@ -4,14 +4,116 @@ windows), told to expect the CPU, in a process of its own."""
 
 import json
 import os
+import re
 import subprocess
 import sys
 import time
+
+import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 if REPO not in sys.path:
     sys.path.insert(0, REPO)
+
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+
+
+# -- what is held of EVERY configuration and EVERY cell ----------------------------
+# Each rule is a function of (root, bench, entry): the tree that holds the
+# files, its BENCHMARK.json as read, one entry of `configs` or `workloads`.
+# The parametrised tests call them on REPO and the tree's own file, one case
+# an entry; `test_bench_absent_cell.py`'s guard calls them on a copy with a
+# candidate cell appended, so a rule a `model_config` PR would trip on fails
+# in the PR that writes the rule.  A new test that holds something of every
+# configuration or cell is written as such a function and added to
+# ENTRY_RULES (benchmark/README.md, "Adding a cell as files").
+
+def _json_at(root: str, *path) -> dict:
+    with open(os.path.join(root, *path)) as f:
+        return json.load(f)
+
+
+def config_entry_and_file_hold(root: str, bench: dict, c: dict) -> None:
+    assert set(c) == {"name", "source", "file", "reduced", "why"}
+    assert NAME.match(c["name"]) and len(c["source"]) <= 200
+    assert len(c["why"]) <= 200 and len(c["reduced"]) <= 16
+    assert any(c["file"].startswith(p + "/") for p in bench["paths"])
+    cfg = _json_at(root, c["file"])
+    assert cfg["name"] == c["name"] and cfg["reduced"] == c["reduced"]
+    assert cfg["guarantees"] and cfg["assumed"] and cfg["chips"] == 1
+    assert all(NAME.match(k) and k in cfg for k in c["reduced"])
+    assert any(w["config"] == c["name"] for w in bench["workloads"])
+
+
+def workload_entry_and_files_hold(root: str, bench: dict, w: dict) -> None:
+    from benchmark.lib import cell as cell_mod
+    assert set(w) == {"name", "config", "traffic", "chips", "why"}
+    assert NAME.match(w["name"]) and NAME.match(w["traffic"])
+    assert w["name"] == f"{w['config']}.{w['traffic']}"
+    assert w["chips"] in (1, 4) and 1 <= len(w["why"]) <= 200
+    cell = cell_mod.load_cell(root, w["name"])
+    assert cell["traffic"]["name"] == w["traffic"]
+    assert set(cell["traffic"]["block"]) == {"txs_per_block", "tx_bytes",
+                                             "keys"}
+    # the chain served is whole windows plus the block with the last
+    # commit, and grows with the window
+    n = cell_mod.chain_blocks(cell, bench["run_seconds"])
+    assert n % 64 == 1 and n > cell_mod.chain_blocks(cell, 5)
+    assert {m["name"] for m in cell["end_to_end"]} >= {"setup_s"}
+    assert len(cell["end_to_end"]) >= 2 and cell["per_layer"]
+
+
+# the app each ACCEPTED configuration was accepted with (None: the
+# program's default), a record beside the rule: a name that is not here
+# is held to the rule alone
+ACCEPTED_APPS = {"catchup-100v": None, "testnet-4v": None,
+                 "catchup-1ktx-100v": None,
+                 "catchup-churn-100v": "valset_kvstore",
+                 "catchup-300v": None, "catchup-absent-100v": None}
+
+
+def config_states_an_app_that_fits(root: str, bench: dict, c: dict) -> None:
+    """The app a configuration states is in the program's registry and
+    fits the mix of EVERY cell that runs it (`cell.app_fits_plans`:
+    `val:` txs come back as `EndBlock` diffs exactly where the mix states
+    a `valset` or a `powers` plan), a node booted on it is the deployment
+    as stated, and a node on any other app is an error."""
+    from benchmark.lib import cell as cell_mod
+    from tendermint_tpu.config import Config
+    cfg = _json_at(root, c["file"])
+    default = Config().base.proxy_app
+    if c["name"] in ACCEPTED_APPS:
+        assert cfg["app"] == (ACCEPTED_APPS[c["name"]] or default)
+    app = cfg.get("app", default)
+    mixes = [w["traffic"] for w in bench["workloads"]
+             if w["config"] == c["name"]]
+    assert mixes
+    for mix in mixes:              # which looks the app up in the registry
+        cell_mod.app_fits_plans(
+            cfg, _json_at(root, "benchmark", "traffic", mix + ".json"))
+    booted = Config()
+    booted.base.proxy_app = app
+    cell_mod.stated_as_run(cfg, booted)
+    if "app" in cfg:
+        booted.base.proxy_app = "counter" if app != "counter" else "nilapp"
+        with pytest.raises(RuntimeError, match="'app'"):
+            cell_mod.stated_as_run(cfg, booted)
+
+
+ENTRY_RULES = {"configs": (config_entry_and_file_hold,
+                           config_states_an_app_that_fits),
+               "workloads": (workload_entry_and_files_hold,)}
+
+
+def every_entry_holds(root: str, bench: dict) -> int:
+    """Every rule of ENTRY_RULES held to every entry of its list; how
+    many (rule, entry) pairs that was."""
+    pairs = [(rule, entry) for key, rules in ENTRY_RULES.items()
+             for entry in bench[key] for rule in rules]
+    for rule, entry in pairs:
+        rule(root, bench, entry)
+    return len(pairs)
 
 # what run.py does, minus its look for a chip: the real cell's files with
 # the sizes cut to what a test can hold.  `config` and `traffic` are laid

@@ -190,6 +190,147 @@ def test_a_seventh_cell_configuration_and_metric_appended_break_no_order(
     assert BENCH["per_layer"][-1]["name"] not in NEW_METRICS
 
 
+# the cell PR 48 prepared, kept as data of THIS test: nothing under
+# `benchmark/` and no entry of `BENCHMARK.json` names these files until a
+# `model_config` PR copies them to `benchmark/configs/` and
+# `benchmark/traffic/` and appends the two entries
+CANDIDATE = "catchup-powers-100v.power-drift"
+DATA = os.path.join(REPO, "tests", "benchmark", "data")
+
+
+def with_the_candidate(tmp_path, **config_edits) -> tuple[str, dict]:
+    """(root, bench) of a copy of the benchmark's data files under
+    `tmp_path` with the candidate's configuration and mix put where a PR
+    would put them and its two entries LAST in `configs` and `workloads`
+    (an entry of the same name that the tree already lists is replaced:
+    the guard judges the data files, whatever the tree has taken since)."""
+    import copy
+    import shutil
+    root = str(tmp_path)
+    for part in ("configs", "traffic", "layers"):
+        shutil.copytree(os.path.join(REPO, "benchmark", part),
+                        os.path.join(root, "benchmark", part))
+    _write(os.path.join(root, "benchmark", "configs",
+                        "catchup-powers-100v.json"),
+           dict(_json(DATA, "catchup-powers-100v.json"), **config_edits))
+    shutil.copy(os.path.join(DATA, "power-drift.json"),
+                os.path.join(root, "benchmark", "traffic"))
+    bench = copy.deepcopy(BENCH)
+    for key, entries in _json(DATA,
+                              "catchup-powers-100v.entries.json").items():
+        names = {e["name"] for e in entries}
+        bench[key] = [e for e in bench[key]
+                      if e["name"] not in names] + entries
+    _write(os.path.join(root, "BENCHMARK.json"), bench)
+    return root, bench
+
+
+def test_the_prepared_seventh_cell_appended_is_held_by_every_rule(tmp_path):
+    """What the guard above cannot see (its seventh cell is a clone of
+    `catchup-100v` under a clone of `empty-blocks`, judged by the order
+    rules alone): a REAL candidate, one that differs from the accepted
+    cells in its app, its plan and its chain plan, appended to a copy
+    with its files, is held by every rule that holds an accepted entry
+    (`benchutil.ENTRY_RULES`: the functions the parametrised tests of
+    `test_bench_files.py` and `test_bench_churn_cell.py` call on the
+    tree), by `lists_hold` and by the order `test_bench_wide_set.py`
+    holds; every cell loads, the others as they load from the tree."""
+    import benchutil
+    root, bench = with_the_candidate(tmp_path)
+    assert bench["workloads"][-1]["name"] == CANDIDATE
+    assert bench["configs"][-1]["name"] == "catchup-powers-100v"
+    assert benchutil.every_entry_holds(root, bench) == \
+        2 * len(bench["configs"]) + len(bench["workloads"])
+    lists_hold(root, bench)
+    cells = [w["name"] for w in bench["workloads"]]
+    assert len(cells) == len(set(cells)) >= 7
+    unlisted = [m["name"] for m in bench["per_layer"] if "workloads" not in m]
+    for name in cells:
+        got = cell_mod.load_cell(root, name)
+        assert [m["name"] for m in got["per_layer"]] == [
+            m["name"] for m in bench["per_layer"]
+            if "workloads" not in m or name in m["workloads"]]
+        if name != CANDIDATE:
+            assert got == cell_mod.load_cell(REPO, name)
+    # as `test_bench_wide_set.py` holds of its cell: every unlisted
+    # metric, in the list's order, and the cell adds itself to no list
+    wide = cell_mod.load_cell(root, "catchup-300v.empty-blocks")
+    assert [m["name"] for m in wide["per_layer"]] == unlisted
+    cand = cell_mod.load_cell(root, CANDIDATE)
+    assert [m["name"] for m in cand["per_layer"]] == unlisted
+    assert not any(CANDIDATE in m.get("workloads", ())
+                   for m in bench["per_layer"] + bench["end_to_end"])
+    assert cand["config"]["app"] == "valset_kvstore"
+    assert cand["traffic"]["powers"] == {
+        "change_every_blocks": 1, "members": 3, "min": 1, "max": 100}
+    assert cell_mod.chain_plan(cand) == {
+        "parent_blocks_per_s": 15, "warmup_s": 22, "headroom": 20.0}
+    assert cell_mod.chain_blocks(cand, 45) == 20161
+    chain.check_plans(SEED, 100, cand["traffic"].get("valset"),
+                      cand["traffic"].get("absent"),
+                      cand["traffic"]["powers"])
+    cell_mod.app_fits_plans(cand["config"], cand["traffic"])
+    from tendermint_tpu.config import Config
+    booted = Config()
+    booted.base.proxy_app = cand["config"]["app"]
+    cell_mod.stated_as_run(cand["config"], booted)
+
+
+def test_the_rules_refuse_the_candidate_on_an_app_its_plan_cannot_use(
+        tmp_path):
+    """And the other way round, so that the rule bites through the same
+    functions: the candidate stating the default app is refused by name
+    of its plan and of the app, before anything could build its chain."""
+    import benchutil
+    root, bench = with_the_candidate(tmp_path, app="kvstore")
+    with pytest.raises(ValueError, match="'catchup-powers-100v'.*'kvstore'"
+                       ".*'power-drift'.*a powers plan.*returns none"):
+        benchutil.every_entry_holds(root, bench)
+    # all but the app rule hold of it still
+    for key, rules in benchutil.ENTRY_RULES.items():
+        for rule in rules:
+            if rule is not benchutil.config_states_an_app_that_fits:
+                rule(root, bench, bench[key][-1])
+
+
+def test_the_candidates_files_are_the_churn_cells_but_for_what_powers_change():
+    """`catchup-churn-100v.json` key for key in its shapes, rates and
+    limits; `empty-blocks`' block; entries within the contract's 200
+    characters; depth the only cut."""
+    cfg = _json(DATA, "catchup-powers-100v.json")
+    churn = _json("benchmark", "configs", "catchup-churn-100v.json")
+    own = {"name", "source", "deployment", "voting_power", "on_device",
+           "guarantees", "assumed", "reduced_to"}
+    assert list(cfg) == list(churn)
+    assert {k for k in cfg if cfg[k] != churn[k]} == own
+    assert cfg["guarantees"][:3] == PLAIN["guarantees"]
+    assert len(cfg["guarantees"]) == 4
+    assert "KEYS AND POWERS" in cfg["guarantees"][3]
+    assert "/validators" in cfg["guarantees"][3]
+    assert set(cfg["assumed"]) >= {"change_every_blocks", "members", "min",
+                                   "max", "genesis", "source_peers"}
+    assert cfg["reduced"] == ["upstream_chain_blocks"]
+    assert "20,161" in cfg["reduced_to"] and "ONE comb table" in cfg[
+        "on_device"] and "327,155,712 B" in cfg["on_device"]
+    mix = _json(DATA, "power-drift.json")
+    assert mix["block"] == _json("benchmark", "traffic",
+                                 "empty-blocks.json")["block"]
+    assert not {"valset", "absent"} & set(mix)
+    assert mix["chain"]["default"] == mix["chain"]["catchup-powers-100v"]
+    assert "0.8 x headroom = 16 (240 blocks/s)" in mix["chain"]["note"]
+    # data of a test: neither BENCHMARK.json nor a file under
+    # `benchmark/` names them
+    paths = [os.path.join(REPO, "BENCHMARK.json")] + [
+        os.path.join(d, f)
+        for d, _dirs, files in os.walk(os.path.join(REPO, "benchmark"))
+        for f in files if f.endswith((".json", ".md", ".py"))]
+    for path in paths:
+        with open(path) as f:
+            text = f.read()
+        assert not any(word in text for word in (
+            "data/catchup-powers-100v", "data/power-drift")), path
+
+
 @pytest.mark.parametrize("seconds,blocks", [(5, 12033), (45, 29761),
                                             (51, 32449)])
 def test_the_chain_is_whole_windows_plus_one_from_the_mixs_own_plan(

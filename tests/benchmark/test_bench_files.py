@@ -7,11 +7,11 @@ import re
 
 import pytest
 
-from benchutil import REPO
+import benchutil
+from benchutil import NAME, REPO
 from benchmark.lib import cell as cell_mod
 from benchmark.lib import reducers, roofline
 
-NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 PATH = re.compile(r"^[A-Za-z0-9_.\-/]{1,200}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
@@ -44,34 +44,12 @@ def test_files_under_paths_are_named_from_allowed_characters():
 
 @pytest.mark.parametrize("c", BENCH["configs"], ids=lambda c: c["name"])
 def test_config_entry_and_file(c):
-    assert set(c) == {"name", "source", "file", "reduced", "why"}
-    assert NAME.match(c["name"]) and len(c["source"]) <= 200
-    assert len(c["why"]) <= 200 and len(c["reduced"]) <= 16
-    assert any(c["file"].startswith(p + "/") for p in BENCH["paths"])
-    with open(os.path.join(REPO, c["file"])) as f:
-        cfg = json.load(f)
-    assert cfg["name"] == c["name"] and cfg["reduced"] == c["reduced"]
-    assert cfg["guarantees"] and cfg["assumed"] and cfg["chips"] == 1
-    assert all(NAME.match(k) and k in cfg for k in c["reduced"])
-    assert any(w["config"] == c["name"] for w in BENCH["workloads"])
+    benchutil.config_entry_and_file_hold(REPO, BENCH, c)
 
 
 @pytest.mark.parametrize("w", BENCH["workloads"], ids=lambda w: w["name"])
 def test_workload_entry_and_files(w):
-    assert set(w) == {"name", "config", "traffic", "chips", "why"}
-    assert NAME.match(w["name"]) and NAME.match(w["traffic"])
-    assert w["name"] == f"{w['config']}.{w['traffic']}"
-    assert w["chips"] in (1, 4) and 1 <= len(w["why"]) <= 200
-    cell = cell_mod.load_cell(REPO, w["name"])
-    assert cell["traffic"]["name"] == w["traffic"]
-    assert set(cell["traffic"]["block"]) == {"txs_per_block", "tx_bytes",
-                                             "keys"}
-    # the chain served is whole windows plus the block with the last
-    # commit, and grows with the window
-    n = cell_mod.chain_blocks(cell, BENCH["run_seconds"])
-    assert n % 64 == 1 and n > cell_mod.chain_blocks(cell, 5)
-    assert {m["name"] for m in cell["end_to_end"]} >= {"setup_s"}
-    assert len(cell["end_to_end"]) >= 2 and cell["per_layer"]
+    benchutil.workload_entry_and_files_hold(REPO, BENCH, w)
 
 
 def _cell_of(config: str, traffic: str, plan=None) -> dict:

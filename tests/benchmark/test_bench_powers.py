@@ -506,8 +506,11 @@ def test_run_cell_hands_the_plan_to_the_source_child(monkeypatch):
     monkeypatch.setattr(cell_mod.children_mod, "Children", Kids)
     monkeypatch.setattr(cell_mod, "start_watchdog",
                         lambda kids: types.SimpleNamespace(cancel=lambda: 0))
-    for traffic in ({"powers": PLAN}, {}):
-        cell = {"config": {"validators": N_VALS, "source_peers": 1},
+    # a mix with the plan runs on an app that returns its `val:` txs as
+    # diffs, one without on the default (`cell.app_fits_plans`, PR 49)
+    for traffic, app in (({"powers": PLAN}, {"app": "valset_kvstore"}),
+                         ({}, {})):
+        cell = {"config": dict(app, validators=N_VALS, source_peers=1),
                 "config_name": "c", "traffic_name": "t",
                 "traffic": dict(traffic, block=EMPTY, chain={
                     "default": {"parent_blocks_per_s": 10, "warmup_s": 1}})}

@@ -282,7 +282,12 @@ with open(os.path.join(REPO, "BENCHMARK.json")) as _f:
 def test_every_accepted_configuration_names_an_app_the_program_boots(config):
     """A configuration's `app` is a name in the program's registry of
     in-process apps, and `stated_as_run` accepts the node booted with it
-    (`boot_node` hands it to the node's Config as `proxy_app`)."""
+    (`boot_node` hands it to the node's Config as `proxy_app`).
+    `test_bench_churn_cell.py::test_every_configuration_states_an_app_the_program_has`
+    overlaps: it holds the same two and, beside them, the harness's rule
+    of which app goes with which mix (`cell.app_fits_plans`), the record
+    of the apps the accepted configurations were accepted with, and the
+    error on a node booted on another app."""
     from tendermint_tpu.config import Config
     with open(os.path.join(REPO, CONFIG_FILES[config])) as f:
         cfg = json.load(f)
