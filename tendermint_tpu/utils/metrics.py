@@ -315,6 +315,9 @@ class Registry:
         # upstream's nil entries (a precommit that missed the commit)
         # in the commits decoded, on either path
         self.commit_precommits_absent = Counter()
+        # `C:h` rows `BlockStore.save_block` wrote as the marker for the
+        # bytes of `SC:h-1` (blockchain/store.py)
+        self.blockstore_commits_aliased = Counter()
         # fast-sync windows by the lane builder they took
         # (types/validator.py::window_commit_lanes): every commit in its
         # wire bytes and one vectorised pass, or a pass a block
@@ -457,6 +460,8 @@ class Registry:
                 self.commits_decoded_wire_absent.value,
             "commits_decoded_objects": self.commits_decoded_objects.value,
             "commit_precommits_absent": self.commit_precommits_absent.value,
+            "blockstore_commits_aliased":
+                self.blockstore_commits_aliased.value,
             "lane_windows_vectorised": self.lane_windows_vectorised.value,
             "lane_windows_per_block": self.lane_windows_per_block.value,
             "snapshots_created": self.snapshots_created.value,

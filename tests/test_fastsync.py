@@ -706,3 +706,37 @@ def test_a_regular_window_records_nothing_and_a_pruned_commit_one_instant():
     assert [a["height"] for a in since(t0, "pool.redo")] == [40]
     assert since(t0, "commit.object_form") == []
     assert len(since(t0, "commit.wire_absent")) == 1
+
+
+def test_a_synced_window_stores_one_marker_a_height_less_one():
+    """64 heights through receive, verify and `apply_window` into the
+    block store: the seen commit of h is block h + 1's `last_commit`, so
+    every `C:` row but the first is the marker for the bytes of `SC:h-1`
+    (`blockchain/store.py`), counted once on `/metrics` and once in the
+    flight recorder, and every commit loads back as what was served."""
+    from tendermint_tpu.utils import tracing
+    from tendermint_tpu.utils.metrics import REGISTRY, prometheus_text
+    privs, vs = make_validators(4)
+    chain = build_chain(privs, vs, CHAIN, 65,
+                        app_hashes=kvstore_app_hashes(65))
+    bc = _window_through_receive([b.encode() for b, _ps, _seen in chain], 64)
+    aliased0 = REGISTRY.blockstore_commits_aliased.value
+    t0 = tracing.now_epoch()
+    assert bc._sync_step() is True
+    assert bc.state.last_block_height == bc.store.height == 64
+    assert REGISTRY.blockstore_commits_aliased.value - aliased0 == 63
+    assert "\ntendermint_blockstore_commits_aliased %d\n" % \
+        REGISTRY.blockstore_commits_aliased.value in prometheus_text()
+    assert REGISTRY.snapshot()["blockstore_commits_aliased"] == \
+        REGISTRY.blockstore_commits_aliased.value
+    assert len([s for s in tracing.RECORDER.since(t0)
+                if s["name"] == "store.commit_alias"
+                and s["ts"] >= t0]) == 63
+    rows = dict(bc.store.db.iterate_prefix(b"C:"))
+    assert len(rows) == 64 and rows.pop(b"C:1") != b""
+    assert set(rows.values()) == {b""}
+    for h in range(1, 64):
+        seen = chain[h - 1][2]
+        for got in (bc.store.load_block_commit(h),
+                    bc.store.load_seen_commit(h)):
+            assert got == seen and got.encode() == seen.encode()
